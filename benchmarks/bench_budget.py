@@ -80,7 +80,7 @@ def run_admission(n_queries: int = 8):
     pool_pages = int(10 * pages_per_cluster)
     eng = TeleRAGEngine(index, EngineConfig(
         nprobe=12, top_k=3, buffer_pages=pool_pages, lookahead_rank=16,
-        kernel_mode="ref", chips=4, seed=3), get_arch("llama3-8b"))
+        chips=4, seed=3), get_arch("llama3-8b"))
     runtime = RetrievalRuntime(eng, micro_batch=2)
 
     cents = index.centroids / np.linalg.norm(index.centroids, axis=-1,

@@ -147,10 +147,10 @@ def _serve_path_records(*, B: int, steps: int, page_size: int,
                                   max_steps=steps, page_size=page_size,
                                   slab_seqs=B, paged=paged)
             runner.attach(SimpleNamespace(
-                wall=SystemClock(),
+                wall=SystemClock(), micro_batch=None,
                 engines=[SimpleNamespace(
                     cfg=EngineConfig(paged_decode=paged, kernel_mode=mode),
-                    pool=None)]))
+                    pool=None, device=None)]))
             before = traced["paged"]
             secs: List[float] = []
             for w in range(waves + 1):          # wave 0 is jit warmup

@@ -21,7 +21,6 @@ def run(nprobes=(16, 32, 64, 128), budget_pages: int = 640,
     for np_ in nprobes:
         cfg = EngineConfig(nprobe=np_, top_k=3, buffer_pages=1024,
                            lookahead_rank=min(4 * np_, N_CLUSTERS),
-                           kernel_mode="ref",
                            prefetch_budget_bytes=budget_pages
                            * idx.paged.page_nbytes(), chips=4)
         eng = TeleRAGEngine(idx, cfg, get_arch("llama3-8b"))
@@ -31,11 +30,11 @@ def run(nprobes=(16, 32, 64, 128), budget_pages: int = 640,
         res = eng.retrieve(q_out)
         hits = sum(len(h) for h in res.hit_clusters)
         miss = sum(len(m) for m in res.missed_clusters)
-        t_cc = paper_scale_tcc(cfg.hw)
+        t_cc = paper_scale_tcc(eng.cfg.hw)
         t_cpu = (hits + miss) / n_queries * t_cc
         t_tel = max(miss / n_queries * t_cc,
                     hits / n_queries * PAPER_CLUSTER_BYTES
-                    / (cfg.hw.hbm_bw * cfg.chips)) + 2e-5
+                    / (eng.cfg.hw.hbm_bw * cfg.chips)) + 2e-5
         rows.append({"nprobe": np_, "hit_rate": round(res.hit_rate, 4),
                      "retrieval_speedup": round(t_cpu / t_tel, 2),
                      "t_cpu_ms": round(t_cpu * 1e3, 2),

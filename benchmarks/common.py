@@ -68,7 +68,7 @@ def bench_cfg(mode: str = "telerag", *, buffer_pages: int = 640,
     return EngineConfig(
         nprobe=NPROBE, top_k=TOP_K, buffer_pages=buffer_pages,
         lookahead_rank=min(2 * NPROBE, N_CLUSTERS), mode=mode,
-        kernel_mode="ref", cache_enabled=cache,
+        cache_enabled=cache,
         prefetch_budget_bytes=budget_bytes, chips=chips, seed=seed)
 
 

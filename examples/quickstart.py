@@ -31,8 +31,8 @@ def main():
           f"{store.nbytes()/1e6:.0f} MB host-resident")
 
     eng = TeleRAGEngine(index, EngineConfig(
-        nprobe=16, top_k=3, buffer_pages=192, lookahead_rank=32,
-        kernel_mode="ref"), get_arch("llama3-8b"))
+        nprobe=16, top_k=3, buffer_pages=192, lookahead_rank=32),
+        get_arch("llama3-8b"))
 
     # the user query (embedding) — q_in
     rng = np.random.default_rng(7)

@@ -46,7 +46,7 @@ def main():
     store = core.synthetic_datastore(60_000, dim=160, seed=1)
     index = core.build_ivf(store, 96, page_size=96, kmeans_iters=4)
     cfg = EngineConfig(nprobe=24, top_k=3, buffer_pages=384,
-                       lookahead_rank=48, kernel_mode="ref",
+                       lookahead_rank=48,
                        cache_enabled=True, chips=4)
     srv = TeleRAGServer(index, cfg, args.replicas, get_arch("llama3-8b"),
                         scheduler=TeleRAGScheduler(),
@@ -112,7 +112,7 @@ def main():
     print("\n== wave 4: multi-tenant SLO mix (interactive floor + "
           "batch burst) ==")
     cfg_mt = EngineConfig(nprobe=24, top_k=3, buffer_pages=384,
-                          lookahead_rank=48, kernel_mode="ref",
+                          lookahead_rank=48,
                           cache_enabled=True, chips=4,
                           tenant_shares={"interactive": (96, None),
                                          "batch": (0, 288)})

@@ -5,7 +5,7 @@ from repro.configs.base import ArchConfig, MoEConfig, register
 GRANITE_MOE_3B = register(ArchConfig(
     name="granite-moe-3b-a800m",
     family="moe",
-    source="hf:ibm-granite/granite-3.0-1b-a400m-base; hf",
+    source="hf:ibm-granite/granite-3.0-3b-a800m-base; hf",
     num_layers=32,
     d_model=1536,
     num_heads=24,

@@ -53,6 +53,25 @@ TPU_V5E = HardwareProfile(
 RTX4090 = HardwareProfile("rtx4090", 165e12, 1008e9, 0.0, 32e9, 24e9)
 H100 = HardwareProfile("h100", 989e12, 3350e9, 0.0, 64e9, 80e9)
 
+# jax ``Device.device_kind`` -> profile.  The host CPU has no device to
+# time: the event clock of a CPU run models the v5e the serve path is
+# built for, and says so by this explicit entry.
+HARDWARE_PROFILES = {
+    "TPU v5 lite": TPU_V5E,
+    "cpu": TPU_V5E,
+}
+
+
+def hardware_profile(device) -> HardwareProfile:
+    """The profile of a jax device; a kind missing from
+    ``HARDWARE_PROFILES`` is an error, never a silent default."""
+    try:
+        return HARDWARE_PROFILES[device.device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no hardware profile for device kind {device.device_kind!r}; "
+            f"known: {sorted(HARDWARE_PROFILES)}") from None
+
 
 def host_cluster_search_seconds(cluster_bytes: float, hw: HardwareProfile,
                                 ) -> float:

@@ -14,7 +14,13 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import ml_dtypes
 import numpy as np
+
+# the precision pages move host->device at and are searched at, on both
+# sides: host search scores exactly the values the device pool holds, so
+# a cluster ranks the same wherever it is resident
+PAGE_DTYPE = ml_dtypes.bfloat16
 
 
 @dataclass
@@ -52,13 +58,20 @@ def synthetic_datastore(num_vectors: int, dim: int = 768, *, seed: int = 0,
     return Datastore(embeddings=emb)
 
 
+def page_nbytes(page_size: int, dim: int) -> int:
+    """Bytes of one page as it moves and sits on the device: ``page_size``
+    vectors at ``PAGE_DTYPE`` plus their int32 ids."""
+    return page_size * dim * np.dtype(PAGE_DTYPE).itemsize + page_size * 4
+
+
 @dataclass
 class PagedClusters:
     """Cluster-major paged layout of a datastore under an IVF assignment."""
 
     page_size: int
     dim: int
-    # page-major storage: pages[i] is [page_size, d] (tail zero-padded)
+    # page-major storage: pages[i] is [page_size, d] (tail zero-padded),
+    # float32 holding PAGE_DTYPE values
     pages: np.ndarray               # [total_pages, page_size, d] float32
     page_ids: np.ndarray            # [total_pages, page_size] int32, -1 = pad
     page_cluster: np.ndarray        # [total_pages] int32 owning cluster
@@ -86,9 +99,8 @@ class PagedClusters:
         """Transfer cost of cluster c (whole pages, vector payload)."""
         return int(self.cluster_num_pages[c]) * self.page_nbytes()
 
-    def page_nbytes(self, dtype_bytes: int = 2) -> int:
-        # transfers happen in bf16 (2 bytes): the device search runs in bf16
-        return self.page_size * self.dim * dtype_bytes + self.page_size * 4
+    def page_nbytes(self) -> int:
+        return page_nbytes(self.page_size, self.dim)
 
     def all_cluster_bytes(self) -> np.ndarray:
         return self.cluster_num_pages.astype(np.int64) * self.page_nbytes()
@@ -124,7 +136,8 @@ def build_paged_clusters(store: Datastore, assignments: np.ndarray,
             pclust.append(c)
     return PagedClusters(
         page_size=page_size, dim=d,
-        pages=np.stack(pages), page_ids=np.stack(pids),
+        pages=np.stack(pages).astype(PAGE_DTYPE).astype(np.float32),
+        page_ids=np.stack(pids),
         page_cluster=np.asarray(pclust, np.int32),
         cluster_first_page=np.asarray(first_page, np.int32),
         cluster_num_pages=np.asarray(num_pages, np.int32),

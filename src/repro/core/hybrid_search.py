@@ -153,8 +153,8 @@ def hybrid_retrieve(buffer: PrefetchBuffer, queries: np.ndarray,
             luts[b, hit[b]] = True
         pages, page_ids, _ = buffer.device_view()
         pc = buffer.slot_cluster                # host page-table mirror
-        page_mask = np.zeros((B, buffer.num_pages), bool)
-        valid_slots = pc >= 0
+        page_mask = np.zeros((B, pages.shape[0]), bool)
+        valid_slots = np.flatnonzero(pc >= 0)
         page_mask[:, valid_slots] = luts[:, pc[valid_slots]]
         dev_s, dev_i = ops.ivf_topk(pages, page_ids, jnp.asarray(page_mask),
                                     qd, k, mode=kernel_mode)
@@ -195,8 +195,7 @@ def sharded_device_search(mesh, queries: jax.Array, pages: jax.Array,
         top_s, idx = jax.lax.top_k(s_all, k)
         return top_s, jnp.take_along_axis(i_all, idx, axis=-1)
 
-    from repro.compat import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), P(axis), P(axis), P(axis)),
         out_specs=(P(), P()), check_vma=False)

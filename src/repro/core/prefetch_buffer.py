@@ -50,12 +50,12 @@ class TransferStats:
 
 class PrefetchBuffer:
     def __init__(self, paged: PagedClusters, num_pages: Optional[int] = None,
-                 dtype=jnp.bfloat16, *, pool: Optional[DevicePagePool] = None,
+                 *, pool: Optional[DevicePagePool] = None,
                  quota_pages: Optional[int] = None):
         if pool is None:
             if num_pages is None:
                 raise ValueError("need num_pages or a pool")
-            pool = DevicePagePool(paged, num_pages, dtype)
+            pool = DevicePagePool(paged, num_pages)
         self.paged = paged
         self.pool = pool
         # the prefetch share of the pool (cache quotas key off this, not

@@ -9,6 +9,10 @@ ONE mode-dispatch layer for every kernel — resolution order:
   3. backend auto-detect: real compiled kernel on TPU, pure-jnp oracle
      everywhere else (fast CPU path).
 
+Every entry point records the mode it resolved to (``resolved_modes``),
+so a run on the chip can show — and assert — that its kernels really
+ran compiled rather than through an oracle.
+
 Accepted modes (aliases in parentheses):
   * "auto"                      — the detection above
   * "kernel" ("tpu")            — pallas kernel compiled for the backend
@@ -26,14 +30,13 @@ forms keep their pad-and-flatten prep for callers that hold dense slabs.
 from __future__ import annotations
 
 import os
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref as ref_mod
 from repro.kernels.centroid_probe import centroid_scores as _probe_kernel
-from repro.kernels.flash_decode import flash_decode as _flash_kernel
 from repro.kernels.flash_decode import flash_decode_paged as _flash_paged_kernel
 from repro.kernels.ivf_topk import ivf_topk_flat as _ivf_kernel
 from repro.kernels.probe_topk import probe_topk_fused as _probe_topk_kernel
@@ -46,6 +49,21 @@ _ALIASES = {
     "kernel": "kernel", "tpu": "kernel", "compiled": "kernel",
     "kernel_interpret": "kernel_interpret", "interpret": "kernel_interpret",
 }
+
+
+# entry point -> execution plane it last resolved to (set at trace time)
+_RESOLVED: Dict[str, str] = {}
+
+
+def resolved_modes() -> Dict[str, str]:
+    """The execution plane each kernel entry point last resolved to."""
+    return dict(_RESOLVED)
+
+
+def _resolve_for(entry: str, mode: Optional[str]) -> str:
+    m = resolve_mode(mode)
+    _RESOLVED[entry] = m
+    return m
 
 
 def resolve_mode(mode: Optional[str] = DEFAULT_MODE) -> str:
@@ -70,10 +88,6 @@ def _interpret(m: str) -> bool:
     return m == "kernel_interpret"
 
 
-# kept for callers/tests that used the private resolver
-_resolve = resolve_mode
-
-
 def _pad_rows(x: jax.Array, multiple: int, fill=0):
     n = x.shape[0]
     pad = (-n) % multiple
@@ -83,13 +97,16 @@ def _pad_rows(x: jax.Array, multiple: int, fill=0):
     return jnp.pad(x, widths, constant_values=fill)
 
 
-def _divisor_tile(n: int, want: int) -> int:
-    """Largest tile <= want that divides n (paged inputs are read in
-    place, so the tile must divide instead of padding a copy)."""
-    for t in range(min(want, n), 0, -1):
+def _page_tile(n: int, want: int) -> int:
+    """Pool pages per grid step: the largest multiple of 8 that divides
+    ``n`` and is <= max(want, 8), else all ``n`` pages in one block
+    (paged inputs are read in place, so the tile must divide instead of
+    padding a copy, and a [tile, ps] block must be 8-row aligned or
+    whole).  ``DevicePagePool`` pads its slab to a multiple of 8 rows."""
+    for t in range(max(want, 8) // 8 * 8, 0, -8):
         if n % t == 0:
             return t
-    return 1
+    return n
 
 
 def ivf_topk(pages: jax.Array, page_ids: jax.Array, page_mask: jax.Array,
@@ -97,7 +114,7 @@ def ivf_topk(pages: jax.Array, page_ids: jax.Array, page_mask: jax.Array,
              mode: str = DEFAULT_MODE) -> Tuple[jax.Array, jax.Array]:
     """Search the prefetch slab. pages [P,ps,d]; page_mask [P] or per-query
     [B,P]; queries [B,d] -> (scores [B,k], ids [B,k])."""
-    m = resolve_mode(mode)
+    m = _resolve_for("ivf_topk", mode)
     if m == "ref":
         return ref_mod.ivf_topk_ref(pages, page_ids, page_mask, queries, k)
     B = queries.shape[0]
@@ -121,7 +138,7 @@ def centroid_probe(centroids: jax.Array, queries: jax.Array, nprobe: int, *,
                    valid: Optional[jax.Array] = None, tile: int = 512,
                    mode: str = DEFAULT_MODE) -> Tuple[jax.Array, jax.Array]:
     """Coarse probe -> (scores [B,nprobe], cluster ids [B,nprobe])."""
-    m = resolve_mode(mode)
+    m = _resolve_for("centroid_probe", mode)
     Nc = centroids.shape[0]
     if valid is None:
         valid = jnp.ones((Nc,), bool)
@@ -148,7 +165,7 @@ def probe_and_topk(queries: jax.Array, centroids: jax.Array,
     page_cluster [P]) in place.  Replaces the ``centroid_probe`` ->
     host-built page mask -> ``ivf_topk``-over-compacted-slab chain on
     the serving hot path.  Returns (scores [B,k], doc ids [B,k])."""
-    m = resolve_mode(mode)
+    m = _resolve_for("probe_and_topk", mode)
     Nc = centroids.shape[0]
     nprobe = max(1, min(nprobe, Nc))
     if valid is None:
@@ -160,7 +177,7 @@ def probe_and_topk(queries: jax.Array, centroids: jax.Array,
     cent = _pad_rows(centroids, ct)
     v = _pad_rows(valid, ct, fill=False)
     P = pages.shape[0]
-    pt = _divisor_tile(P, page_tile)
+    pt = _page_tile(P, page_tile)
     return _probe_topk_kernel(queries, cent, v, pages, page_ids,
                               page_cluster, nprobe=nprobe, k=k, cent_tile=ct,
                               page_tile=pt, interpret=_interpret(m))
@@ -169,16 +186,22 @@ def probe_and_topk(queries: jax.Array, centroids: jax.Array,
 def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array, pos: jax.Array, *,
                  window: int = 0, tile: int = 512,
                  mode: str = DEFAULT_MODE) -> jax.Array:
-    """Decode attention [B,KVH,G,Dh] over dense KV [B,S,KVH,Dh]."""
-    m = resolve_mode(mode)
+    """Decode attention [B,KVH,G,Dh] over dense KV [B,S,KVH,Dh] at
+    per-row position ``pos``: the paged kernel over an identity block
+    table, one page per S-tile (a free reshape of the dense cache)."""
+    m = _resolve_for("flash_decode", mode)
     if m == "ref":
         return ref_mod.flash_decode_ref(q, k, v, pos, window)
-    S = k.shape[1]
+    B, S = k.shape[:2]
     tile = min(tile, S)
     if S % tile:
         tile = S
-    return _flash_kernel(q, k, v, pos, window=window, tile=tile,
-                         interpret=_interpret(m))
+    nt = S // tile
+    pages = lambda x: x.reshape((B * nt, tile) + x.shape[2:])
+    table = jnp.arange(B * nt, dtype=jnp.int32).reshape(B, nt)
+    return _flash_paged_kernel(q, pages(k), pages(v), table,
+                               pos.astype(jnp.int32) + 1, window=window,
+                               interpret=_interpret(m))
 
 
 def flash_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
@@ -189,7 +212,7 @@ def flash_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     gathered through ``block_table`` [B,max_blocks] (-1 = unallocated)
     with per-request ``lengths`` [B] — the block-table form of
     ``flash_decode`` (identical numerics at ``pos = lengths - 1``)."""
-    m = resolve_mode(mode)
+    m = _resolve_for("flash_decode_paged", mode)
     if m == "ref":
         return ref_mod.flash_decode_paged_ref(q, k_pages, v_pages,
                                               block_table, lengths, window)
@@ -202,17 +225,16 @@ def flash_decode_spliced(q: jax.Array, k_pages: jax.Array,
                          lengths: jax.Array, page_delta: jax.Array,
                          page_valid: jax.Array, *,
                          rope_fraction: float = 1.0,
-                         rope_theta: float = 10_000.0,
-                         mode: str = DEFAULT_MODE) -> jax.Array:
+                         rope_theta: float = 10_000.0) -> jax.Array:
     """Paged decode attention over a block table mixing fresh pages with
     spliced chunk-KV pages: per-page reordered-RoPE reindexing
     (``page_delta`` [B,MB], the constant rotation offset per page) plus
     per-page live-token masking (``page_valid`` [B,MB], < ps only on a
-    spliced chunk's partial last page).  A Pallas plane for the spliced
-    form does not exist yet, so every resolved mode runs the jnp oracle
-    — resolution still happens so invalid modes fail loudly and the
-    ``REPRO_KERNEL_MODE`` switch stays uniform across entry points."""
-    resolve_mode(mode)
+    spliced chunk's partial last page).
+
+    This is the jnp oracle and nothing else: the spliced form has no
+    Pallas kernel, so it takes no ``mode`` and records ``"ref"``."""
+    _RESOLVED["flash_decode_spliced"] = "ref"
     return ref_mod.flash_decode_spliced_ref(
         q, k_pages, v_pages, block_table, lengths, page_delta, page_valid,
         rope_fraction=rope_fraction, rope_theta=rope_theta)

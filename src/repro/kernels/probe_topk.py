@@ -50,6 +50,9 @@ NEG_INF = float("-inf")
 # expands cluster scores to pages multiplies by 0.0, and -inf * 0 = nan
 FINITE_NEG = -1.0e30
 VALID_FLOOR = -1.0e29          # scores above this came from a real centroid
+# f32 scores in full precision: the admitted clusters and the document
+# scores then match the host's numpy search of the same vectors
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _kernel(q_ref, cent_ref, valid_ref, pages_ref, ids_ref, pc_ref,
@@ -71,6 +74,7 @@ def _kernel(q_ref, cent_ref, valid_ref, pages_ref, ids_ref, pc_ref,
         c = cent_ref[...].astype(jnp.float32)          # [ct, d]
         v = valid_ref[0]                               # [1, ct]
         s = jax.lax.dot_general(q, c, (((1,), (1,)), ((), ())),
+                                precision=HIGHEST,
                                 preferred_element_type=jnp.float32)
         scores_s[:, pl.dslice(t * cent_tile, cent_tile)] = jnp.where(
             v > 0, s, FINITE_NEG)
@@ -102,24 +106,33 @@ def _kernel(q_ref, cent_ref, valid_ref, pages_ref, ids_ref, pc_ref,
     def _search():
         q = q_ref[...].astype(jnp.float32)             # [B, d]
         tile = pages_ref[...].astype(jnp.float32)      # [pt, ps, d]
-        vids = ids_ref[...]                            # [pt, ps]
-        pc = pc_ref[0, 0]                              # [pt]
+        pc = pc_ref[0]                                 # [1, pt]
 
         # gather-free page mask: cluster score -> page via one-hot matmul
+        # (the one-hot is built transposed, [Nc, pt], so the page row
+        # broadcasts down sublanes instead of being relaid out)
         nc_pad = scores_s.shape[1]
-        iota = jax.lax.broadcasted_iota(jnp.int32, (page_tile, nc_pad), 1)
-        onehot = (pc[:, None] == iota).astype(jnp.float32)
+        iota = jax.lax.broadcasted_iota(jnp.int32, (nc_pad, page_tile), 0)
+        onehot = (pc == iota).astype(jnp.float32)
         cs = jax.lax.dot_general(scores_s[...], onehot,
-                                 (((1,), (1,)), ((), ())),
+                                 (((1,), (0,)), ((), ())),
+                                 precision=HIGHEST,
                                  preferred_element_type=jnp.float32)  # [B,pt]
         allowed = ((cs >= tau_s[...]) & (cs > VALID_FLOOR)
-                   & (pc >= 0)[None, :])               # [B, pt]
+                   & (pc >= 0))                        # [B, pt]
 
         flat = tile.reshape(page_tile * page_size, tile.shape[-1])
         s = jax.lax.dot_general(q, flat, (((1,), (1,)), ((), ())),
+                                precision=HIGHEST,
                                 preferred_element_type=jnp.float32)
-        fid = vids.reshape(1, page_tile * page_size)
-        vmask = jnp.repeat(allowed, page_size, axis=1) & (fid >= 0)
+        # page-major lane layout of the tile's vectors: page j owns lanes
+        # [j*ps, (j+1)*ps) — built by concatenation, not a reshape
+        fid = jnp.concatenate([ids_ref[pl.ds(j, 1), :]
+                               for j in range(page_tile)], axis=1)
+        vmask = jnp.concatenate(
+            [jnp.broadcast_to(allowed[:, j:j + 1], (allowed.shape[0],
+                                                    page_size))
+             for j in range(page_tile)], axis=1) & (fid >= 0)
         s = jnp.where(vmask, s, NEG_INF)
         ts, ti = _tile_topk(s, jnp.broadcast_to(fid, s.shape), k)
 
@@ -156,7 +169,7 @@ def probe_topk_fused(queries: jax.Array, centroids: jax.Array,
     assert P % page_tile == 0, (P, page_tile)
     nct = Nc // cent_tile
     npt = P // page_tile
-    valid2 = valid.astype(jnp.int8).reshape(nct, 1, cent_tile)
+    valid2 = valid.astype(jnp.int32).reshape(nct, 1, cent_tile)
     pc2 = page_cluster.reshape(npt, 1, page_tile)
     grid = (nct + npt,)
     # index maps clamp each input to its own phase's range; the out-of-

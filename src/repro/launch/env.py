@@ -99,6 +99,26 @@ def validate(host_device_count: Optional[int] = None,
     return diffs
 
 
+# one fixed path inside the checkout (never a temp name, pid or time):
+# a cache that moves between runs is never hit
+COMPILE_CACHE_DIR = os.path.normpath(os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), os.pardir, os.pardir,
+    os.pardir, ".jax_cache"))
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its path:
+    ``JAX_COMPILATION_CACHE_DIR`` when set (JAX reads it itself and no
+    other path is set here), else ``COMPILE_CACHE_DIR``.  Call before the
+    first compile."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR", "")
+    if not path:
+        import jax
+        path = COMPILE_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def print_env(host_device_count: Optional[int] = None) -> None:
     """Print the snippet plus a divergence report for the live process."""
     print(render_shell(host_device_count))

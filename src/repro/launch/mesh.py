@@ -14,23 +14,13 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
-from jax.sharding import Mesh
-
-# jax >= 0.5 has jax.sharding.AxisType and make_mesh(..., axis_types=...);
-# older jax builds meshes without axis types. One fallback for both.
-AxisType = getattr(jax.sharding, "AxisType", None)
-
-
-def _axis_type_kwargs(n_axes: int) -> dict:
-    if AxisType is None:
-        return {}
-    return {"axis_types": (AxisType.Auto,) * n_axes}
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model: int = 1, data: Optional[int] = None) -> Mesh:
@@ -38,7 +28,7 @@ def make_host_mesh(model: int = 1, data: Optional[int] = None) -> Mesh:
     n = len(jax.devices())
     data = data or max(n // model, 1)
     return jax.make_mesh((data, model), ("data", "model"),
-                         **_axis_type_kwargs(2))
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def mesh_chip_count(mesh: Mesh) -> int:

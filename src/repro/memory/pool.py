@@ -48,7 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.datastore import PagedClusters
+from repro.core.datastore import PAGE_DTYPE, PagedClusters
 from repro.memory.ledger import MemoryLedger
 from repro.obs.recorder import FlightRecorder, PoolEvent
 
@@ -132,6 +132,12 @@ class TenantShare:
     max_pages: Optional[int] = None
 
 
+def device_rows(num_pages: int) -> int:
+    """Rows of a pool's device arrays: ``num_pages`` padded to a multiple
+    of 8, the search kernels' page tile."""
+    return -(-num_pages // 8) * 8
+
+
 class DevicePagePool:
     """One replica's HBM slab allocator: ``num_pages`` fixed-size page
     slots handed out as refcounted leases (block tables), with
@@ -139,18 +145,22 @@ class DevicePagePool:
     same free list.  All byte quantities are exact bytes; all counts
     returned by ``*_pages`` methods are whole page slots."""
 
-    def __init__(self, paged: PagedClusters, num_pages: int,
-                 dtype=jnp.bfloat16, *, ledger: Optional[MemoryLedger] = None):
+    def __init__(self, paged: PagedClusters, num_pages: int, *,
+                 ledger: Optional[MemoryLedger] = None,
+                 device: Optional[jax.Device] = None):
         """Build a pool of ``num_pages`` device page slots over ``paged``
-        (which fixes the page geometry and therefore ``page_nbytes``);
-        ``ledger`` defaults to a fresh byte ledger sized to the slab."""
+        (which fixes the page geometry and therefore ``page_nbytes``) on
+        ``device`` (None = jax's default placement); ``ledger`` defaults
+        to a fresh byte ledger sized to the slab."""
         self.paged = paged
         self.num_pages = num_pages
-        self.dtype = dtype
+        self.device = device
         ps, d = paged.page_size, paged.dim
-        self.pages = jnp.zeros((num_pages, ps, d), dtype)
-        self.page_ids = jnp.full((num_pages, ps), -1, jnp.int32)
-        self.page_cluster = jnp.full((num_pages,), -1, jnp.int32)
+        # pad rows never leave the free list, so stay unsearchable
+        rows = device_rows(num_pages)
+        self.pages = jnp.zeros((rows, ps, d), PAGE_DTYPE, device=device)
+        self.page_ids = jnp.full((rows, ps), -1, jnp.int32, device=device)
+        self.page_cluster = jnp.full((rows,), -1, jnp.int32, device=device)
         self.free: List[int] = list(range(num_pages - 1, -1, -1))
         self.ledger = ledger if ledger is not None else MemoryLedger(
             capacity_bytes=num_pages * self.page_nbytes)
@@ -435,7 +445,7 @@ class DevicePagePool:
         if n == 0:
             return
         cap = _round_up_pow2(n)
-        slots_arr = np.full(cap, self.num_pages, np.int32)   # OOB = dropped
+        slots_arr = np.full(cap, self.pages.shape[0], np.int32)  # OOB = dropped
         slots_arr[:n] = list(slot_list)
         pages_arr = np.zeros((cap, self.paged.page_size, self.paged.dim),
                              np.float32)
@@ -445,10 +455,10 @@ class DevicePagePool:
         cl_arr = np.full(cap, -1, np.int32)
         cl_arr[:n] = list(np_cl)
         # async dispatch: device_put + scatter overlap with LLM decode
+        put = lambda x: jax.device_put(x, self.device)
         self.pages, self.page_ids, self.page_cluster = _scatter_pages(
             self.pages, self.page_ids, self.page_cluster,
-            jnp.asarray(slots_arr), jnp.asarray(pages_arr),
-            jnp.asarray(ids_arr), jnp.asarray(cl_arr))
+            put(slots_arr), put(pages_arr), put(ids_arr), put(cl_arr))
 
     def device_view(self):
         """The (pages, page_ids, page_cluster) device arrays the search

@@ -40,6 +40,7 @@ def moe_params(mk: Maker, cfg: ArchConfig, prefix: str = "moe") -> dict:
 
 
 def moe_forward(p: dict, x: jax.Array, cfg: ArchConfig,
+                live_rows: Optional[jax.Array] = None,
                 ) -> Tuple[jax.Array, jax.Array]:
     """x: [B, S, d] -> (out [B, S, d], aux_loss scalar).
 
@@ -50,6 +51,11 @@ def moe_forward(p: dict, x: jax.Array, cfg: ArchConfig,
     size, independent of sequence length. This keeps high-top-k/small-ff
     configs (granite-moe: K=8 of E=40, ff=512) from blowing up, where
     sequence-sized GShard groups would need C≈T/3.
+
+    ``live_rows`` (traced int scalar) marks rows ``>= live_rows`` as
+    padding: they take no expert capacity, and capacity is that of the
+    live tokens alone, so the live rows' output equals ``moe_forward`` on
+    ``x[:live_rows]``.  Padding must fit one dispatch group.
     """
     mo = cfg.moe
     B, S, d = x.shape
@@ -59,8 +65,10 @@ def moe_forward(p: dict, x: jax.Array, cfg: ArchConfig,
     while T % Tg:
         Tg -= 1
     G = T // Tg
-    C = max(1, math.ceil(Tg * K * mo.capacity_factor / E))
-    C = min(C, Tg)
+    def capacity(t: int) -> int:
+        return min(max(1, math.ceil(t * K * mo.capacity_factor / E)), t)
+
+    C = capacity(Tg)
 
     xg = x.reshape(G, Tg, d)
     logits = jnp.einsum("gtd,de->gte", xg, p["router"]).astype(jnp.float32)
@@ -72,10 +80,19 @@ def moe_forward(p: dict, x: jax.Array, cfg: ArchConfig,
     sel = jax.nn.one_hot(top_e, E, dtype=jnp.float32)             # [G,T,K,E]
     gate = jnp.einsum("gtk,gtke->gte", top_p, sel)                # [G,T,E]
     sel_any = jnp.max(sel, axis=2)                                # [G,T,E] 0/1
+    cap = C
+    if live_rows is not None:
+        if G != 1:
+            raise ValueError(f"live_rows needs one dispatch group; {T} "
+                             f"tokens exceed group_size {mo.group_size}")
+        live = jnp.arange(B) < live_rows                          # [B]
+        sel_any = sel_any * jnp.repeat(live, S)[None, :, None]
+        caps = jnp.asarray([capacity(t) for t in range(T + 1)], jnp.int32)
+        cap = caps[jnp.clip(live_rows, 0, B) * S]
 
     # position of each token within each expert's capacity buffer
     pos_in_e = jnp.cumsum(sel_any, axis=1) - sel_any              # [G,T,E]
-    keep = sel_any * (pos_in_e < C)
+    keep = sel_any * (pos_in_e < cap)
     onehot_c = jax.nn.one_hot(pos_in_e.astype(jnp.int32), C,
                               dtype=jnp.float32)                  # [G,T,E,C]
     dispatch = (keep[..., None] * onehot_c).astype(x.dtype)

@@ -814,12 +814,14 @@ def serve_step_paged(params: Params, k_slab: jax.Array, v_slab: jax.Array,
 
     k_slab/v_slab: the ``KVPageSlab`` arrays [L, NP, ps, KVH, Dh] (all
     layers stacked); block_table: [B, max_blocks] int32 slab page slots
-    (a ``PagedCacheLease.device_tables()`` view); lengths: [B] int32
+    (a ``PagedCacheLease.tables()`` operand); lengths: [B] int32
     tokens already written per sequence — the new token is scattered at
     position ``lengths`` through the block table (the in-jit half of
     ``KVCacheManager.append_paged``; the caller advances the lease's
     host-side lengths afterwards) and attended in place with
-    ``kernels.ops.flash_decode_paged``.  inputs: token [B].
+    ``kernels.ops.flash_decode_paged``.  inputs: token [B], and
+    optionally live_rows (int32 scalar): rows from it on are padding,
+    which an MoE layer leaves out of expert capacity.
 
     Returns (logits [B, V], k_slab, v_slab).  Plain global-causal GQA
     attention archs only (the same restriction as
@@ -874,7 +876,8 @@ def serve_step_paged(params: Params, k_slab: jax.Array, v_slab: jax.Array,
         h = h + jnp.einsum("bshk,hkd->bsd", out, ap["wo"])
         m_in = rms_norm(h, lp["mlp_norm"], cfg.norm_eps)
         if cfg.moe is not None:
-            m_out, _ = moe_mod.moe_forward(lp["mlp"], m_in, cfg)
+            m_out, _ = moe_mod.moe_forward(lp["mlp"], m_in, cfg,
+                                           live_rows=inputs.get("live_rows"))
         else:
             m_out = mlp_forward(lp["mlp"], m_in, cfg.mlp_act, cfg.mlp_gated)
         return (h + m_out, ks, vs), None
@@ -891,8 +894,7 @@ def serve_step_paged_spliced(params: Params, k_slab: jax.Array,
                              v_slab: jax.Array, block_table: jax.Array,
                              lengths: jax.Array, page_delta: jax.Array,
                              page_valid: jax.Array,
-                             inputs: Dict[str, jax.Array], cfg: ArchConfig, *,
-                             kernel_mode: Optional[str] = None,
+                             inputs: Dict[str, jax.Array], cfg: ArchConfig,
                              ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """``serve_step_paged`` over a block table that mixes fresh pages
     with **spliced** chunk-KV pages (reordered RoPE per TurboRAG).
@@ -905,8 +907,9 @@ def serve_step_paged_spliced(params: Params, k_slab: jax.Array,
     masked via ``page_valid[b, blk]`` live-token counts.  Fresh pages
     carry ``delta = 0`` and ``valid = ps`` — with an all-fresh table this
     is numerically ``serve_step_paged``.  The new token is roped and
-    scattered at layout position ``lengths`` exactly as in the unspliced
-    form.  Same plain global-causal GQA restriction.
+    scattered at layout position ``lengths``, and ``inputs`` read, exactly
+    as in the unspliced form.  Same plain global-causal GQA restriction.  Attention runs
+    through the jnp oracle ``ops.flash_decode_spliced`` (no kernel yet).
     """
     from repro.kernels import ops as kernel_ops
 
@@ -915,7 +918,6 @@ def serve_step_paged_spliced(params: Params, k_slab: jax.Array,
         raise ValueError(
             "serve_step_paged_spliced supports plain global-causal GQA archs "
             f"only (family {family_kind(cfg)!r}, attn_kind {cfg.attn_kind!r})")
-    mode = kernel_ops.DEFAULT_MODE if kernel_mode is None else kernel_mode
 
     tok = inputs["token"]
     x = embed_tokens(params, tok[:, None], cfg)
@@ -949,13 +951,13 @@ def serve_step_paged_spliced(params: Params, k_slab: jax.Array,
         out = kernel_ops.flash_decode_spliced(
             q[:, 0].reshape(B, KVH, H // KVH, Dh), kl, vl,
             block_table, lengths + 1, page_delta, page_valid,
-            rope_fraction=cfg.rope_fraction, rope_theta=cfg.rope_theta,
-            mode=mode)
+            rope_fraction=cfg.rope_fraction, rope_theta=cfg.rope_theta)
         out = out.reshape(B, 1, H, Dh).astype(h.dtype)
         h = h + jnp.einsum("bshk,hkd->bsd", out, ap["wo"])
         m_in = rms_norm(h, lp["mlp_norm"], cfg.norm_eps)
         if cfg.moe is not None:
-            m_out, _ = moe_mod.moe_forward(lp["mlp"], m_in, cfg)
+            m_out, _ = moe_mod.moe_forward(lp["mlp"], m_in, cfg,
+                                           live_rows=inputs.get("live_rows"))
         else:
             m_out = mlp_forward(lp["mlp"], m_in, cfg.mlp_act, cfg.mlp_gated)
         return (h + m_out, ks, vs), None

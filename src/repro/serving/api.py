@@ -443,7 +443,8 @@ class TeleRAGServer:
                  dispatch: Optional[DispatchPolicy] = None,
                  continuous: bool = False,
                  trace: Optional[FlightRecorder] = None,
-                 wall_clock=None):
+                 wall_clock=None,
+                 devices: Optional[Sequence] = None):
         """``scheduler=None`` forms FIFO micro-batches and routes them
         round-robin (persistent across waves); a ``SchedulerPolicy``
         enables the paper's similarity grouping + cache-aware routing.
@@ -478,7 +479,10 @@ class TeleRAGServer:
         overhead, host-search calibration).  The default is the
         deterministic ``obs.clock.EventClock`` — identical inputs give
         identical traces; launch drivers that want real measurement
-        pass ``obs.clock.SystemClock()``."""
+        pass ``obs.clock.SystemClock()``.
+
+        ``devices`` gives each replica its own jax device (one per
+        replica; None = every replica on the first device)."""
         self.index = index
         self.cfg = cfg
         self.continuous = bool(continuous)
@@ -490,9 +494,14 @@ class TeleRAGServer:
         self.wall = wall_clock if wall_clock is not None \
             else EventClock(self.recorder)
         self.metrics = MetricsRegistry()
+        if devices is not None and len(devices) != num_replicas:
+            raise ValueError(f"{len(devices)} devices for {num_replicas} "
+                             f"replicas")
         self.engines = [TeleRAGEngine(index, cfg, arch,
-                                      wall_clock=self.wall)
-                        for _ in range(num_replicas)]
+                                      wall_clock=self.wall,
+                                      device=(None if devices is None
+                                              else devices[i]))
+                        for i in range(num_replicas)]
         for i, eng in enumerate(self.engines):
             eng.attach_recorder(self.recorder, i)
         # under continuous dispatch the runtime's wave former IS the

@@ -36,6 +36,7 @@ from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.data.chunk_kv import ChunkKVStore
@@ -81,6 +82,7 @@ class DecodeRunner:
         are spliced into its paged lease from precomputed pages instead
         of being re-prefilled."""
         self.params = params
+        self._params: Dict[int, object] = {}   # replica -> params on its device
         self.cfg = cfg
         self.max_len = max_len
         self.max_steps = max_steps
@@ -95,9 +97,14 @@ class DecodeRunner:
         self._dense_step = None
         self._paged_step = None
         self._spliced_step = None
+        self._rows = 0                         # attach(): server micro_batch
+        self._pad_slot: Dict[int, int] = {}    # replica -> scratch KV page
         # per-request generated tokens, per round: the differential
         # parity suite pins these exactly equal across paged/dense runs
         self.generated: Dict[int, List[Tuple[int, ...]]] = {}
+        # measured seconds per decode step, one entry per wave that
+        # stepped (first-call compilation lands in a shape's first wave)
+        self.wave_step_seconds: List[float] = []
         self.stats = {"paged_waves": 0, "dense_waves": 0,
                       "paged_appends": 0, "dense_steps": 0,
                       "spliced_waves": 0}
@@ -105,9 +112,13 @@ class DecodeRunner:
     # -- wiring --------------------------------------------------------------
     def attach(self, server) -> "DecodeRunner":
         """Bind to a constructed ``TeleRAGServer``: one pool-backed KV
-        manager per replica engine (paged mode also allocates the slab),
-        clock from the server's ``wall_clock`` injection point."""
+        manager per replica engine (paged mode also allocates the slab)
+        and the params, both on the engine's device; clock from the
+        server's ``wall_clock`` injection point."""
         self.clock = server.wall
+        # paged waves run at one row count (the server's micro-batch), so
+        # the decode step compiles once instead of once per wave size
+        self._rows = server.micro_batch or 0
         eng0 = server.engines[0]
         want = (eng0.cfg.paged_decode if self._paged_override is None
                 else self._paged_override)
@@ -118,10 +129,13 @@ class DecodeRunner:
         self.chunk_docs = eng0.cfg.chunk_kv_docs
         for r, eng in enumerate(server.engines):
             kv = KVCacheManager(self.cfg, pool=eng.pool)
+            self._params[r] = jax.device_put(self.params, eng.device)
             if self.paged:
-                blocks = -(-self.max_len // self.page_size)
-                kv.init_paged(num_pages=self.slab_seqs * blocks,
+                kv.init_paged(num_pages=self.slab_pages,
                               page_size=self.page_size)
+                # one page outside the free list: padding rows write and
+                # read only there, so they never touch a leased page
+                self._pad_slot[r] = kv.slab.free.pop(0)
             self._kv[r] = kv
             if want_chunk:
                 cache = ChunkKVCache(kv, self.chunk_store)
@@ -132,16 +146,16 @@ class DecodeRunner:
         if self.paged:
             cfg, mode = self.cfg, self._kernel_mode
             self._paged_step = jax.jit(
-                lambda p, k, v, bt, lens, tok: tf.serve_step_paged(
-                    p, k, v, bt, lens, {"token": tok}, cfg,
-                    kernel_mode=mode),
+                lambda p, k, v, bt, lens, tok, live: tf.serve_step_paged(
+                    p, k, v, bt, lens, {"token": tok, "live_rows": live},
+                    cfg, kernel_mode=mode),
                 donate_argnums=(1, 2))
             if want_chunk:
                 self._spliced_step = jax.jit(
-                    lambda p, k, v, bt, lens, dl, vd, tok:
+                    lambda p, k, v, bt, lens, dl, vd, tok, live:
                         tf.serve_step_paged_spliced(
-                            p, k, v, bt, lens, dl, vd, {"token": tok}, cfg,
-                            kernel_mode=mode),
+                            p, k, v, bt, lens, dl, vd,
+                            {"token": tok, "live_rows": live}, cfg),
                     donate_argnums=(1, 2))
         else:
             cfg = self.cfg
@@ -149,9 +163,19 @@ class DecodeRunner:
                 lambda p, c, i: tf.serve_step(p, c, i, cfg))
         return self
 
+    @property
+    def slab_pages(self) -> int:
+        """KV slab page slots: ``slab_seqs`` sequences of ``max_len``
+        plus the padding rows' scratch page."""
+        return self.slab_seqs * -(-self.max_len // self.page_size) + 1
+
     def kv(self, replica: int = 0) -> KVCacheManager:
         """The replica's KV manager (attach() must have run)."""
         return self._kv[replica]
+
+    def replica_params(self, replica: int = 0):
+        """The params the replica decodes with, on its engine's device."""
+        return self._params[replica]
 
     def chunk(self, replica: int = 0) -> Optional[ChunkKVCache]:
         """The replica's chunk-KV residency cache (None when chunk-KV
@@ -170,6 +194,7 @@ class DecodeRunner:
         steps = min(max(gen_tokens, default=0), self.max_steps)
         kv = self._kv[replica]
         tenant = records[0].tenant
+        params = self._params[replica]
         if self.paged:
             row_docs = None
             chunk = self._chunk.get(replica)
@@ -181,10 +206,13 @@ class DecodeRunner:
                     [int(d) for d in r.result.doc_ids[-1]][:self.chunk_docs]
                     if r.result.doc_ids else []
                     for r in records]
-            toks, per_step = self._run_paged(kv, n, steps, tenant,
-                                             chunk=chunk, row_docs=row_docs)
+            toks, per_step = self._run_paged(replica, params, kv, n, steps,
+                                             tenant, chunk=chunk,
+                                             row_docs=row_docs)
         else:
-            toks, per_step = self._run_dense(kv, n, steps, tenant)
+            toks, per_step = self._run_dense(params, kv, n, steps, tenant)
+        if steps:
+            self.wave_step_seconds.append(per_step)
         for j, r in enumerate(records):
             self.generated.setdefault(r.request_id, []).append(
                 tuple(int(t[j]) for t in toks))
@@ -193,7 +221,17 @@ class DecodeRunner:
                             seconds=per_step * (min(g, steps) if g else 0))
                 for r, g in zip(records, gen_tokens)]
 
-    def _run_paged(self, kv: KVCacheManager, n: int, steps: int,
+    def _padded_tables(self, replica: int, tables, n: int, rows: int):
+        """The lease's (block_table, lengths[, page_delta, page_valid])
+        grown from ``n`` to ``rows`` rows: padding rows hold one token on
+        the replica's scratch page (lengths 0, delta 0, valid = ps)."""
+        fill = (self._pad_slot[replica], 0, 0, self.page_size)
+        return tuple(np.concatenate([t, np.full((rows - n,) + t.shape[1:],
+                                                f, t.dtype)])
+                     for t, f in zip(tables, fill))
+
+    def _run_paged(self, replica: int, params, kv: KVCacheManager, n: int,
+                   steps: int,
                    tenant: str, *, chunk: Optional[ChunkKVCache] = None,
                    row_docs: Optional[List[List[int]]] = None):
         """Block-table decode: acquire_paged -> (serve_step_paged +
@@ -216,18 +254,21 @@ class DecodeRunner:
                                                            tenant=tenant)
                 if kv.splice_paged(lease, row_chunks):
                     self.stats["spliced_waves"] += 1
-            tok = jnp.zeros((n,), jnp.int32)
+            rows = max(n, self._rows)
+            tok = jnp.zeros((rows,), jnp.int32)
+            # padding rows stay out of MoE expert capacity, so a wave's
+            # tokens do not depend on the micro-batch it is padded to
+            live = jax.device_put(np.int32(n), kv.device)
             t0 = self.clock.perf()
             for _ in range(steps):
-                if lease.spliced_pages:
-                    bt, lens, dl, vd = lease.device_splice_tables()
-                    logits, kv.slab.k, kv.slab.v = self._spliced_step(
-                        self.params, kv.slab.k, kv.slab.v, bt, lens, dl, vd,
-                        tok)
-                else:
-                    bt, lens = lease.device_tables()
-                    logits, kv.slab.k, kv.slab.v = self._paged_step(
-                        self.params, kv.slab.k, kv.slab.v, bt, lens, tok)
+                tables = lease.tables()
+                if rows > n:
+                    tables = self._padded_tables(replica, tables, n, rows)
+                tables = [jax.device_put(t, kv.device) for t in tables]
+                step = (self._spliced_step if lease.spliced_pages
+                        else self._paged_step)
+                logits, kv.slab.k, kv.slab.v = step(
+                    params, kv.slab.k, kv.slab.v, *tables, tok, live)
                 kv.append_paged(lease)      # scatter was fused in-jit
                 self.stats["paged_appends"] += 1
                 tok = sample(logits)
@@ -245,7 +286,7 @@ class DecodeRunner:
                 chunk.release_rows(pinned)
         return toks, per_step
 
-    def _run_dense(self, kv: KVCacheManager, n: int, steps: int,
+    def _run_dense(self, params, kv: KVCacheManager, n: int, steps: int,
                    tenant: str):
         """The pinned legacy path: one dense [n, max_len] bucket."""
         self.stats["dense_waves"] += 1
@@ -256,7 +297,7 @@ class DecodeRunner:
             t0 = self.clock.perf()
             for t in range(steps):
                 logits, lease.cache = self._dense_step(
-                    self.params, lease.cache,
+                    params, lease.cache,
                     {"token": tok, "pos": jnp.full((n,), t, jnp.int32)})
                 self.stats["dense_steps"] += 1
                 tok = sample(logits)

@@ -16,10 +16,12 @@ Mode behaviour lives in serving/policies.py (``RetrievalPolicy``); the
 engine owns the resources and delegates, and async H2D copies go through
 ``core/transfer.py``'s ``TransferEngine`` as timestamped events.
 
-Quantities that are *measured* on this container: bytes moved, cluster
-hit/miss sets, search results, scheduler quality. Wall-clock is modeled
-from the HardwareProfile (CPU-only container; see DESIGN.md §7) — except
-host search, whose per-cluster cost t_cc can be measured and plugged in.
+Quantities that are *measured* wherever it runs: bytes moved, cluster
+hit/miss sets, search results, scheduler quality.  The event clock's
+round windows are modeled from the HardwareProfile of the engine's
+device — except host search, whose per-cluster cost t_cc can be
+measured, and decode windows, which a ``DecodeRunner`` measures.
+Modeled windows are never device timings, on the CPU or on the chip.
 """
 
 from __future__ import annotations
@@ -28,11 +30,12 @@ import dataclasses
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.core import budget as budget_mod
-from repro.core.budget import HardwareProfile, TPU_V5E
+from repro.core.budget import HardwareProfile
 from repro.core.cache import CacheConfig, ClusterCache
 from repro.core.datastore import PagedClusters
 from repro.core.hybrid_search import RetrievalResult, host_search
@@ -74,7 +77,8 @@ class EngineConfig:
     chunk_kv_prefetch_pages: int = 16             # lookahead chunk-page burst
                                                   # per round (0 = no chunk
                                                   # prefetch)
-    hw: HardwareProfile = TPU_V5E
+    hw: Optional[HardwareProfile] = None          # None => the profile of the
+                                                  # engine's device kind
     chips: int = 1
     t_cc: Optional[float] = None                  # None => bytes/host_mem_bw
     seed: int = 0
@@ -145,12 +149,20 @@ class RequestResult:
 
 
 class TeleRAGEngine:
-    """Single-replica engine: prefetch buffer + cache + hybrid retrieval."""
+    """Single-replica engine: prefetch buffer + cache + hybrid retrieval.
+
+    ``device`` is the one jax device the replica's pool (and the KV slab
+    and params a ``DecodeRunner`` attaches) live on; None = the first
+    device."""
 
     def __init__(self, index: IVFIndex, cfg: EngineConfig,
                  arch: Optional[ArchConfig] = None, *,
-                 wall_clock=None):
+                 wall_clock=None, device: Optional[jax.Device] = None):
         self.index = index
+        self.device = device if device is not None else jax.devices()[0]
+        if cfg.hw is None:
+            cfg = dataclasses.replace(
+                cfg, hw=budget_mod.hardware_profile(self.device))
         self.cfg = cfg
         self.arch = arch
         # every engine records; a standalone engine owns its recorder,
@@ -189,14 +201,17 @@ class TeleRAGEngine:
         """One HBM arbiter per replica: page pool + byte ledger +
         admission control, shared by prefetch buffer and KV cache."""
         cfg = self.cfg
-        self.ledger = MemoryLedger(
-            capacity_bytes=int(cfg.hw.hbm_bytes * cfg.chips))
+        # a device that reports its memory sets the capacity; the
+        # profile's HBM stands in only where none is reported (CPU)
+        stats = self.device.memory_stats() or {}
+        capacity = stats.get("bytes_limit", cfg.hw.hbm_bytes * cfg.chips)
+        self.ledger = MemoryLedger(capacity_bytes=int(capacity))
         if self.arch is not None:
             # resident model weights compete for the same HBM (bf16)
             self.ledger.charge("weights", self.arch.param_count() * 2)
         self.pool = DevicePagePool(
             self.index.paged, cfg.pool_pages or cfg.buffer_pages,
-            ledger=self.ledger)
+            ledger=self.ledger, device=self.device)
         self.buffer = PrefetchBuffer(self.index.paged, pool=self.pool,
                                      quota_pages=cfg.buffer_pages)
         for tenant, share in (cfg.tenant_shares or {}).items():

@@ -76,6 +76,8 @@ class KVCacheManager:
         self.cfg = cfg
         self.dtype = dtype
         self.pool = pool
+        # the KV slab lives beside the pool it is accounted against
+        self.device = pool.device if pool is not None else None
         self._pool_buckets: Dict[Tuple[int, int], Tuple[dict, Optional[PageLease]]] = {}
         self._nbytes_memo: Dict[Tuple[int, int], int] = {}
         self.slab: Optional["KVPageSlab"] = None   # init_paged() creates it
@@ -227,7 +229,8 @@ class KVCacheManager:
         KVH, Dh = self.cfg.num_kv_heads, self.cfg.resolved_head_dim
         shape = (L, num_pages, page_size, KVH, Dh)
         self.slab = KVPageSlab(
-            k=jnp.zeros(shape, self.dtype), v=jnp.zeros(shape, self.dtype),
+            k=jnp.zeros(shape, self.dtype, device=self.device),
+            v=jnp.zeros(shape, self.dtype, device=self.device),
             page_size=page_size, free=list(range(num_pages)))
         return self.slab
 
@@ -306,7 +309,10 @@ class KVCacheManager:
             slab.k, slab.v = _append_token(
                 slab.k, slab.v, jnp.asarray(k_new), jnp.asarray(v_new),
                 jnp.asarray(slots), jnp.asarray(offs, np.int32))
-        lease.lengths += 1
+        # rebind, never `+=`: a step dispatched with the lease's tables()
+        # may still read the old buffer (an upload to the CPU adopts an
+        # aligned host buffer without a copy)
+        lease.lengths = lease.lengths + 1
         self._record("kv.append", lease.batch, lease.max_len, 0,
                      lease.tenant, lease_id=lease.lease_id,
                      pages=lease.block_table.size,
@@ -483,17 +489,12 @@ class PagedCacheLease:
     page_valid: Optional[np.ndarray] = None
     spliced_pages: int = 0
 
-    def device_tables(self) -> Tuple[jax.Array, jax.Array]:
-        """(block_table, lengths) as device arrays for the kernel."""
-        return jnp.asarray(self.block_table), jnp.asarray(self.lengths)
-
-    def device_splice_tables(self) -> Tuple[jax.Array, jax.Array,
-                                            jax.Array, jax.Array]:
-        """(block_table, lengths, page_delta, page_valid) as device
-        arrays — the ``serve_step_paged_spliced`` operands.  Requires a
-        prior ``splice_paged`` (which materializes delta/valid)."""
-        if self.page_delta is None or self.page_valid is None:
-            raise RuntimeError("lease has no splice tables: call "
-                               "KVCacheManager.splice_paged first")
-        return (jnp.asarray(self.block_table), jnp.asarray(self.lengths),
-                jnp.asarray(self.page_delta), jnp.asarray(self.page_valid))
+    def tables(self) -> Tuple[np.ndarray, ...]:
+        """The decode step's host-side table operands: (block_table,
+        lengths) for ``serve_step_paged``, plus (page_delta, page_valid)
+        for ``serve_step_paged_spliced`` once ``splice_paged`` spliced
+        pages in."""
+        if self.spliced_pages:
+            return (self.block_table, self.lengths, self.page_delta,
+                    self.page_valid)
+        return self.block_table, self.lengths

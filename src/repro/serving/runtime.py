@@ -302,7 +302,10 @@ class RetrievalRuntime:
         # reform_wave hook), else the base EDF/tenant-aware default
         self._former = scheduler if scheduler is not None \
             else SchedulerPolicy()
-        self._rng = np.random.default_rng(engine.cfg.seed + 1)
+        # rewrite noise is keyed by (seed, request, round, sub-query), so
+        # a request's retrieval queries do not depend on which replica
+        # served it or in what order
+        self._rewrite_seed = engine.cfg.seed + 1
         self._now = 0.0                      # drained clock across run()s
         self._seq = itertools.count()
         self._gid = itertools.count()
@@ -799,10 +802,13 @@ class RetrievalRuntime:
                 for k, j in enumerate(ret):
                     sigma = members[j].trace.rewrite_sigma
                     nq = members[j].plan[rounds[j]][1]
-                    for _ in range(nq):
+                    for qi in range(nq):
+                        rng = np.random.default_rng(
+                            (self._rewrite_seed, members[j].request_id,
+                             rounds[j], qi))
                         q_out_rows.append(
                             synthetic_rewrite(act_q[k][None, :], sigma,
-                                              self._rng)[0]
+                                              rng)[0]
                             if sigma > 0 else act_q[k])
                         owners.append(j)
                 q_out = np.stack(q_out_rows)

@@ -103,8 +103,7 @@ def make_manual_dp_train_step(cfg: ArchConfig, opt_cfg: OptConfig,
         params, opt_state, om = adamw_update(params, grads, opt_state, opt_cfg)
         return params, opt_state, err, {"loss": loss, **om}
 
-    from repro.compat import shard_map
-    shard_step = shard_map(
+    shard_step = jax.shard_map(
         step, mesh=mesh,
         in_specs=(P(), P(), P(), P(axis)),
         out_specs=(P(), P(), P(), P()),

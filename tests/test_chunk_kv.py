@@ -29,6 +29,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
+from hypothesis import HealthCheck, given, settings
+from hypothesis import strategies as st
 
 from repro.analysis import check_recorder
 from repro.configs import get_arch
@@ -151,7 +153,7 @@ def _rand_qkv(rng, B, S, KVH, G, Dh):
 
 def test_spliced_all_fresh_equals_dense_ref():
     """delta=0 / valid=ps degenerates to plain paged attention — and the
-    ops entry point resolves modes but runs the same oracle."""
+    ops entry point is that oracle: it takes no kernel mode."""
     rng = np.random.default_rng(4)
     B, S, KVH, G, Dh, ps = 2, 12, 2, 2, 16, 4
     q, k1, v1 = _rand_qkv(rng, B, S, KVH, G, Dh)
@@ -167,12 +169,12 @@ def test_spliced_all_fresh_equals_dense_ref():
     want = ref.flash_decode_ref(q, k, v, lengths - 1, 0)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
-    out2 = ops.flash_decode_spliced(q, kp, vp, bt, lengths, delta, valid,
-                                    mode="ref")
+    out2 = ops.flash_decode_spliced(q, kp, vp, bt, lengths, delta, valid)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(out2))
-    with pytest.raises(ValueError):
+    assert ops.resolved_modes()["flash_decode_spliced"] == "ref"
+    with pytest.raises(TypeError):
         ops.flash_decode_spliced(q, kp, vp, bt, lengths, delta, valid,
-                                 mode="not_a_mode")
+                                 mode="kernel")
 
 
 def test_spliced_multi_chunk_delta_equals_layout_rope():
@@ -308,9 +310,6 @@ def test_spliced_hole_slots_are_garbage_invariant():
 
 def test_hypothesis_spliced_kernel_vs_loopy_oracle():
     """Randomized ragged sweep of the kernel oracle pair."""
-    pytest.importorskip("hypothesis")
-    from hypothesis import HealthCheck, given, settings
-    from hypothesis import strategies as st
 
     @settings(max_examples=20, deadline=None,
               suppress_health_check=list(HealthCheck))
@@ -360,7 +359,7 @@ def _spliced_greedy(params, cfg, mgr, lease, steps):
     logits_seq, toks = [], []
     tok = jnp.zeros((lease.batch,), jnp.int32)
     for _ in range(steps):
-        bt, lens, dl, vd = lease.device_splice_tables()
+        bt, lens, dl, vd = map(jnp.asarray, lease.tables())
         logits, mgr.slab.k, mgr.slab.v = tf.serve_step_paged_spliced(
             params, mgr.slab.k, mgr.slab.v, bt, lens, dl, vd,
             {"token": tok}, cfg)
@@ -472,7 +471,7 @@ def test_spliced_decode_ragged_chunk_garbage_invariant(tparams):
     assert lease.page_valid[0][1] == 1
     hole_slot = int(lease.block_table[0, 1])
     k0, v0 = mgr.slab.k, mgr.slab.v
-    bt, lens, dl, vd = lease.device_splice_tables()
+    bt, lens, dl, vd = map(jnp.asarray, lease.tables())
     tok = jnp.zeros((1,), jnp.int32)
     clean, _, _ = tf.serve_step_paged_spliced(
         tparams, k0, v0, bt, lens, dl, vd, {"token": tok}, TINY)
@@ -489,9 +488,6 @@ def test_hypothesis_spliced_decode_vs_oracles(tparams):
     """Randomized aligned multi-chunk orderings: greedy tokens exact
     and logits within tolerance of the assembled-cache oracle (which
     for a single chunk IS full re-prefill)."""
-    pytest.importorskip("hypothesis")
-    from hypothesis import HealthCheck, given, settings
-    from hypothesis import strategies as st
 
     @settings(max_examples=8, deadline=None,
               suppress_health_check=list(HealthCheck))
@@ -572,7 +568,7 @@ def test_splice_paged_rejects_bad_rows(tparams):
 
 def _pool_cache(small_index, tparams, *, slab_pages=32, pool_pages=128,
                 docs=(1, 2, 3), lens=(5, 8, 9), cluster_of=None):
-    pool = DevicePagePool(small_index.paged, pool_pages, jnp.float32)
+    pool = DevicePagePool(small_index.paged, pool_pages)
     pool.recorder = FlightRecorder()
     pool.replica_id = 0
     mgr = KVCacheManager(TINY, dtype=jnp.float32, pool=pool)

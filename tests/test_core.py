@@ -237,6 +237,22 @@ def test_budget_case1_and_headroom():
     assert b_rwkv <= b
 
 
+@pytest.mark.parametrize("kind,known", [("TPU v5 lite", True),
+                                        ("cpu", True),
+                                        ("TPU v4", False)])
+def test_hardware_profile_by_device_kind(kind, known):
+    """The profile follows the device's kind; an unknown kind is an
+    error, never a silent v5e."""
+    from types import SimpleNamespace
+    from repro.core.budget import hardware_profile
+    dev = SimpleNamespace(device_kind=kind)
+    if known:
+        assert hardware_profile(dev) is core.TPU_V5E
+    else:
+        with pytest.raises(KeyError, match="TPU v4"):
+            hardware_profile(dev)
+
+
 def test_budget_case2_interior_minimum():
     # a steep miss-rate curve rewards prefetching past the window
     fn = core.empirical_miss_curve([0, 1e9, 2e9, 4e9], [0.0, 0.8, 0.97, 1.0])

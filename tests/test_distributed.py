@@ -4,11 +4,10 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 import repro.distributed as dist
 from repro.configs import get_arch
-from repro.launch.mesh import _axis_type_kwargs
 from repro.launch.roofline import collective_bytes, model_flops_for
 
 
@@ -16,7 +15,8 @@ def fake_mesh(shape=(2, 2), axes=("data", "model")):
     # abstract mesh over fake devices (no jax device init needed for specs)
     devs = np.array(jax.devices() * (int(np.prod(shape)) // len(jax.devices())
                                      + 1))[:int(np.prod(shape))]
-    return Mesh(devs.reshape(shape), axes, **_axis_type_kwargs(len(axes)))
+    return Mesh(devs.reshape(shape), axes,
+                axis_types=(AxisType.Auto,) * len(axes))
 
 
 def test_spec_for_divisibility_and_duplicates():
@@ -91,9 +91,8 @@ def test_cost_analysis_is_per_device():
     mesh = fake_mesh((1, 1))
     w = jnp.ones((256, 256), jnp.float32)
     x = jnp.ones((64, 256), jnp.float32)
-    from repro.compat import compiled_cost_analysis
     c = jax.jit(lambda a, b: a @ b.T).lower(x, w).compile()
-    flops = compiled_cost_analysis(c)["flops"]
+    flops = c.cost_analysis()["flops"]
     assert flops == pytest.approx(2 * 64 * 256 * 256, rel=0.01)
 
 

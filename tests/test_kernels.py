@@ -177,8 +177,11 @@ def test_env_mode_flips_whole_stack(monkeypatch):
     q = jnp.asarray(rng.standard_normal((2, 16)), jnp.float32)
     monkeypatch.setenv(ops.MODE_ENV_VAR, "interpret")
     si, ii = ops.centroid_probe(cents, q, 4)
+    # the entry point reports the plane it ran on
+    assert ops.resolved_modes()["centroid_probe"] == "kernel_interpret"
     monkeypatch.delenv(ops.MODE_ENV_VAR)
     sr, ir = ops.centroid_probe(cents, q, 4, mode="ref")
+    assert ops.resolved_modes()["centroid_probe"] == "ref"
     np.testing.assert_allclose(np.asarray(si), np.asarray(sr), rtol=1e-5)
     np.testing.assert_array_equal(np.asarray(ii), np.asarray(ir))
 

@@ -76,6 +76,41 @@ def test_smoke_train_step_decreases_loss(arch):
     assert min(losses[4:]) < losses[0] + 0.1, (arch, losses)
 
 
+@pytest.mark.parametrize("live", [3, 5, 6])
+def test_moe_padding_rows_take_no_expert_capacity(live):
+    """Rows past ``live_rows`` are padding: the live rows' output equals
+    the layer on the live rows alone, including which tokens capacity
+    drops.  Every row is the same token, as at a wave's first decode
+    step, so all pick the same experts and capacity binds."""
+    from repro.models import moe
+    cfg = get_arch("granite-moe-3b-a800m").reduced()
+    lp = jax.tree.map(lambda a: a[0], tf.init_params(
+        cfg, jax.random.PRNGKey(0), dtype=jnp.float32)["layers"]["mlp"])
+    x = jnp.broadcast_to(jax.random.normal(jax.random.PRNGKey(1),
+                                           (1, 1, cfg.d_model)),
+                         (8, 1, cfg.d_model))
+    want, _ = moe.moe_forward(lp, x[:live], cfg)
+    got, _ = moe.moe_forward(lp, x, cfg, live_rows=jnp.int32(live))
+    np.testing.assert_allclose(np.asarray(got[:live]), np.asarray(want),
+                               rtol=1e-6, atol=1e-6)
+    padded, _ = moe.moe_forward(lp, x, cfg)
+    assert not np.allclose(np.asarray(padded[:live]), np.asarray(want),
+                           atol=1e-6), "capacity never bound: no check"
+
+
+def test_moe_live_rows_needs_one_dispatch_group():
+    import dataclasses
+    from repro.models import moe
+    cfg = get_arch("granite-moe-3b-a800m").reduced()
+    cfg = dataclasses.replace(cfg, moe=dataclasses.replace(cfg.moe,
+                                                           group_size=4))
+    lp = jax.tree.map(lambda a: a[0], tf.init_params(
+        cfg, jax.random.PRNGKey(0), dtype=jnp.float32)["layers"]["mlp"])
+    with pytest.raises(ValueError, match="one dispatch group"):
+        moe.moe_forward(lp, jnp.zeros((8, 1, cfg.d_model)), cfg,
+                        live_rows=jnp.int32(3))
+
+
 PARITY_ARCHS = ["llama3-8b", "gemma2-27b", "minicpm3-4b", "granite-20b",
                 "rwkv6-3b", "zamba2-2.7b", "musicgen-large",
                 "granite-moe-3b-a800m"]

@@ -63,7 +63,7 @@ def test_append_paged_then_attention_matches_dense():
         mgr.append_paged(lease, ks[t], vs[t])
     assert (lease.lengths == steps).all()
     q = jnp.asarray(rng.standard_normal((B, KVH, G, Dh)), jnp.float32)
-    bt, lens = lease.device_tables()
+    bt, lens = map(jnp.asarray, lease.tables())
     for l in range(L):
         kp, vp = slab.layer(l)
         out_p = ops.flash_decode_paged(q, kp, vp, bt, lens,
@@ -76,7 +76,7 @@ def test_append_paged_then_attention_matches_dense():
 
 
 def test_paged_pool_accounting_and_exhaustion(small_index):
-    pool = DevicePagePool(small_index.paged, 64, jnp.float32)
+    pool = DevicePagePool(small_index.paged, 64)
     mgr = KVCacheManager(tiny_cfg(), dtype=jnp.float32, pool=pool)
     mgr.init_paged(num_pages=16, page_size=4)
     lease = mgr.acquire_paged(2, 8, tenant="acme")

@@ -23,12 +23,16 @@ import math
 
 import numpy as np
 import jax
+import jax.numpy as jnp
 import pytest
+from hypothesis import HealthCheck, given, settings
+from hypothesis import strategies as st
 
 from repro.analysis import check_recorder
 from repro.configs import get_arch
 from repro.models import transformer as tf
-from repro.serving import (DecodeRunner, EngineConfig, RagRequest,
+from repro.serving import (DecodeRunner, EngineConfig, KVCacheManager,
+                           RagRequest,
                            RequestState, TeleRAGServer, make_traces,
                            supports_paged_decode)
 from repro.serving.trace import RequestTrace, StageTrace
@@ -36,6 +40,7 @@ from tests.conftest import unit_queries
 
 ARCH = get_arch("llama3-8b")
 CFG = ARCH.reduced()
+MOE_ARCH = get_arch("granite-moe-3b-a800m")
 
 
 @pytest.fixture(scope="module")
@@ -45,17 +50,18 @@ def params():
 
 def _serve(small_index, q, traces, *, paged, params, micro_batch=3,
            max_len=24, max_steps=6, page_size=4, slab_seqs=None,
-           arrivals=None, tenants=None):
-    """One full serve run; returns (runner, server, responses)."""
+           arrivals=None, tenants=None, arch=ARCH):
+    """One full serve run of ``arch`` reduced; returns (runner, server,
+    responses)."""
     n = len(traces)
-    runner = DecodeRunner(params, CFG, max_len=max_len,
+    runner = DecodeRunner(params, arch.reduced(), max_len=max_len,
                           max_steps=max_steps, page_size=page_size,
                           slab_seqs=slab_seqs if slab_seqs is not None
                           else n + 2)
     srv = TeleRAGServer(small_index, EngineConfig(
         nprobe=8, top_k=3, buffer_pages=256, pool_pages=4096,
         lookahead_rank=16, kernel_mode="ref", chips=8, seed=7,
-        paged_decode=paged), 1, ARCH, micro_batch=micro_batch,
+        paged_decode=paged), 1, arch, micro_batch=micro_batch,
         include_tail=True, decode_hook=runner, continuous=True)
     runner.attach(srv)
     resp = srv.serve([RagRequest(
@@ -142,6 +148,28 @@ def test_pipeline_parity_paged_vs_dense(small_store, small_index, rng,
     assert rp.stats["paged_appends"] > 0
     assert not rd.paged and rd.stats["dense_waves"] > 0
     assert rd.stats["paged_waves"] == 0
+    _assert_full_parity(rp, respp, rd, respd)
+    _assert_kv_drained((rp, sp), (rd, sd))
+
+
+@pytest.mark.parametrize("pipeline,n,micro_batch", [
+    ("hyde", 7, 4),           # waves of 4 and 3: the 3 decode on 4 rows
+    ("iter", 5, 4),           # multi-round waves shorter than 4
+])
+def test_moe_parity_paged_vs_dense_on_short_waves(small_store, small_index,
+                                                  rng, pipeline, n,
+                                                  micro_batch):
+    """An MoE arch's expert capacity follows a wave's live rows: a paged
+    wave shorter than the micro-batch decodes on padded rows, the dense
+    wave on exactly its rows, and the tokens still pin exactly."""
+    params = tf.init_params(MOE_ARCH.reduced(), jax.random.PRNGKey(0))
+    q = unit_queries(small_store, rng, n)
+    traces = make_traces(pipeline, n, seed=3)
+    kw = dict(params=params, micro_batch=micro_batch, max_steps=6,
+              page_size=4, arch=MOE_ARCH)
+    rp, sp, respp = _serve(small_index, q, traces, paged=True, **kw)
+    rd, sd, respd = _serve(small_index, q, traces, paged=False, **kw)
+    assert rp.paged and not rd.paged
     _assert_full_parity(rp, respp, rd, respd)
     _assert_kv_drained((rp, sp), (rd, sd))
 
@@ -272,10 +300,6 @@ def test_randomized_shape_parity(small_store, small_index, params):
     """Hypothesis-driven differential sweep over batch shapes, page
     sizes and step counts (ragged batches, boundary-crossing lengths,
     partially-filled last blocks)."""
-    pytest.importorskip("hypothesis")
-    from hypothesis import HealthCheck, given, settings
-    from hypothesis import strategies as st
-
     @settings(max_examples=5, deadline=None,
               suppress_health_check=list(HealthCheck))
     @given(pipeline=st.sampled_from(["hyde", "iter", "irg", "flare"]),
@@ -295,3 +319,36 @@ def test_randomized_shape_parity(small_store, small_index, params):
         _assert_kv_drained((rp, sp), (rd, sd))
 
     check()
+
+
+def _aligned_int32(values, align: int = 64) -> np.ndarray:
+    """An int32 array whose buffer starts on an ``align``-byte boundary:
+    the buffers ``jnp.asarray`` adopts without a copy on the CPU."""
+    n = len(values)
+    raw = np.zeros(n + align // 4, np.int32)
+    off = (-raw.ctypes.data % align) // 4
+    out = raw[off:off + n]
+    out[:] = values
+    assert out.ctypes.data % align == 0
+    return out
+
+
+def test_step_sees_pre_append_lengths_on_aligned_buffers():
+    """A step dispatched with the lease's ``tables()`` must read the
+    lengths as they were at dispatch, even when ``append_paged`` runs
+    before the step does and the lease's buffer is one the device may
+    alias (both uploads below adopt such a buffer on the CPU)."""
+    kv = KVCacheManager(CFG)
+    kv.init_paged(num_pages=8, page_size=4)
+    lease = kv.acquire_paged(2, 8)
+    try:
+        for upload in (jax.device_put, jnp.asarray):
+            lease.lengths = _aligned_int32([1, 2])
+            bt, lens = map(upload, lease.tables())
+            seen = jax.jit(lambda b, l: l + 0 * b[:, 0])(bt, lens)
+            kv.append_paged(lease)
+            np.testing.assert_array_equal(np.asarray(seen), [1, 2])
+            np.testing.assert_array_equal(np.asarray(lens), [1, 2])
+            np.testing.assert_array_equal(lease.lengths, [2, 3])
+    finally:
+        kv.release_paged(lease)

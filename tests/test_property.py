@@ -4,9 +4,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
-
-hypothesis = pytest.importorskip("hypothesis")
-from hypothesis import given, settings, strategies as st  # noqa: E402
+from hypothesis import given, settings, strategies as st
 
 import repro.core as core
 from repro.core.datastore import build_paged_clusters, Datastore
