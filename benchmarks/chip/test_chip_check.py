@@ -1,0 +1,158 @@
+"""The comparison that decides ``correct``, on the CPU at a size a test
+run holds: a sound run of the served path is correct, its control (the
+reference one precision lower in the program's place) is not, and each
+fault the cells can have, planted in the timed path underneath the
+harness, makes ``correct`` come out false.
+
+The harness runs as ``run.py`` runs it, with the look for a chip left
+out.  The cells run on one chip, so there is no exchange between chips
+to leave out.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import os
+import time
+
+import jax
+import numpy as np
+import pytest
+
+from benchmarks.chip import cell, check, harness
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+SEED = 2**33 + 12
+
+
+def tiny_cell(config: str) -> cell.Cell:
+    bench = cell.load_benchmark()
+    td = os.path.join(HERE, "testdata")
+    return cell.Cell(name=f"{config}.tiny", chips=1,
+                     config=cell.load_json(os.path.join(td, f"{config}.json")),
+                     traffic=cell.load_json(os.path.join(td,
+                                                         "tiny-traffic.json")),
+                     end_to_end=bench["end_to_end"],
+                     per_layer=bench["per_layer"])
+
+
+def run(config: str, tmp_path, **kw) -> dict:
+    return harness.run_cell(tiny_cell(config), seed=SEED, seconds=1.0,
+                            trace=False, device=jax.devices()[0],
+                            t_start=time.perf_counter(),
+                            trace_dir=str(tmp_path / "trace"), **kw)
+
+
+@pytest.mark.parametrize("config", ["tiny-moe", "tiny-dense"])
+def test_sound_run_is_correct_and_its_control_is_not(config, tmp_path):
+    res = run(config, tmp_path, control=True)
+    assert res["correct"], res["compared"]
+    assert res["attempted"] > 0 and res["failed"] == 0
+    n = res["numbers"]
+    limits = {k: v["limit"] for k, v in res["compared"].items()}
+    ok, _ = check.verdict({"logit_gap": n["control_logit_gap"],
+                           "retrieval_score_gap":
+                               n["control_retrieval_score_gap"]}, limits)
+    assert not ok
+    assert set(res["metrics"]) == {"tokens_per_s", "latency_p50_s",
+                                   "latency_p90_s", "setup_s"}
+    assert list(res)[-1] == "compared"
+
+
+def _state_unchanged(monkeypatch):
+    """The decode step returns the KV slab it was given."""
+    from repro.models import transformer as tf
+
+    def after_build(srv, runner):
+        cfg, mode = runner.cfg, runner._kernel_mode
+        runner._paged_step = jax.jit(
+            lambda p, k, v, bt, lens, tok, live: (tf.serve_step_paged(
+                p, k, v, bt, lens, {"token": tok, "live_rows": live}, cfg,
+                kernel_mode=mode)[0], k, v))
+    return after_build
+
+
+def _half_batch(monkeypatch):
+    """Retrieval searches half of the batch; the rest get its answers."""
+    from repro.serving.policies import TeleRAGPolicy
+    inner = TeleRAGPolicy.retrieve
+
+    def retrieve(self, engine, q_out, **kw):
+        half = max(1, len(q_out) // 2)
+        res = inner(self, engine, q_out[:half], **kw)
+        take = np.arange(len(q_out)) % half
+        return dataclasses.replace(
+            res, doc_ids=res.doc_ids[take], scores=res.scores[take],
+            hit_clusters=[res.hit_clusters[i] for i in take],
+            missed_clusters=[res.missed_clusters[i] for i in take])
+    monkeypatch.setattr(TeleRAGPolicy, "retrieve", retrieve)
+
+
+def _token_altered(monkeypatch):
+    """Each sampled token is one past the greedy choice."""
+    from repro.serving import decode
+    inner = decode.sample
+    monkeypatch.setattr(decode, "sample", lambda logits: (
+        (inner(logits) + 1) % logits.shape[-1]).astype(np.int32))
+
+
+def _answer_altered(monkeypatch):
+    """One doc id of every retrieval is replaced by its neighbour."""
+    from repro.serving.policies import TeleRAGPolicy
+    inner = TeleRAGPolicy.retrieve
+
+    def retrieve(self, engine, q_out, **kw):
+        res = inner(self, engine, q_out, **kw)
+        ids = np.array(res.doc_ids)
+        ids[0, 0] = (ids[0, 0] + 1) % engine.index.assignments.shape[0]
+        return dataclasses.replace(res, doc_ids=ids)
+    monkeypatch.setattr(TeleRAGPolicy, "retrieve", retrieve)
+
+
+FAULTS = {"state_unchanged": _state_unchanged, "half_batch": _half_batch,
+          "token_altered": _token_altered, "answer_altered": _answer_altered}
+
+
+@pytest.mark.parametrize("fault", sorted(FAULTS))
+def test_a_fault_in_the_timed_path_is_not_correct(fault, monkeypatch,
+                                                  tmp_path):
+    after_build = FAULTS[fault](monkeypatch)
+    res = run("tiny-moe", tmp_path, after_build=after_build)
+    assert not res["correct"], res["compared"]
+
+
+def _edge_corpus():
+    """Six one-vector clusters scored by q = e0: cluster 3's centroid
+    (0.3) rounds in bf16 onto cluster 2's (0.30078125), the third of an
+    nprobe of 3, and its vector outscores cluster 2's."""
+    from benchmarks.chip.datastore import Corpus
+    cen = np.zeros((6, 4), np.float32)
+    cen[:, 0] = [0.9, 0.6, 0.30078125, 0.3, 0.1, -0.5]
+    vec = np.zeros((6, 4), np.float32)
+    vec[:, 0] = [0.5, 0.375, 0.25, 0.4375, 0.875, 0.9375]
+    q = np.zeros((1, 4), np.float32)
+    q[0, 0] = 1.0
+    return Corpus(vectors=vec, centroids=cen,
+                  assignment=np.arange(6, dtype=np.int32)), q
+
+
+# served ids (top-2), by the probe they come from
+EDGE_ANSWERS = {"exact_probe": [0, 1], "edge_cluster_taken": [0, 3],
+                "far_cluster_taken": [4, 0], "best_doc_left_out": [1, 3]}
+
+
+@pytest.mark.parametrize("edge,answer,ok", [
+    ("bf16", "exact_probe", True), ("bf16", "edge_cluster_taken", True),
+    ("bf16", "far_cluster_taken", False),
+    ("bf16", "best_doc_left_out", False),
+    ("highest", "exact_probe", True),
+    ("highest", "edge_cluster_taken", False)])
+def test_an_answer_at_the_probe_edge(edge, answer, ok):
+    from benchmarks.chip.references import ivf
+    corpus, q = _edge_corpus()
+    ref = ivf.search(corpus, q, nprobe=3, k=2, edge_precision=edge)
+    assert ref.ids.tolist() == [[0, 1]]
+    ids = np.asarray([EDGE_ANSWERS[answer]])
+    scores = corpus.vectors[ids[0], 0][None, :]
+    gap = float(np.max(ivf.answer_gap(corpus, q, ids, scores, ref)))
+    assert (gap <= 1e-5) == ok, gap
