@@ -53,7 +53,7 @@ from types import SimpleNamespace
 from repro.configs.base import ArchConfig
 from repro.kernels import ops
 from repro.models import transformer as tf
-from repro.obs import SystemClock
+from repro.obs import FlightRecorder, SystemClock
 from repro.serving import DecodeRunner, EngineConfig
 from repro.serving.kv_cache import KVCacheManager
 from benchmarks.common import (emit, report_path, summarize_rows,
@@ -147,7 +147,8 @@ def _serve_path_records(*, B: int, steps: int, page_size: int,
                                   max_steps=steps, page_size=page_size,
                                   slab_seqs=B, paged=paged)
             runner.attach(SimpleNamespace(
-                wall=SystemClock(), micro_batch=None,
+                wall=SystemClock(), recorder=FlightRecorder(),
+                micro_batch=None,
                 engines=[SimpleNamespace(
                     cfg=EngineConfig(paged_decode=paged, kernel_mode=mode),
                     pool=None, device=None)]))

@@ -28,6 +28,7 @@ import numpy as np
 from repro.core.datastore import PagedClusters
 from repro.core.prefetch_buffer import PrefetchBuffer
 from repro.kernels import ops
+from repro.obs.recorder import NULL_SPAN, FlightRecorder
 
 
 # ---------------------------------------------------------------------------
@@ -106,6 +107,7 @@ def hybrid_retrieve(buffer: PrefetchBuffer, queries: np.ndarray,
                     probed_clusters: np.ndarray, *, k: int,
                     kernel_mode: str = "auto", fused: bool = False,
                     centroids: Optional[np.ndarray] = None,
+                    recorder: Optional[FlightRecorder] = None,
                     ) -> RetrievalResult:
     """queries [B, d]; probed_clusters [B, nprobe] (ranked by q_out).
 
@@ -121,7 +123,13 @@ def hybrid_retrieve(buffer: PrefetchBuffer, queries: np.ndarray,
     score-matrix round trip.  The admitted cluster set equals
     ``probed_clusters`` (same centroid scores, tie-free), so the host
     miss partition and telemetry are unchanged.
+
+    ``recorder`` times the three parts as host spans
+    (``telerag.retrieve.device`` / ``.host`` / ``.merge``) while its
+    host spans are on.
     """
+    span = (lambda name, **a: NULL_SPAN) if recorder is None \
+        else recorder.span
     B, nprobe = probed_clusters.shape
     buffer.flush_invalidations()
     resident = buffer.resident_clusters()
@@ -132,41 +140,48 @@ def hybrid_retrieve(buffer: PrefetchBuffer, queries: np.ndarray,
         hit.append([c for c in cs if c in resident])
         miss.append([c for c in cs if c not in resident])
 
-    qd = jnp.asarray(queries, jnp.float32)
-    if fused and centroids is not None:
-        # one-launch device partition: probe + admission + top-k read the
-        # pool pages in place through the device page table — a page is
-        # searchable iff its cluster's centroid score reaches the
-        # nprobe-th largest, which is exactly the probed set
-        pages, page_ids, page_cluster = buffer.device_view()
-        dev_s, dev_i = ops.probe_and_topk(
-            qd, jnp.asarray(centroids, jnp.float32), pages, page_ids,
-            page_cluster, nprobe=nprobe, k=k, mode=kernel_mode)
-    else:
-        # legacy two-launch partition — fused masked search over the slab
-        # with *per-query* page masks built on host (exact per-query IVF
-        # nprobe semantics; mask is page-level so the traffic is
-        # num_pages bytes per query, tiny)
-        Nc = buffer.paged.num_clusters
-        luts = np.zeros((B, Nc), bool)
-        for b in range(B):
-            luts[b, hit[b]] = True
-        pages, page_ids, _ = buffer.device_view()
-        pc = buffer.slot_cluster                # host page-table mirror
-        page_mask = np.zeros((B, pages.shape[0]), bool)
-        valid_slots = np.flatnonzero(pc >= 0)
-        page_mask[:, valid_slots] = luts[:, pc[valid_slots]]
-        dev_s, dev_i = ops.ivf_topk(pages, page_ids, jnp.asarray(page_mask),
-                                    qd, k, mode=kernel_mode)
+    with span("telerag.retrieve.device", queries=B):
+        qd = jnp.asarray(queries, jnp.float32)
+        if fused and centroids is not None:
+            # one-launch device partition: probe + admission + top-k read
+            # the pool pages in place through the device page table — a
+            # page is searchable iff its cluster's centroid score reaches
+            # the nprobe-th largest, which is exactly the probed set
+            pages, page_ids, page_cluster = buffer.device_view()
+            dev_s, dev_i = ops.probe_and_topk(
+                qd, jnp.asarray(centroids, jnp.float32), pages, page_ids,
+                page_cluster, nprobe=nprobe, k=k, mode=kernel_mode)
+        else:
+            # legacy two-launch partition — fused masked search over the
+            # slab with *per-query* page masks built on host (exact
+            # per-query IVF nprobe semantics; mask is page-level so the
+            # traffic is num_pages bytes per query, tiny)
+            Nc = buffer.paged.num_clusters
+            luts = np.zeros((B, Nc), bool)
+            for b in range(B):
+                luts[b, hit[b]] = True
+            pages, page_ids, _ = buffer.device_view()
+            pc = buffer.slot_cluster                # host page-table mirror
+            page_mask = np.zeros((B, pages.shape[0]), bool)
+            valid_slots = np.flatnonzero(pc >= 0)
+            page_mask[:, valid_slots] = luts[:, pc[valid_slots]]
+            dev_s, dev_i = ops.ivf_topk(pages, page_ids,
+                                        jnp.asarray(page_mask), qd, k,
+                                        mode=kernel_mode)
 
     # host partition (scalar scores/ids only cross the link)
-    host_results = [host_search(buffer.paged, miss[b], queries[b], k)
-                    for b in range(B)]
-    host_s = np.stack([r[0] for r in host_results])
-    host_i = np.stack([r[1] for r in host_results])
-    fs, fi = merge_topk(dev_s, dev_i, jnp.asarray(host_s), jnp.asarray(host_i),
-                        k)
-    return RetrievalResult(doc_ids=np.asarray(fi), scores=np.asarray(fs),
+    with span("telerag.retrieve.host",
+              clusters=sum(len(m) for m in miss)):
+        host_results = [host_search(buffer.paged, miss[b], queries[b], k)
+                        for b in range(B)]
+        host_s = np.stack([r[0] for r in host_results])
+        host_i = np.stack([r[1] for r in host_results])
+    # the np.asarray waits for the device partition and the merge
+    with span("telerag.retrieve.merge"):
+        fs, fi = merge_topk(dev_s, dev_i, jnp.asarray(host_s),
+                            jnp.asarray(host_i), k)
+        doc_ids, scores = np.asarray(fi), np.asarray(fs)
+    return RetrievalResult(doc_ids=doc_ids, scores=scores,
                            hit_clusters=hit, missed_clusters=miss,
                            nprobe=nprobe)
 

@@ -28,7 +28,7 @@ from typing import Callable, List, Optional, Sequence, Tuple
 
 from repro.core.prefetch_buffer import PrefetchBuffer
 from repro.memory.pool import Reservation
-from repro.obs.recorder import FlightRecorder, TransferRecord
+from repro.obs.recorder import NULL_SPAN, FlightRecorder, TransferRecord
 
 
 @dataclass(frozen=True)
@@ -97,13 +97,22 @@ class TransferEngine:
         runtime-fetch baseline's modeled demand fetch).
         """
         clusters = [int(c) for c in clusters]
-        loaded, rejected = self.buffer.load_clusters(clusters,
-                                                     reservation=reservation)
-        if rejected and make_room is not None:
-            make_room(sum(int(self.buffer.paged.cluster_num_pages[c])
-                          for c in rejected))
-            _, rejected = self.buffer.load_clusters(rejected,
-                                                    reservation=reservation)
+        stats = self.buffer.stats
+        pages0, bytes0 = stats.pages_h2d, stats.bytes_h2d
+        # the real H2D issue: staging, device_put and the scatter's
+        # dispatch (host time; the copy lands later, unseen by the span)
+        rec = self.recorder
+        with (NULL_SPAN if rec is None else
+              rec.span("telerag.lookahead.issue", kind=kind)) as span:
+            loaded, rejected = self.buffer.load_clusters(
+                clusters, reservation=reservation)
+            if rejected and make_room is not None:
+                make_room(sum(int(self.buffer.paged.cluster_num_pages[c])
+                              for c in rejected))
+                _, rejected = self.buffer.load_clusters(
+                    rejected, reservation=reservation)
+            span.set(pages=stats.pages_h2d - pages0,
+                     bytes=stats.bytes_h2d - bytes0)
         if rejected:
             # never leak planned clusters silently: shrink the copy (and
             # its modeled byte count — the link must not be occupied for

@@ -164,9 +164,10 @@ def main(argv: Optional[Sequence[str]] = None):
                     help="decode on the dense [B, max_len] KV bucket path "
                          "instead of the paged block-table substrate")
     ap.add_argument("--trace-out", default=None, metavar="PATH",
-                    help="write the run's flight-recorder stream as "
-                         "Chrome/Perfetto trace-event JSON (load in "
-                         "ui.perfetto.dev; see docs/OBSERVABILITY.md)")
+                    help="write the run's flight-recorder stream, "
+                         "host-clock spans included, as Chrome/Perfetto "
+                         "trace-event JSON (load in ui.perfetto.dev; see "
+                         "docs/OBSERVABILITY.md)")
     ap.add_argument("--print-env", action="store_true",
                     help="print the recommended launch environment "
                          "(tcmalloc preload, XLA flags) and exit")
@@ -193,6 +194,9 @@ def main(argv: Optional[Sequence[str]] = None):
 
     q = make_queries(store, args.requests, args.seed)
     traces = make_traces(args.pipeline, args.requests, seed=args.seed)
+    if args.trace_out:
+        # the trace then holds the host-clock lanes too
+        srv.recorder.enable_host_spans(srv.wall)
     t0 = time.perf_counter()
     responses = srv.serve([RagRequest(q=q[i], trace=traces[i])
                            for i in range(args.requests)])

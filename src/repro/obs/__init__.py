@@ -15,10 +15,10 @@ depend on the recorder, never the other way around.
 from repro.obs.analyze import OverlapReport, OverlapRound, analyze
 from repro.obs.clock import SYSTEM_CLOCK, EventClock, SystemClock
 from repro.obs.export import to_jsonl, to_perfetto, write_jsonl, write_trace
-from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
-                               TimeSeries)
-from repro.obs.recorder import (LEGACY_LABELS, AdmissionEvent, ChunkKVEvent,
-                                CounterSample, DecodeStep, FlightRecorder,
+from repro.obs.metrics import Counter, Gauge, Histogram, MetricsRegistry
+from repro.obs.recorder import (LEGACY_LABELS, NULL_SPAN, AdmissionEvent,
+                                ChunkKVEvent, CounterSample, DecodeStep,
+                                FlightRecorder, HostRequest, HostSpan,
                                 KVEvent, PoolEvent, RequestEvent, SpanEvent,
                                 TraceEvent, TransferRecord, WaveEvent)
 from repro.obs.render import (render_replica_line, render_telemetry,
@@ -28,10 +28,11 @@ __all__ = [
     "AdmissionEvent", "analyze", "ChunkKVEvent", "Counter", "CounterSample",
     "DecodeStep",
     "EventClock", "SYSTEM_CLOCK", "SystemClock",
-    "FlightRecorder", "Gauge", "Histogram", "KVEvent", "LEGACY_LABELS",
+    "FlightRecorder", "Gauge", "Histogram", "HostRequest", "HostSpan",
+    "KVEvent", "LEGACY_LABELS", "NULL_SPAN",
     "MetricsRegistry", "OverlapReport", "OverlapRound", "PoolEvent",
     "RequestEvent", "render_replica_line", "render_telemetry",
-    "render_tenant_line", "SpanEvent", "TimeSeries", "to_jsonl",
+    "render_tenant_line", "SpanEvent", "to_jsonl",
     "to_perfetto", "TraceEvent", "TransferRecord", "WaveEvent",
     "write_jsonl", "write_trace",
 ]

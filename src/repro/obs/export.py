@@ -17,6 +17,15 @@ stream: ``ledger_occupancy`` and ``pool_free_pages`` from pool
 lease/release edges, ``kv_bytes`` per tenant from KV-category pool
 edges, ``queue_depth`` from server samples.
 
+Host-clock spans (``FlightRecorder.span``, when on) get processes of
+their own, one per replica, named "host clock: replica N", with a lane
+each for ``wave``, ``decode``, ``lookahead`` and ``retrieval``
+(``telerag.<lane>.*``; ``telerag.retrieve*`` on ``retrieval``), and each
+request's host-clock life as an async span (cat ``host_request``) from
+submission to the end of its last decode wave or retrieve call.  Their
+timestamps are µs from the first host stamp; they share no origin with
+the event-clock processes, which are modeled.
+
 Timestamps: the event clock is seconds; Chrome wants microseconds
 (``ts`` / ``dur``).  Load the file at https://ui.perfetto.dev or
 chrome://tracing.
@@ -32,6 +41,8 @@ from repro.obs.recorder import FlightRecorder, TraceEvent
 
 _US = 1e6
 _SERVER_PID = 9999                    # replica=-1 events (server lane)
+_HOST_PID = 10000                     # + replica (or + _SERVER_PID)
+HOST_LANES = {"wave": 1, "decode": 2, "lookahead": 3, "retrieval": 4}
 
 _LANES = {"decode": 1, "link": 2, "retrieval": 3, "admission": 4}
 _SPAN_LANE = {
@@ -140,9 +151,51 @@ def to_perfetto(rec: FlightRecorder) -> Dict[str, object]:
                                  "tokens": ev.tokens,
                                  "seconds": ev.seconds,
                                  "batch": ev.batch}})
+    out.extend(_host_events(rec))
     return {"traceEvents": out, "displayTimeUnit": "ms",
             "otherData": {"schema": "telerag.trace/v1",
                           "dropped_events": rec.dropped}}
+
+
+def _host_lane(name: str) -> str:
+    """The host lane of a ``telerag.<part>...`` span name."""
+    part = name.split(".")[1] if name.count(".") else ""
+    return "retrieval" if part == "retrieve" else (
+        part if part in HOST_LANES else "wave")
+
+
+def _host_events(rec: FlightRecorder) -> List[Dict[str, object]]:
+    """The host-clock processes: ``rec.host_spans`` on their lanes and
+    each request's submit -> done async span."""
+    reqs = [r for r in rec.host_requests if r.done_s is not None]
+    stamps = ([s.start for s in rec.host_spans]
+              + [r.submit_s for r in reqs])
+    if not stamps:
+        return []
+    t0 = min(stamps)
+    pid = lambda replica: _HOST_PID + (replica if replica >= 0
+                                       else _SERVER_PID)
+    out: List[Dict[str, object]] = []
+    for r in sorted({s.replica for s in rec.host_spans}
+                    | {r.replica for r in reqs}):
+        name = "server" if r < 0 else f"replica {r}"
+        out.append({"ph": "M", "name": "process_name", "pid": pid(r),
+                    "tid": 0, "args": {"name": f"host clock: {name}"}})
+        for lane, tid in HOST_LANES.items():
+            out.append({"ph": "M", "name": "thread_name", "pid": pid(r),
+                        "tid": tid, "args": {"name": lane}})
+    for s in sorted(rec.host_spans, key=lambda s: (s.start, -s.end)):
+        out.append({"ph": "X", "name": s.name, "cat": "host",
+                    "pid": pid(s.replica),
+                    "tid": HOST_LANES[_host_lane(s.name)],
+                    "ts": (s.start - t0) * _US, "dur": s.dur * _US,
+                    "args": dict(s.args, wave_id=s.wave_id, seq=s.seq)})
+    for r in reqs:
+        for ph, t in (("b", r.submit_s), ("e", r.done_s)):
+            out.append({"ph": ph, "cat": "host_request", "id": r.request_id,
+                        "name": f"req {r.request_id}", "pid": pid(r.replica),
+                        "tid": HOST_LANES["wave"], "ts": (t - t0) * _US})
+    return out
 
 
 def write_trace(rec: FlightRecorder, path: str) -> str:

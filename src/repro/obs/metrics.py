@@ -1,13 +1,10 @@
-"""Label-keyed metrics registry: counters, gauges, histograms, series.
+"""Label-keyed metrics registry: counters, gauges, histograms.
 
 ``TeleRAGServer``'s telemetry dataclasses are *views* over this
 registry: the server's lifetime counts (completed / waves / batches)
 and every per-tenant SLO accumulator live here as first-class
 instruments, keyed by ``(name, labels)`` — so the future autoscaler
-and the telemetry snapshot read the same numbers.  Occupancy and
-attainment are additionally sampled as ``TimeSeries`` (time-stamped on
-the shared event clock), which is what a control loop needs instead of
-an end-of-run scalar.
+and the telemetry snapshot read the same numbers.
 
 Numerically this is a refactor, not a change: ``Histogram.percentile``
 is ``np.percentile`` over the raw samples, exactly what the pre-registry
@@ -87,29 +84,6 @@ class Histogram:
         return float(np.percentile(np.asarray(self.samples), q))
 
 
-@dataclass
-class TimeSeries:
-    """(t, value) samples on the shared event clock — the consumable
-    form of occupancy/attainment for control loops."""
-
-    name: str
-    labels: LabelKey = ()
-    samples: List[Tuple[float, float]] = field(default_factory=list)
-
-    def sample(self, t: float, v: float) -> None:
-        self.samples.append((float(t), float(v)))
-
-    def sorted_samples(self) -> List[Tuple[float, float]]:
-        """Samples in event-clock order (emission can be post-hoc)."""
-        return sorted(self.samples)
-
-    @property
-    def last(self) -> float:
-        """Most recent value on the clock (0 when never sampled)."""
-        s = self.sorted_samples()
-        return s[-1][1] if s else 0.0
-
-
 class MetricsRegistry:
     """Get-or-create instrument store keyed by ``(name, labels)``."""
 
@@ -117,7 +91,6 @@ class MetricsRegistry:
         self._counters: Dict[Tuple[str, LabelKey], Counter] = {}
         self._gauges: Dict[Tuple[str, LabelKey], Gauge] = {}
         self._histograms: Dict[Tuple[str, LabelKey], Histogram] = {}
-        self._series: Dict[Tuple[str, LabelKey], TimeSeries] = {}
 
     def counter(self, name: str, **labels: object) -> Counter:
         key = (name, _label_key(labels))
@@ -137,18 +110,11 @@ class MetricsRegistry:
             self._histograms[key] = Histogram(name, key[1])
         return self._histograms[key]
 
-    def series(self, name: str, **labels: object) -> TimeSeries:
-        key = (name, _label_key(labels))
-        if key not in self._series:
-            self._series[key] = TimeSeries(name, key[1])
-        return self._series[key]
-
     def label_values(self, name: str, label: str) -> List[str]:
         """Distinct values one label takes across all instruments of
         ``name`` (e.g. every tenant a histogram was observed for)."""
         out = []
-        for store in (self._counters, self._gauges,
-                      self._histograms, self._series):
+        for store in (self._counters, self._gauges, self._histograms):
             for (n, lk) in store:
                 for k, v in lk:
                     if n == name and k == label and v not in out:
@@ -169,15 +135,10 @@ class MetricsRegistry:
                          "labels": dict(lk), "count": h.count,
                          "sum": h.sum,
                          "p50": h.percentile(50), "p99": h.percentile(99)})
-        for (name, lk), s in self._series.items():
-            rows.append({"type": "series", "name": name,
-                         "labels": dict(lk), "samples": len(s.samples),
-                         "last": s.last})
         return rows
 
     def items(self) -> Iterable[Tuple[str, object]]:
-        """Every (name, instrument) pair across the four stores."""
-        for store in (self._counters, self._gauges,
-                      self._histograms, self._series):
+        """Every (name, instrument) pair across the three stores."""
+        for store in (self._counters, self._gauges, self._histograms):
             for (name, _lk), inst in store.items():
                 yield name, inst

@@ -22,12 +22,22 @@ future timestamp); consumers that need time order use
 ``RetrievalRuntime.event_log`` list: the same ``(t, label,
 request_id)`` 3-tuples, in emission order, filtered to one replica's
 lane — existing tests and benches keep iterating it unchanged.
+
+Host-clock spans are the recorder's second, opt-in stream.  Event-clock
+stamps are modeled; ``span(name, **args)`` times real host work on an
+injected real clock (``enable_host_spans``).  Off by default, a span is
+one shared null context; on, it stamps start and end from
+``clock.perf()``, carries the replica and wave ids of the wave it runs
+in, lands in ``host_spans`` (apart from ``events``, so the event stream
+stays replay-deterministic) and opens a
+``jax.profiler.TraceAnnotation`` of the same name, so a profiler trace
+holds the same span on the clock of the device's ops.
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 # the request-lifecycle labels the retired ``runtime.event_log`` carried;
 # ``legacy_tuples()`` reproduces exactly these (a server-side "submit"
@@ -204,6 +214,105 @@ class CounterSample(TraceEvent):
     value: float = 0.0
 
 
+@dataclass(frozen=True)
+class HostSpan:
+    """One interval of host work on the real clock: ``start`` and
+    ``end`` in seconds of the injected clock's ``perf()``, the replica
+    and wave it ran in (-1 outside a wave), ``seq`` (the span's number,
+    also an arg of its ``TraceAnnotation``, which pairs the two) and the
+    call's args."""
+
+    name: str
+    start: float
+    end: float
+    replica: int = -1
+    wave_id: int = -1
+    seq: int = -1
+    args: Dict[str, object] = field(default_factory=dict)
+
+    @property
+    def dur(self) -> float:
+        return self.end - self.start
+
+
+@dataclass
+class HostRequest:
+    """One request's host-clock stamps: submitted at ``submit_s``; the
+    last decode wave or retrieve call that worked for it ended at
+    ``done_s`` (None until one has)."""
+
+    request_id: int
+    replica: int
+    submit_s: float
+    done_s: Optional[float] = None
+
+
+class _NullSpan:
+    """The span while host spans are off: enters, takes args and exits
+    as nothing."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_NullSpan":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+    def set(self, **args: object) -> None:
+        pass
+
+
+NULL_SPAN = _NullSpan()
+"""The one shared span of a recorder with host spans off (and of code
+with no recorder)."""
+
+
+class _LiveSpan:
+    """One open host span (see ``FlightRecorder.span``)."""
+
+    __slots__ = ("rec", "name", "args", "replica", "wave_id", "seq",
+                 "start", "_ann", "_outer")
+
+    def __init__(self, rec: "FlightRecorder", name: str,
+                 replica: Optional[int], wave_id: Optional[int],
+                 args: Dict[str, object]):
+        self.rec, self.name, self.args = rec, name, args
+        self.replica = rec._replica if replica is None else replica
+        self.wave_id = rec._wave if wave_id is None else wave_id
+
+    def __enter__(self) -> "_LiveSpan":
+        rec = self.rec
+        self._outer = (rec._replica, rec._wave)
+        rec._replica, rec._wave = self.replica, self.wave_id
+        rec._seq += 1
+        self.seq = rec._seq
+        self._ann = rec._annotate(self.name, seq=self.seq,
+                                  replica=self.replica, wave=self.wave_id,
+                                  **self.args)
+        self._ann.__enter__()
+        self.start = rec.host_clock.perf()
+        return self
+
+    def set(self, **args: object) -> None:
+        """Add args known only once the work has run (pages moved)."""
+        self.args.update(args)
+        self._ann.set_metadata(**args)
+
+    def __exit__(self, *exc) -> bool:
+        rec = self.rec
+        end = rec.host_clock.perf()
+        self._ann.__exit__(*exc)
+        rec._replica, rec._wave = self._outer
+        rec.host_spans.append(HostSpan(
+            name=self.name, start=self.start, end=end,
+            replica=self.replica, wave_id=self.wave_id, seq=self.seq,
+            args=self.args))
+        if rec.capacity is not None and len(rec.host_spans) > rec.capacity:
+            del rec.host_spans[:len(rec.host_spans) // 2]
+        return False
+
+
 @dataclass
 class FlightRecorder:
     """Append-only typed event log on the shared event clock.
@@ -214,12 +323,21 @@ class FlightRecorder:
     with.  ``capacity`` bounds memory for long-lived servers: when
     exceeded, the oldest half of the log is dropped (a flight recorder
     keeps the recent past; ``dropped`` counts the loss so analyzers
-    can report a truncated window instead of silently lying)."""
+    can report a truncated window instead of silently lying).  The same
+    bound halves ``host_spans``."""
 
     capacity: Optional[int] = None
     now: float = 0.0
     events: List[TraceEvent] = field(default_factory=list)
     dropped: int = 0
+    # host-clock spans (``span``): off while ``host_clock`` is None
+    host_clock: Optional[object] = None
+    host_spans: List[HostSpan] = field(default_factory=list)
+    host_requests: List[HostRequest] = field(default_factory=list)
+    _annotate: Optional[Callable] = field(default=None, repr=False)
+    _replica: int = field(default=-1, repr=False)
+    _wave: int = field(default=-1, repr=False)
+    _seq: int = field(default=0, repr=False)
 
     def tick(self, t: float) -> float:
         """Advance the clock cursor (monotone); returns the cursor."""
@@ -236,6 +354,46 @@ class FlightRecorder:
             del self.events[:drop]
             self.dropped += drop
         return ev
+
+    # -- host-clock spans ----------------------------------------------------
+    def enable_host_spans(self, clock) -> None:
+        """Turn host spans on, timed by ``clock`` (``obs.clock``'s
+        ``SystemClock``).  A clock that does not measure (``real`` False,
+        the ``EventClock``) is refused: its spans would all read 0."""
+        if not getattr(clock, "real", False):
+            raise ValueError("host spans need a real clock, not "
+                             f"{type(clock).__name__}")
+        from jax.profiler import TraceAnnotation
+        self._annotate = TraceAnnotation
+        self.host_clock = clock
+
+    def span(self, name: str, *, replica: Optional[int] = None,
+             wave: Optional[int] = None, **args: object):
+        """Context manager timing one piece of host work as a
+        ``HostSpan`` (yields an object whose ``set(**args)`` adds args
+        at the end).  ``replica``/``wave`` set the ids for this span and
+        every span opened inside it; left out, they are inherited.  Off,
+        it returns ``NULL_SPAN``: no clock read, nothing stored."""
+        if self.host_clock is None:
+            return NULL_SPAN
+        return _LiveSpan(self, name, replica, wave, args)
+
+    def host_now(self) -> Optional[float]:
+        """The host clock's reading, or None while host spans are off."""
+        return None if self.host_clock is None else self.host_clock.perf()
+
+    def host_request(self, request_id: int, replica: int,
+                     submit_s: Optional[float] = None,
+                     ) -> Optional[HostRequest]:
+        """Start one request's host stamps (submitted at ``submit_s``,
+        default now); None while host spans are off."""
+        if self.host_clock is None:
+            return None
+        hr = HostRequest(request_id, replica,
+                         self.host_clock.perf() if submit_s is None
+                         else submit_s)
+        self.host_requests.append(hr)
+        return hr
 
     # -- queries -------------------------------------------------------------
     def of(self, *kinds: str) -> List[TraceEvent]:
@@ -273,7 +431,9 @@ class FlightRecorder:
                 and (replica is None or e.replica == replica)]
 
     def clear(self) -> None:
-        """Drop all events (the clock cursor is kept — it is shared
-        with live runtimes and must stay monotone)."""
+        """Drop all events and host spans (the clock cursor is kept —
+        it is shared with live runtimes and must stay monotone)."""
         self.events.clear()
         self.dropped = 0
+        self.host_spans.clear()
+        self.host_requests.clear()

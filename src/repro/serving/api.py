@@ -118,7 +118,8 @@ class RagRequest:
 class RagResponse:
     """One completed request: results + its event-clock life story.
 
-    All timestamps are seconds on the shared global event clock.  The
+    All timestamps but ``host_submit_s``/``host_done_s`` are seconds
+    on the shared global event clock, which is modeled.  The
     deadline flags split an SLO miss by *where* the time was lost:
     ``deadline_missed_in_queue`` means the deadline had already passed
     while the request was still waiting for a replica slot (before
@@ -142,6 +143,11 @@ class RagResponse:
     priority: int = 0
     deadline_s: Optional[float] = None
     demoted_rounds: int = 0          # rounds whose prefetch was demoted
+    # host-clock stamps (seconds of the recorder's host clock), kept only
+    # while host spans are on: submission, and the end of the last decode
+    # wave or retrieve call that worked for the request
+    host_submit_s: Optional[float] = None
+    host_done_s: Optional[float] = None
 
     @property
     def queue_s(self) -> float:
@@ -155,9 +161,18 @@ class RagResponse:
 
     @property
     def latency_s(self) -> float:
-        """End-to-end arrival → complete in seconds (what open-loop
-        load inflates)."""
+        """End-to-end arrival → complete in seconds on the event clock
+        (what open-loop load inflates): modeled, not measured."""
         return self.complete_t - self.arrival_t
+
+    @property
+    def host_latency_s(self) -> Optional[float]:
+        """Submission to the end of the request's last decode wave or
+        retrieve call, measured on the host clock; None while host spans
+        are off."""
+        if self.host_submit_s is None or self.host_done_s is None:
+            return None
+        return self.host_done_s - self.host_submit_s
 
     @property
     def stall_s(self) -> float:
@@ -364,6 +379,7 @@ class _Submitted:
     arrival_abs: float = 0.0
     replica: int = -1
     record: Optional[RequestRecord] = None
+    host_submit_s: Optional[float] = None
 
 
 @dataclass(eq=False)
@@ -561,7 +577,8 @@ class TeleRAGServer:
         if trace is None:
             trace = make_trace(request.pipeline, seq,
                                np.random.default_rng(self.cfg.seed + seq))
-        self._inbox.append(_Submitted(seq=seq, request=request, trace=trace))
+        self._inbox.append(_Submitted(seq=seq, request=request, trace=trace,
+                                      host_submit_s=self.recorder.host_now()))
         return trace.request_id
 
     def serve(self, requests: Sequence[RagRequest]) -> List[RagResponse]:
@@ -737,11 +754,6 @@ class TeleRAGServer:
             requeued=requeued,
             sched_overhead_s=self.wall.perf() - t0))
         self._c_waves.inc()
-        # occupancy time series on the event clock: one sample per
-        # replica at every routed wave (what a control loop consumes)
-        for i, e in enumerate(self.engines):
-            self.metrics.series("ledger_occupancy", replica=i).sample(
-                wave_t, e.ledger.occupancy())
         touched = []
         for a in fixed:
             batch = [members[i] for i in groups[a.batch_index]]
@@ -795,7 +807,8 @@ class TeleRAGServer:
                 s.record = rt.submit(s.request.q, s.trace, arrival_t=t_disp,
                                      tenant=s.request.tenant,
                                      priority=s.request.priority,
-                                     deadline_t=self._deadline_abs(s))
+                                     deadline_t=self._deadline_abs(s),
+                                     host_submit_s=s.host_submit_s)
             submitted = True
             self._c_batches.inc()
             if not self.continuous:
@@ -847,14 +860,10 @@ class TeleRAGServer:
             deadline_missed_in_queue=missed_in_queue,
             tenant=s.request.tenant, priority=s.request.priority,
             deadline_s=s.request.deadline_s,
-            demoted_rounds=rec.demoted_rounds)
+            demoted_rounds=rec.demoted_rounds,
+            host_submit_s=rec.host_submit_s, host_done_s=rec.host_done_s)
         tenant = s.request.tenant
         if tenant not in self._tenant_acc:
             self._tenant_acc[tenant] = _TenantAcc(self.metrics, tenant)
         self._tenant_acc[tenant].note(resp)
-        if s.request.deadline_s is not None:
-            # attainment time series: 1/0 per deadline-carrying response
-            # at its completion time (mean over a window = attainment)
-            self.metrics.series("attainment", tenant=tenant).sample(
-                rec.complete_t, 0.0 if missed else 1.0)
         return resp

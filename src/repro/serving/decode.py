@@ -27,7 +27,10 @@ pressure is an admission decision, not a hook crash.
 Timing comes from an injected clock (``attach`` adopts the server's
 ``wall_clock``): launch drivers inject ``SystemClock`` for real
 measurement; the library default is the deterministic event clock, which
-is what lets tests pin paged==dense telemetry exactly.
+is what lets tests pin paged==dense telemetry exactly.  While the server
+recorder's host spans are on, the step loop (``telerag.decode.steps``),
+each step's host work (``telerag.decode.dispatch``) and the token
+read-back (``telerag.decode.readback``) are host-clock spans.
 """
 
 from __future__ import annotations
@@ -92,6 +95,7 @@ class DecodeRunner:
         self.paged = bool(paged) and supports_paged_decode(cfg)
         self.chunk_store = chunk_store
         self.clock = None                      # attach() adopts server.wall
+        self.recorder = None                   # attach(): server.recorder
         self._kv: Dict[int, KVCacheManager] = {}
         self._chunk: Dict[int, ChunkKVCache] = {}
         self._dense_step = None
@@ -116,6 +120,7 @@ class DecodeRunner:
         and the params, both on the engine's device; clock from the
         server's ``wall_clock`` injection point."""
         self.clock = server.wall
+        self.recorder = server.recorder        # host spans, when on
         # paged waves run at one row count (the server's micro-batch), so
         # the decode step compiles once instead of once per wave size
         self._rows = server.micro_batch or 0
@@ -213,9 +218,11 @@ class DecodeRunner:
             toks, per_step = self._run_dense(params, kv, n, steps, tenant)
         if steps:
             self.wave_step_seconds.append(per_step)
-        for j, r in enumerate(records):
-            self.generated.setdefault(r.request_id, []).append(
-                tuple(int(t[j]) for t in toks))
+        with self.recorder.span("telerag.decode.readback", rows=n,
+                                steps=len(toks)):
+            for j, r in enumerate(records):
+                self.generated.setdefault(r.request_id, []).append(
+                    tuple(int(t[j]) for t in toks))
         return [DecodeEvent(request_id=r.request_id,
                             tokens=min(g, steps) if g else 0,
                             seconds=per_step * (min(g, steps) if g else 0))
@@ -259,23 +266,29 @@ class DecodeRunner:
             # padding rows stay out of MoE expert capacity, so a wave's
             # tokens do not depend on the micro-batch it is padded to
             live = jax.device_put(np.int32(n), kv.device)
-            t0 = self.clock.perf()
-            for _ in range(steps):
-                tables = lease.tables()
-                if rows > n:
-                    tables = self._padded_tables(replica, tables, n, rows)
-                tables = [jax.device_put(t, kv.device) for t in tables]
-                step = (self._spliced_step if lease.spliced_pages
-                        else self._paged_step)
-                logits, kv.slab.k, kv.slab.v = step(
-                    params, kv.slab.k, kv.slab.v, *tables, tok, live)
-                kv.append_paged(lease)      # scatter was fused in-jit
-                self.stats["paged_appends"] += 1
-                tok = sample(logits)
-                toks.append(tok)
-            if toks:
-                jax.block_until_ready(toks[-1])
-            per_step = (self.clock.perf() - t0) / max(steps, 1)
+            span = self.recorder.span
+            with span("telerag.decode.steps", rows=rows, steps=steps):
+                t0 = self.clock.perf()
+                for i in range(steps):
+                    with span("telerag.decode.dispatch", step=i):
+                        tables = lease.tables()
+                        if rows > n:
+                            tables = self._padded_tables(replica, tables,
+                                                         n, rows)
+                        tables = [jax.device_put(t, kv.device)
+                                  for t in tables]
+                        step = (self._spliced_step if lease.spliced_pages
+                                else self._paged_step)
+                        logits, kv.slab.k, kv.slab.v = step(
+                            params, kv.slab.k, kv.slab.v, *tables, tok,
+                            live)
+                        kv.append_paged(lease)  # scatter was fused in-jit
+                        self.stats["paged_appends"] += 1
+                        tok = sample(logits)
+                        toks.append(tok)
+                if toks:
+                    jax.block_until_ready(toks[-1])
+                per_step = (self.clock.perf() - t0) / max(steps, 1)
         finally:
             # a raising decode step must still free the block table —
             # leaked paged leases shrink the slab AND the shared pool
@@ -294,17 +307,21 @@ class DecodeRunner:
         toks: List[jax.Array] = []
         try:
             tok = jnp.zeros((n,), jnp.int32)
-            t0 = self.clock.perf()
-            for t in range(steps):
-                logits, lease.cache = self._dense_step(
-                    params, lease.cache,
-                    {"token": tok, "pos": jnp.full((n,), t, jnp.int32)})
-                self.stats["dense_steps"] += 1
-                tok = sample(logits)
-                toks.append(tok)
-            if toks:
-                jax.block_until_ready(toks[-1])
-            per_step = (self.clock.perf() - t0) / max(steps, 1)
+            span = self.recorder.span
+            with span("telerag.decode.steps", rows=n, steps=steps):
+                t0 = self.clock.perf()
+                for t in range(steps):
+                    with span("telerag.decode.dispatch", step=t):
+                        logits, lease.cache = self._dense_step(
+                            params, lease.cache,
+                            {"token": tok,
+                             "pos": jnp.full((n,), t, jnp.int32)})
+                        self.stats["dense_steps"] += 1
+                        tok = sample(logits)
+                        toks.append(tok)
+                if toks:
+                    jax.block_until_ready(toks[-1])
+                per_step = (self.clock.perf() - t0) / max(steps, 1)
         finally:
             kv.release(lease)
         return toks, per_step

@@ -350,7 +350,9 @@ class TeleRAGEngine:
                        wave_key: object = None):
         """The wave's *desired* prefetch plan (None for non-prefetching
         policies) — what admission control reserves headroom for."""
-        return self.policy.plan(self, q_in, gen_tokens, wave_key=wave_key)
+        with self.recorder.span("telerag.lookahead.plan"):
+            return self.policy.plan(self, q_in, gen_tokens,
+                                    wave_key=wave_key)
 
     def lookahead_ex(self, q_in: np.ndarray, gen_tokens: Sequence[int], *,
                      now: float = 0.0, plan=None, ticket=None,
@@ -382,7 +384,8 @@ class TeleRAGEngine:
         """Run the mode policy's retrieval for the rewritten queries at
         event-clock time ``now`` (seconds); ``tenant`` scopes any
         demand-fetch eviction to the requester's floor view."""
-        return self.policy.retrieve(self, q_out, now=now, tenant=tenant)
+        with self.recorder.span("telerag.retrieve", queries=len(q_out)):
+            return self.policy.retrieve(self, q_out, now=now, tenant=tenant)
 
     def end_batch(self) -> None:
         """Post-batch consolidation (paper App. D reproducibility rule)."""

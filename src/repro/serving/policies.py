@@ -122,7 +122,8 @@ class RetrievalPolicy:
                               k=engine.cfg.top_k,
                               kernel_mode=engine.cfg.kernel_mode,
                               fused=engine.cfg.fused_retrieval,
-                              centroids=engine.index.centroids)
+                              centroids=engine.index.centroids,
+                              recorder=engine.recorder)
         used = [c for h in res.hit_clusters for c in h]
         engine.cache.record_lookup([c for r in ranked_out for c in r],
                                    engine.buffer.resident_clusters())

@@ -76,8 +76,8 @@ import numpy as np
 from repro.core.embedder import synthetic_rewrite
 from repro.core.schedulers import SchedulerPolicy
 from repro.memory.pool import PoolExhausted
-from repro.obs.recorder import (DecodeStep, FlightRecorder, RequestEvent,
-                                SpanEvent, WaveEvent)
+from repro.obs.recorder import (DecodeStep, FlightRecorder, HostRequest,
+                                RequestEvent, SpanEvent, WaveEvent)
 from repro.serving.engine import (RequestResult, RoundTelemetry,
                                   TeleRAGEngine)
 from repro.serving.policies import LatencyContext
@@ -173,11 +173,25 @@ class RequestRecord:                   # live state, and `q` is an ndarray
     cur_q: Optional[np.ndarray] = None
     next_round: int = 0
     ready_t: float = float("nan")
+    # host-clock stamps, kept only while the recorder's host spans are on
+    host: Optional[HostRequest] = None
 
     @property
     def latency(self) -> float:
         """Admit→complete on the event clock (seconds)."""
         return self.complete_t - self.admit_t
+
+    @property
+    def host_submit_s(self) -> Optional[float]:
+        """Submission on the host clock (None while host spans are off)."""
+        return None if self.host is None else self.host.submit_s
+
+    @property
+    def host_done_s(self) -> Optional[float]:
+        """End, on the host clock, of the last decode wave or retrieve
+        call that worked for the request (None while host spans are
+        off, or before any has)."""
+        return None if self.host is None else self.host.done_s
 
     def spans(self, kind: str) -> List[Span]:
         """All timeline spans of one kind (e.g. ``"pressure_stall"``)."""
@@ -369,18 +383,23 @@ class RetrievalRuntime:
     def submit(self, q: np.ndarray, trace: RequestTrace,
                arrival_t: float = 0.0, *, tenant: str = "shared",
                priority: int = 0,
-               deadline_t: float = float("inf")) -> RequestRecord:
+               deadline_t: float = float("inf"),
+               host_submit_s: Optional[float] = None) -> RequestRecord:
         """Queue one request. ``arrival_t`` is relative to this run's
         start (the clock is monotonic across run() calls);
         ``deadline_t`` is the request's absolute event-clock deadline in
         seconds (``inf`` = no SLO) and ``tenant``/``priority`` tag it
-        for tenant-scoped admission and SLO accounting."""
+        for tenant-scoped admission and SLO accounting.
+        ``host_submit_s`` is when the request reached the server on the
+        host clock (default now; kept only while host spans are on)."""
         rec = RequestRecord(
             request_id=trace.request_id, pipeline=trace.pipeline,
             trace=trace, q=np.asarray(q), arrival_t=float(arrival_t),
             result=RequestResult(trace.request_id, trace.pipeline),
             tenant=tenant, priority=int(priority),
-            deadline_t=float(deadline_t))
+            deadline_t=float(deadline_t),
+            host=self.recorder.host_request(trace.request_id,
+                                            self.replica_id, host_submit_s))
         self._pending.append(rec)
         self._batch.append(rec)
         return rec
@@ -614,6 +633,24 @@ class RetrievalRuntime:
     def _exec_wave(self, wave: _Wave, *, now: float,
                    starts: Sequence[float], force: bool = False,
                    cohort: Optional[_Cohort] = None) -> None:
+        """``_run_wave`` inside the wave's ``telerag.wave`` host span."""
+        with self.recorder.span("telerag.wave", replica=self.replica_id,
+                                wave=wave.wid):
+            self._run_wave(wave, now=now, starts=starts, force=force,
+                           cohort=cohort)
+
+    def _host_done(self, reqs: Sequence[RequestRecord]) -> None:
+        """Stamp the end of a call that worked for ``reqs`` on the host
+        clock (nothing while host spans are off)."""
+        t = self.recorder.host_now()
+        if t is not None:
+            for r in reqs:
+                if r.host is not None:
+                    r.host.done_s = t
+
+    def _run_wave(self, wave: _Wave, *, now: float,
+                  starts: Sequence[float], force: bool = False,
+                  cohort: Optional[_Cohort] = None) -> None:
         """Execute one wave's round frontier: reserve the wave's pool
         headroom (or park its members ``PRESSURE_STALLED``), run the
         engine data ops for the whole wave, and schedule each member's
@@ -778,6 +815,7 @@ class RetrievalRuntime:
                         wave, keys, hit_pins, fetch_pins, ticket,
                         now=now, starts=starts)
                     return
+                self._host_done(members)
                 if evs is not None:
                     if len(evs) != batch:
                         raise ValueError(
@@ -815,6 +853,7 @@ class RetrievalRuntime:
 
                 # 3) hybrid retrieval (device hits + host misses + merge)
                 res = eng.retrieve(q_out, now=now, tenant=wave.tenant)
+                self._host_done([members[j] for j in ret])
         except BaseException:
             # drop every pin the wave's members hold (hit pins taken
             # before admission, fetch pins taken above, and any earlier

@@ -15,7 +15,12 @@ Pins the observability subsystem's contracts:
   * ``analyze`` reports a positive mean overlap ratio on a
     hyde/iter prefetching mix;
   * ``benchmarks.common.write_report`` round-trips through
-    ``validate_report``.
+    ``validate_report``;
+  * host-clock spans: off, they read no clock and store nothing; on,
+    every wave nests its lookahead, decode and retrieval spans, the
+    request stamps agree with the benchmark probe's host-clock latency,
+    the event stream is unchanged, and the Perfetto host lanes pass
+    ``tools/check_trace.py``.
 """
 
 import dataclasses
@@ -23,14 +28,17 @@ import importlib.util
 import json
 import os
 
+import jax
 import numpy as np
 import pytest
 
 from repro.configs import get_arch
-from repro.obs import (FlightRecorder, MetricsRegistry, analyze,
-                       to_perfetto, write_trace)
-from repro.serving import (EngineConfig, RagRequest, Span, TeleRAGServer,
-                           make_traces)
+from repro.models import transformer as tf
+from repro.obs import (SYSTEM_CLOCK, EventClock, FlightRecorder,
+                       MetricsRegistry, analyze, to_jsonl, to_perfetto,
+                       write_trace)
+from repro.serving import (DecodeRunner, EngineConfig, RagRequest, Span,
+                           TeleRAGServer, make_traces)
 from tests.conftest import unit_queries
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -241,11 +249,6 @@ def test_metrics_registry_primitives():
     assert h.count == 4
     assert h.percentile(50) == pytest.approx(np.percentile(
         [1.0, 2.0, 3.0, 4.0], 50))
-    s = m.series("occ", replica=0)
-    s.sample(1.0, 0.5)
-    s.sample(0.5, 0.25)
-    assert s.last == 0.5                                # clock order, not emission
-    assert [t for t, _ in s.sorted_samples()] == [0.5, 1.0]
 
 
 # ---------------------------------------------------------------------------
@@ -330,3 +333,214 @@ def test_bench_report_roundtrip(tmp_path):
     bad = dict(report, schema="nope")
     with pytest.raises(AssertionError):
         common.validate_report(bad)
+
+
+# ---------------------------------------------------------------------------
+# Host-clock spans
+# ---------------------------------------------------------------------------
+
+
+class CountingClock:
+    """A real clock that counts its reads."""
+
+    real = True
+
+    def __init__(self):
+        self.reads = 0
+
+    def perf(self) -> float:
+        self.reads += 1
+        return SYSTEM_CLOCK.perf()
+
+
+@pytest.fixture(scope="module")
+def dense_params():
+    return tf.init_params(get_arch("llama3-8b").reduced(),
+                          jax.random.PRNGKey(0))
+
+
+def _serve_decode(small_store, small_index, dense_params, *, spans,
+                  wall_clock=None, hook=None, n=6):
+    """A tiny dense model decoding an iter/hyde mix through one
+    continuous replica, host spans on or off; returns (server, runner,
+    responses).  ``hook(runner, server)`` may wrap the decode hook."""
+    arch = get_arch("llama3-8b")
+    runner = DecodeRunner(dense_params, arch.reduced(), max_len=32,
+                          max_steps=6, page_size=4, slab_seqs=n + 2)
+    decode = hook(runner) if hook is not None else runner
+    srv = TeleRAGServer(small_index, EngineConfig(
+        nprobe=8, top_k=3, buffer_pages=256, pool_pages=4096,
+        lookahead_rank=16, kernel_mode="ref", chips=8, seed=7,
+        cache_enabled=True), 1, arch, micro_batch=3, include_tail=True,
+        decode_hook=decode, continuous=True, wall_clock=wall_clock)
+    runner.attach(srv)
+    if hasattr(decode, "wrap"):
+        decode.wrap(srv.engines[0])
+    if spans:
+        srv.recorder.enable_host_spans(SYSTEM_CLOCK)
+    q = unit_queries(small_store, np.random.default_rng(3), n)
+    traces = [dataclasses.replace(t, request_id=i) for i, t in enumerate(
+        make_traces("iter", n // 2, seed=4)
+        + make_traces("hyde", n - n // 2, seed=5))]
+    resp = srv.serve([RagRequest(q=q[i], trace=traces[i])
+                      for i in range(n)])
+    return srv, runner, resp
+
+
+def test_host_spans_off_read_no_clock(small_store, small_index,
+                                      dense_params, monkeypatch):
+    made = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                        lambda *a, **k: made.append(a))
+    clock = CountingClock()
+    srv, runner, resp = _serve_decode(small_store, small_index, dense_params,
+                                      spans=False, wall_clock=clock)
+    rec = srv.recorder
+    assert rec.host_clock is None
+    assert rec.host_spans == [] and rec.host_requests == [] and made == []
+    assert all(r.host_latency_s is None and r.host_submit_s is None
+               for r in resp)
+    # the only reads are the timing the program does with spans off: a
+    # decode wave's two, and a routed wave's scheduler overhead two
+    assert clock.reads == (2 * runner.stats["paged_waves"]
+                           + 2 * len(srv.wave_log))
+
+
+def test_host_spans_refuse_a_modeled_clock():
+    rec = FlightRecorder()
+    with pytest.raises(ValueError):
+        rec.enable_host_spans(EventClock(rec))
+    assert rec.host_clock is None
+
+
+def _inside(child, parent) -> bool:
+    return parent.start <= child.start and child.end <= parent.end
+
+
+def test_host_spans_nest_in_every_wave(small_store, small_index,
+                                       dense_params):
+    srv, runner, resp = _serve_decode(small_store, small_index, dense_params,
+                                      spans=True)
+    spans = srv.recorder.host_spans
+    waves = [s for s in spans if s.name == "telerag.wave"]
+    assert len(waves) == len(srv.runtimes[0].wave_log) > 1
+    by_wave = {}
+    for s in spans:
+        if s.name != "telerag.wave":
+            by_wave.setdefault(s.wave_id, []).append(s)
+    issued = 0
+    for w in waves:
+        kids = sorted(by_wave.get(w.wave_id, []), key=lambda s: s.start)
+        assert w.replica == 0 and all(_inside(k, w) for k in kids)
+        names = [k.name for k in kids]
+        steps = [k for k in kids if k.name == "telerag.decode.steps"]
+        assert len(steps) == 1 and names.count(
+            "telerag.decode.readback") == 1
+        (st,) = steps
+        dispatch = [k for k in kids if k.name == "telerag.decode.dispatch"]
+        assert len(dispatch) == st.args["steps"]
+        assert all(_inside(d, st) for d in dispatch)
+        assert [d.args["step"] for d in dispatch] == list(
+            range(st.args["steps"]))
+        rb = next(k for k in kids if k.name == "telerag.decode.readback")
+        assert st.end <= rb.start
+        for k in kids:
+            if k.name.startswith("telerag.lookahead."):
+                assert k.end <= st.start       # issued before decode
+        issued += names.count("telerag.lookahead.issue")
+        ret = [k for k in kids if k.name == "telerag.retrieve"]
+        if ret:
+            (r,) = ret
+            assert rb.end <= r.start
+            parts = [k for k in kids if k.name.startswith(
+                "telerag.retrieve.")]
+            assert [p.name for p in parts] == [
+                "telerag.retrieve.device", "telerag.retrieve.host",
+                "telerag.retrieve.merge"]
+            assert all(_inside(p, r) for p in parts)
+            assert all(a.end <= b.start for a, b in zip(parts, parts[1:]))
+    assert issued > 0
+    issues = [s for s in spans if s.name == "telerag.lookahead.issue"]
+    assert sum(s.args["pages"] for s in issues) == \
+        srv.engines[0].buffer.stats.pages_h2d
+    assert all(s.args["bytes"] > 0 for s in issues)
+
+
+def test_host_latency_is_the_probe_latency(small_store, small_index,
+                                           dense_params):
+    """``host_latency_s`` is the benchmark's request latency: drain
+    start to the end of the last decode wave or retrieve call that
+    worked for the request, on the same clock."""
+    from benchmarks.chip.probe import Probe  # noqa: PLC0415
+
+    probes = []
+
+    def hook(runner):
+        probes.append(Probe(runner, rows=3))
+        return probes[0]
+
+    t0 = SYSTEM_CLOCK.perf()
+    srv, _, resp = _serve_decode(small_store, small_index, dense_params,
+                                 spans=True, wall_clock=SYSTEM_CLOCK,
+                                 hook=hook)
+    (probe,) = probes
+    for r in resp:
+        assert r.host_latency_s is not None and r.host_latency_s > 0
+        assert r.host_submit_s >= t0
+        assert r.host_done_s == pytest.approx(
+            probe.last_touch[r.request_id], abs=5e-3)
+
+
+def test_host_spans_leave_the_event_stream_unchanged(small_store,
+                                                     small_index,
+                                                     dense_params):
+    off, _, _ = _serve_decode(small_store, small_index, dense_params,
+                              spans=False)
+    on, _, _ = _serve_decode(small_store, small_index, dense_params,
+                             spans=True)
+    assert on.recorder.host_spans
+
+    def stream(rec):
+        # paged lease ids count up across the process: number them by
+        # first appearance
+        ids = {}
+        out = []
+        for line in to_jsonl(rec):
+            ev = json.loads(line)
+            if ev.get("lease_id", -1) >= 0:
+                ev["lease_id"] = ids.setdefault(ev["lease_id"], len(ids))
+            out.append(ev)
+        return out
+
+    assert stream(on.recorder) == stream(off.recorder)
+    assert on.recorder.legacy_tuples() == off.recorder.legacy_tuples()
+
+
+def test_perfetto_host_lanes_validate(small_store, small_index, dense_params,
+                                     tmp_path):
+    srv, _, resp = _serve_decode(small_store, small_index, dense_params,
+                                 spans=True)
+    doc = to_perfetto(srv.recorder)
+    check = _load_check_trace()
+    check.validate_trace(doc)
+    host = [e for e in doc["traceEvents"] if e.get("cat") == "host"]
+    assert len(host) == len(srv.recorder.host_spans)
+    assert check.validate_host_lanes(doc["traceEvents"]) == len(host)
+    lanes = {(e["args"]["name"]) for e in doc["traceEvents"]
+             if e["ph"] == "M" and e["name"] == "thread_name"
+             and e["pid"] >= 10000}
+    assert lanes == {"wave", "decode", "lookahead", "retrieval"}
+    reqs = [e for e in doc["traceEvents"] if e.get("cat") == "host_request"]
+    assert sorted(e["ph"] for e in reqs) == ["b"] * len(resp) + [
+        "e"] * len(resp)
+    assert min(e["ts"] for e in host + reqs) == 0.0
+    out = tmp_path / "trace.json"
+    write_trace(srv.recorder, str(out))
+    assert check.main(["check_trace", str(out)]) == 0
+    # a span that crosses the end of the one it started in is refused
+    steps = next(e for e in host if e["name"] == "telerag.decode.steps")
+    bad = dict(next(e for e in host if e["name"] == "telerag.decode.dispatch"
+                    and e["ts"] >= steps["ts"]))
+    bad["dur"] = steps["ts"] + steps["dur"] + 1e3 - bad["ts"]
+    with pytest.raises(AssertionError):
+        check.validate_host_lanes(doc["traceEvents"] + [bad])

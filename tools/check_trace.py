@@ -12,7 +12,11 @@ Checks, beyond JSON well-formedness:
 * complete spans (``"X"``) have non-negative ``dur``;
 * async begin/end pairs (``"b"``/``"e"``) balance per (cat, id);
 * counter events (``"C"``) exist and include the ledger-occupancy and
-  pool-free-pages tracks the acceptance criteria require.
+  pool-free-pages tracks the acceptance criteria require;
+* host-clock processes (named "host clock: ..."): every span is a
+  ``telerag.*`` span of cat ``host`` on one of the named lanes
+  (wave / decode / lookahead / retrieval), and the spans of one lane
+  nest — each lies wholly inside or wholly after the one before.
 
 After format validation the trace is replayed through the
 happens-before invariant checker (``repro.analysis.invariants``):
@@ -39,6 +43,7 @@ sys.path.insert(0, os.path.join(
 
 from repro.analysis import (check_events, events_from_jsonl,     # noqa: E402
                             events_from_perfetto)
+from repro.obs.export import HOST_LANES                          # noqa: E402
 
 # phases that must carry a timestamp
 _TIMED = {"X", "B", "E", "b", "e", "i", "C"}
@@ -86,7 +91,42 @@ def validate_trace(doc: Dict) -> Dict[str, int]:
     assert not missing, \
         f"missing required counter tracks: {sorted(missing)} " \
         f"(have {sorted(counters)})"
+    validate_host_lanes(events)
     return phases
+
+
+def validate_host_lanes(events) -> int:
+    """Assert the host-clock processes' lanes hold nested ``telerag.*``
+    spans; returns how many host spans there are."""
+    host = {ev["pid"] for ev in events
+            if ev.get("ph") == "M" and ev.get("name") == "process_name"
+            and str(ev["args"].get("name", "")).startswith("host clock")}
+    lanes = {(ev["pid"], ev["tid"]): ev["args"].get("name")
+             for ev in events if ev.get("ph") == "M"
+             and ev.get("name") == "thread_name" and ev["pid"] in host}
+    per_lane: Dict[Tuple[int, int], list] = {}
+    for i, ev in enumerate(events):
+        if ev.get("ph") != "X" or ev["pid"] not in host:
+            continue
+        key = (ev["pid"], ev.get("tid"))
+        assert lanes.get(key) in HOST_LANES, \
+            f"host span {i} on an unnamed lane: {ev}"
+        assert ev.get("cat") == "host" and \
+            str(ev.get("name", "")).startswith("telerag."), \
+            f"host span {i} is not a telerag.* host span: {ev}"
+        per_lane.setdefault(key, []).append(
+            (ev["ts"], ev["ts"] + ev["dur"], ev["name"]))
+    eps = 1e-3                                          # µs
+    for key, spans in per_lane.items():
+        open_: list = []
+        for s, e, name in sorted(spans, key=lambda x: (x[0], -x[1])):
+            while open_ and open_[-1][1] <= s + eps:
+                open_.pop()
+            assert not open_ or e <= open_[-1][1] + eps, \
+                f"host span {name} [{s}, {e}] crosses the end of " \
+                f"{open_[-1][2]} on lane {lanes[key]} of pid {key[0]}"
+            open_.append((s, e, name))
+    return sum(len(v) for v in per_lane.values())
 
 
 def check_invariants(doc: Dict, path: str,
