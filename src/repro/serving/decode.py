@@ -30,7 +30,9 @@ measurement; the library default is the deterministic event clock, which
 is what lets tests pin paged==dense telemetry exactly.  While the server
 recorder's host spans are on, the step loop (``telerag.decode.steps``),
 each step's host work (``telerag.decode.dispatch``) and the token
-read-back (``telerag.decode.readback``) are host-clock spans.
+read-back (``telerag.decode.readback``) are host-clock spans.  The
+read-back is one batched transfer of the wave's ``[steps, rows]``
+tokens after the last step (``stats["readback_syncs"]`` counts them).
 """
 
 from __future__ import annotations
@@ -111,7 +113,7 @@ class DecodeRunner:
         self.wave_step_seconds: List[float] = []
         self.stats = {"paged_waves": 0, "dense_waves": 0,
                       "paged_appends": 0, "dense_steps": 0,
-                      "spliced_waves": 0}
+                      "spliced_waves": 0, "readback_syncs": 0}
 
     # -- wiring --------------------------------------------------------------
     def attach(self, server) -> "DecodeRunner":
@@ -219,10 +221,17 @@ class DecodeRunner:
         if steps:
             self.wave_step_seconds.append(per_step)
         with self.recorder.span("telerag.decode.readback", rows=n,
-                                steps=len(toks)):
-            for j, r in enumerate(records):
+                                steps=len(toks), syncs=int(bool(toks))):
+            # one transfer of the whole [steps, rows] wave, stacked on the
+            # host: a device-side stack would compile once per wave length
+            if toks:
+                host = np.stack(jax.device_get(toks))
+                self.stats["readback_syncs"] += 1
+            else:
+                host = np.zeros((0, n), np.int32)
+            for r, row in zip(records, host.T.tolist()):
                 self.generated.setdefault(r.request_id, []).append(
-                    tuple(int(t[j]) for t in toks))
+                    tuple(row))
         return [DecodeEvent(request_id=r.request_id,
                             tokens=min(g, steps) if g else 0,
                             seconds=per_step * (min(g, steps) if g else 0))

@@ -20,6 +20,7 @@ conservation, no append past capacity) is verified on every run here.
 
 import dataclasses
 import math
+from types import SimpleNamespace
 
 import numpy as np
 import jax
@@ -30,6 +31,7 @@ from hypothesis import strategies as st
 
 from repro.analysis import check_recorder
 from repro.configs import get_arch
+from repro.data.chunk_kv import build_chunk_kv
 from repro.models import transformer as tf
 from repro.serving import (DecodeRunner, EngineConfig, KVCacheManager,
                            RagRequest,
@@ -352,3 +354,111 @@ def test_step_sees_pre_append_lengths_on_aligned_buffers():
             np.testing.assert_array_equal(lease.lengths, [2, 3])
     finally:
         kv.release_paged(lease)
+
+
+# ---------------------------------------------------------------------------
+# Token read-back: one batched transfer per wave
+# ---------------------------------------------------------------------------
+
+
+def _direct_runner(small_index, params, mode, *, micro_batch=3):
+    """A runner attached to a one-replica server, to be called directly
+    as the decode hook; ``mode`` is ``paged``, ``dense`` or ``spliced``
+    (paged over a chunk store holding docs 0-5).  Returns the runner
+    and ``waves``, which keeps every wave's step arrays."""
+    store = None
+    if mode == "spliced":
+        store = build_chunk_kv(params, CFG, range(6), page_size=4, seed=3,
+                               min_len=6, max_len=8)
+    runner = DecodeRunner(params, CFG, max_len=32, max_steps=6, page_size=4,
+                          slab_seqs=micro_batch + 8, chunk_store=store)
+    srv = TeleRAGServer(small_index, EngineConfig(
+        nprobe=8, top_k=3, buffer_pages=256, pool_pages=4096,
+        lookahead_rank=16, kernel_mode="ref", chips=8, seed=7,
+        paged_decode=mode != "dense", chunk_kv=store is not None), 1, ARCH,
+        micro_batch=micro_batch, include_tail=True, decode_hook=runner,
+        continuous=True)
+    runner.attach(srv)
+    waves = []
+
+    def keep(run):
+        def wrapped(*args, **kw):
+            toks, per_step = run(*args, **kw)
+            waves.append(toks)
+            return toks, per_step
+        return wrapped
+
+    runner._run_paged = keep(runner._run_paged)
+    runner._run_dense = keep(runner._run_dense)
+    return runner, waves
+
+
+def _records(ids):
+    """Stand-in wave members: what the decode hook reads of a record.
+    Each has retrieved its own docs, so spliced rows decode apart."""
+    return [SimpleNamespace(request_id=i, tenant="shared",
+                            result=SimpleNamespace(
+                                doc_ids=[[i % 6, (i + 2) % 6][:1 + i % 2]]))
+            for i in ids]
+
+
+@pytest.mark.parametrize("mode", ["paged", "dense", "spliced"])
+def test_readback_equals_per_element_read(small_index, params, mode):
+    """``generated`` from the one batched read equals a read of every
+    token on its own, ``int(t[j])``: the same values, Python ints, in
+    round order, for a full wave, a wave with fewer live rows than the
+    micro-batch (its padding rows stay out) and a wave of 0 steps."""
+    runner, waves = _direct_runner(small_index, params, mode)
+    plan = [(_records([0, 1, 2]), [5, 2, 4]),
+            (_records([3]), [3]),
+            (_records([4, 5]), [0, 0]),
+            (_records([0, 1]), [2, 6])]
+    want = {}
+    for recs, gen in plan:
+        runner(0, recs, gen, 1)
+        toks = waves[-1]
+        assert len(toks) == max(gen)
+        for j, r in enumerate(recs):
+            want.setdefault(r.request_id, []).append(
+                tuple(int(t[j]) for t in toks))
+    assert runner.generated == want
+    assert all(type(x) is int for rounds in runner.generated.values()
+               for row in rounds for x in row)
+    assert runner.generated[4] == runner.generated[5] == [()]
+    assert [len(x) for x in runner.generated[0]] == [5, 6]
+    rows = waves[1][0].shape[0]
+    assert rows == (1 if mode == "dense" else 3)   # padded to micro_batch
+    assert runner.stats["readback_syncs"] == 3     # the 0-step wave reads none
+    if mode == "spliced":
+        assert runner.stats["spliced_waves"] > 0
+        # rows splice other docs, so a mixed-up column would show
+        assert len({runner.generated[i][0][:2] for i in (0, 1, 2)}) > 1
+    runner.kv(0).drop_all()
+    if runner.chunk(0) is not None:
+        runner.chunk(0).drain()
+
+
+def test_readback_syncs_once_per_wave_and_compiles_nothing(small_index,
+                                                           params):
+    """After a first wave, waves of step counts never seen before read
+    back with one sync each and compile no program: a device-side stack
+    of the step arrays would compile once per wave length."""
+    jax.clear_caches()                     # only this test's first wave warms
+    runner, _ = _direct_runner(small_index, params, "paged")
+    runner(0, _records([0, 1, 2]), [4, 1, 3], 1)
+    compiles = []
+
+    def listen(event, secs, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(secs)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        runner(0, _records([3, 4, 5]), [5, 2, 5], 1)
+        runner(0, _records([6, 7, 8]), [1, 6, 2], 1)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert compiles == []
+    assert runner.stats["readback_syncs"] == runner.stats["paged_waves"] == 3
+    assert [len(runner.generated[i][0]) for i in (0, 3, 6)] == [4, 5, 6]
+    runner.kv(0).drop_all()
