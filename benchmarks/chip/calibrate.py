@@ -3,7 +3,7 @@
 its controls' (``check.py``), over many seeds in one process, each seed a
 short run of the cell at its own size.
 
-  python benchmarks/chip/calibrate.py --workload granite-moe-3b.multiround \\
+  python benchmarks/chip/calibrate.py --workload granite-20b-stage.hyde \\
       --seeds 11 12 13 --seconds 10
 
 Prints one JSON line per seed on standard output.  Needs the chip, as

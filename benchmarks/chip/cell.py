@@ -1,8 +1,13 @@
 """Finds a cell's pieces by name: its entry in ``BENCHMARK.json``, the
-configuration file the entry names, the traffic file
-``traffic/<traffic>.json`` and each metric's reader
-``metrics/<metric>.py``.  A new cell, configuration, traffic mix or
-metric is new files plus new entries; nothing here changes."""
+configuration file the entry names, the model layout
+``layouts/<layout>.py`` and the reference ``references/<reference>.py``
+the file names, the traffic file ``traffic/<traffic>.json`` and each
+metric's reader ``metrics/<metric>.py``.
+
+A new cell, traffic mix or metric is new files plus new entries; so is
+a configuration, of an architecture the benchmark already states or of
+a new one: a layout file, a reference file, a configuration file naming
+both, and entries.  Nothing here changes."""
 
 from __future__ import annotations
 
@@ -59,6 +64,23 @@ def load_cell(name: str, bench: Optional[dict] = None,
     return Cell(name=name, chips=int(w["chips"]), config=cfg,
                 traffic=traffic, end_to_end=list(bench["end_to_end"]),
                 per_layer=list(bench["per_layer"]))
+
+
+def load_layout(cfg: dict):
+    """The module ``layouts/<cfg["layout"]>.py``.  A layout gives
+    ``Shape`` (``Shape.from_config(cfg)``, with what ``counts.Shape``
+    reads), ``arch_config(cfg)``, the served model's ``ArchConfig``, and
+    the served parameter tree made from the seed,
+    ``program_params(cfg, seed, device)``, or its shapes,
+    ``param_shapes(cfg)``.  The file names its layout; there is no
+    default."""
+    known = sorted(f[:-3] for f in os.listdir(os.path.join(HERE, "layouts"))
+                   if f.endswith(".py"))
+    name = cfg.get("layout")
+    if name not in known:
+        raise KeyError(f"configuration {cfg.get('name')!r}: layout {name!r} "
+                       f"is none of {known}")
+    return importlib.import_module(f"benchmarks.chip.layouts.{name}")
 
 
 _READERS: Dict[str, Callable] = {}

@@ -15,21 +15,28 @@ against the plain references.
   ``check.probe_edge_precision`` rounds (``references/ivf.py``).  A
   missing id, or one from a cluster no such probe takes, reads infinite.
 
+Every configuration compares the same numbers, ``COMPARED``; its file
+gives each a limit, ``check.<name>_limit``.  A limit of null is one not
+yet set from readings: no run of that file comes out correct.
+
 The controls (``control=True``) put the reference in the program's place
-one precision lower: float8 weights and inputs for the bf16 decoder,
-one bf16 pass for the float32 search.
+one precision lower: float8 weights and the inputs of every product,
+attention's among them, for the bf16 decoder; one bf16 pass for the
+float32 search.
 The benchmark's own runs do not run them.
 """
 
 from __future__ import annotations
 
 import importlib
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
 from benchmarks.chip.references import ivf
 from benchmarks.chip.traffic import seed_rng
+
+COMPARED = ("logit_gap", "retrieval_score_gap")
 
 
 def decode_sample(generated: Dict[int, list], window_waves, seed: int,
@@ -117,12 +124,27 @@ def retrieval_gaps(corpus, ds: dict, rounds, edge_precision: str,
     return out
 
 
+def limits_of(cfg: dict) -> Dict[str, Optional[float]]:
+    """The limit of each of ``COMPARED`` from the file's ``check``: a
+    file that leaves one out, or gives a limit to another number, is an
+    error."""
+    chk = cfg["check"]
+    want = {f"{name}_limit" for name in COMPARED}
+    have = {k for k in chk if k.endswith("_limit")}
+    if have != want:
+        raise ValueError(f"{cfg['name']}: check limits {sorted(have)}, "
+                         f"want exactly {sorted(want)}")
+    return {name: chk[f"{name}_limit"] for name in COMPARED}
+
+
 def verdict(numbers: dict, limits: dict) -> Tuple[bool, List[str]]:
-    """``correct`` and one line per number compared: name, value, limit."""
+    """``correct`` and one line per number compared: name, value, limit.
+    A limit of None, none set from readings, fails."""
     lines, ok = [], True
     for name, limit in limits.items():
         val = numbers.get(name)
-        good = val is not None and np.isfinite(val) and val <= limit
+        good = (limit is not None and val is not None and np.isfinite(val)
+                and val <= limit)
         ok &= bool(good)
         lines.append(f"{name} {val!r} limit {limit!r} "
                      f"{'ok' if good else 'FAIL'}")

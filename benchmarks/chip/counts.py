@@ -2,56 +2,44 @@
 
 These are the numerators of the roofline shares and of ``step_mfu``:
 what the algorithm needs, not what an implementation happens to do, so
-they read the same whatever kernel computes the work.
+they read the same whatever kernel computes the work.  A model's counts
+come from its layout's ``Shape`` (``layouts/``): the parameters a decoded
+token multiplies by and the attention FLOPs per context token; the
+paged attention kernel's from the heads it is called with.
 """
 
 from __future__ import annotations
 
 import math
-from typing import Sequence, Tuple
-
-from benchmarks.chip.model import Shape
+from typing import Protocol, Sequence, Tuple
 
 
-def layer_params(s: Shape) -> Tuple[int, int]:
-    """(all, touched per token) parameters of one decoder layer: the
-    attention projections, the router and experts (top-k of them touched)
-    or the dense MLP, and the two RMSNorm weights."""
-    attn = 2 * s.d * s.heads * s.head_dim + 2 * s.d * s.kv_heads * s.head_dim
-    norms = 2 * s.d
-    if s.moe:
-        expert = 3 * s.d * s.ff
-        router = s.d * s.experts
-        return (attn + router + s.experts * expert + norms,
-                attn + router + s.top_k * expert + norms)
-    mlp = (3 if s.gated else 2) * s.d * s.ff
-    return attn + mlp + norms, attn + mlp + norms
+class Shape(Protocol):
+    """What the counts read of a layout's ``Shape``: the sizes paged
+    attention is called with, and the model's own counts."""
 
+    layers: int
+    heads: int
+    kv_heads: int
+    head_dim: int
 
-def param_count(s: Shape) -> int:
-    """Every parameter held: layers, embedding, output head, final norm."""
-    return s.layers * layer_params(s)[0] + 2 * s.vocab * s.d + s.d
+    def matmul_params_per_token(self) -> int: ...
 
-
-def matmul_params_per_token(s: Shape) -> int:
-    """Parameters a decoded token multiplies by: each layer's touched
-    matrices and the output head (the embedding is a lookup, the norms
-    are not matrix products)."""
-    return s.layers * (layer_params(s)[1] - 2 * s.d) + s.d * s.vocab
+    def attn_flops_per_context_token(self) -> int: ...
 
 
 def decode_token_flops(s: Shape, context: int) -> float:
     """FLOPs to decode one token that attends over ``context`` tokens
     (itself included): 2 per multiplied parameter, plus QK^T and PV."""
-    attn = 4 * s.heads * s.head_dim * context
-    return 2 * matmul_params_per_token(s) + s.layers * attn
+    return (2 * s.matmul_params_per_token()
+            + s.attn_flops_per_context_token() * context)
 
 
 def wave_flops(s: Shape, gens: Sequence[int]) -> float:
     """FLOPs of the live tokens of one wave: row j decodes ``gens[j]``
     tokens from position 0, token t attending over t + 1."""
-    per_tok = 2 * matmul_params_per_token(s)
-    attn = 4 * s.heads * s.head_dim * s.layers
+    per_tok = 2 * s.matmul_params_per_token()
+    attn = s.attn_flops_per_context_token()
     return sum(g * per_tok + attn * g * (g + 1) / 2 for g in gens)
 
 

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import ml_dtypes
 import numpy as np
 
-from benchmarks.chip.model import seed_key
+from benchmarks.chip.weights import seed_key
 
 HIGHEST = jax.lax.Precision.HIGHEST
 

@@ -23,7 +23,7 @@ import jax
 import numpy as np
 
 from benchmarks.chip import cell as cell_mod
-from benchmarks.chip import check, datastore, model, xplane
+from benchmarks.chip import check, counts, datastore, xplane
 from benchmarks.chip.peaks import peak_for
 from benchmarks.chip.probe import Probe, Retrieval, Wave
 from benchmarks.chip.traffic import Traffic, make_requests
@@ -60,7 +60,7 @@ class Window:
     """What a metric reader reads: the window's calls, counts and times,
     and the trace of a traced run."""
 
-    shape: model.Shape
+    shape: counts.Shape
     t0: float
     t1: float
     drains: List[Drain]
@@ -236,7 +236,9 @@ def run_cell(c: "cell_mod.Cell", *, seed: int, seconds: float, trace: bool,
     break the served path underneath."""
     _listen_compiles()
     cfg = c.config
-    shape = model.Shape.from_config(cfg)
+    limits = check.limits_of(cfg)
+    layout = cell_mod.load_layout(cfg)
+    shape = layout.Shape.from_config(cfg)
     traffic = Traffic.from_dict(c.traffic)
     ds = cfg["datastore"]
     peak = peak_for(device.device_kind) if device.platform == "tpu" else None
@@ -246,11 +248,11 @@ def run_cell(c: "cell_mod.Cell", *, seed: int, seconds: float, trace: bool,
     index = datastore.program_index(corpus, ds)
     t_index = time.perf_counter() - t
     t = time.perf_counter()
-    params = model.program_params(cfg, seed, device)
+    params = layout.program_params(cfg, seed, device)
     jax.block_until_ready(params)
     t_weights = time.perf_counter() - t
     t = time.perf_counter()
-    srv, runner, probe = build(cfg, model.arch_config(cfg), params, index,
+    srv, runner, probe = build(cfg, layout.arch_config(cfg), params, index,
                                device, seed)
     t_server = time.perf_counter() - t
     if after_build is not None:
@@ -341,8 +343,6 @@ def run_cell(c: "cell_mod.Cell", *, seed: int, seconds: float, trace: bool,
     numbers.update(check.retrieval_gaps(
         corpus, ds, rounds, cfg["check"]["probe_edge_precision"],
         control=control))
-    limits = {"logit_gap": cfg["check"]["logit_gap_limit"],
-              "retrieval_score_gap": cfg["check"]["retrieval_score_gap_limit"]}
     ok, lines = check.verdict(numbers, limits)
     bad_modes = {k: v for k, v in modes.items()
                  if k in ("flash_decode_paged", "probe_and_topk")

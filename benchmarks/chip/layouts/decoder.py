@@ -1,25 +1,25 @@
-"""A configuration file's model: its shapes, the served model's
-``ArchConfig``, and its weights made from the seed.
+"""The layout ``decoder``: a grouped-query-attention decoder with rotary
+positions, RMSNorm and either a dense MLP (gated or not) or a top-k
+mixture of gated experts with no shared expert; an untied output head
+and no muP multipliers.
 
-The weights are the benchmark's own: every leaf is drawn from
-(seed, leaf name, layer) by ``leaf_values``, so the reference in
-``references/`` draws the very same values layer by layer without taking
-anything from the program.  ``program_params`` lays them out as the
-served model's parameter tree, on the device, in one jitted call.
+A configuration file names it with ``"layout": "decoder"``.  It gives the
+configuration's sizes (``Shape``) with the counts the metrics take from
+them, the served model's ``ArchConfig``, and the served model's
+parameter tree made from the seed (``weights.py``).
 """
 
 from __future__ import annotations
 
 import dataclasses
-import math
-import zlib
 from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from benchmarks.chip.weights import leaf_key, leaf_values, seed_key
+
 ACTIVATIONS = {"silu": "silu", "gelu_pytorch_tanh": "gelu"}
-NORM_STD = 0.1          # RMSNorm weights are 1 + NORM_STD * N(0, 1)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -68,24 +68,41 @@ class Shape:
     def moe(self) -> bool:
         return self.experts > 0
 
+    # -- the counts the metrics take from the shape (``counts.py``) --------
 
-def arch_config(cfg: dict):
-    """The served model's ``ArchConfig`` for a configuration file."""
-    from repro.configs.base import ArchConfig, MoEConfig
+    def layer_params(self) -> Tuple[int, int]:
+        """(all, touched per token) parameters of one layer: the attention
+        projections, the router and experts (top-k of them touched) or
+        the dense MLP, and the two RMSNorm weights."""
+        d = self.d
+        attn = 2 * d * self.heads * self.head_dim \
+            + 2 * d * self.kv_heads * self.head_dim
+        norms = 2 * d
+        if self.moe:
+            expert = 3 * d * self.ff
+            router = d * self.experts
+            return (attn + router + self.experts * expert + norms,
+                    attn + router + self.top_k * expert + norms)
+        mlp = (3 if self.gated else 2) * d * self.ff
+        return attn + mlp + norms, attn + mlp + norms
 
-    s = Shape.from_config(cfg)
-    moe = None
-    if s.moe:
-        moe = MoEConfig(num_experts=s.experts, top_k=s.top_k,
-                        d_ff_expert=s.ff,
-                        capacity_factor=float(cfg["moe_capacity_factor"]))
-    return ArchConfig(
-        name=f"bench-{cfg['name']}", family="moe" if s.moe else "dense",
-        source=cfg["source"], num_layers=s.layers, d_model=s.d,
-        num_heads=s.heads, num_kv_heads=s.kv_heads, head_dim=s.head_dim,
-        d_ff=s.ff, vocab_size=s.vocab, attn_kind="gqa", moe=moe,
-        mlp_act=s.act, mlp_gated=s.gated, norm_eps=s.eps,
-        rope_theta=s.rope_theta, tie_embeddings=False, dtype="bfloat16")
+    def param_count(self) -> int:
+        """Every parameter held: layers, embedding, output head, final
+        norm."""
+        return self.layers * self.layer_params()[0] + 2 * self.vocab * self.d \
+            + self.d
+
+    def matmul_params_per_token(self) -> int:
+        """Parameters a decoded token multiplies by: each layer's touched
+        matrices and the output head (the embedding is a lookup, the
+        norms are not matrix products)."""
+        return self.layers * (self.layer_params()[1] - 2 * self.d) \
+            + self.d * self.vocab
+
+    def attn_flops_per_context_token(self) -> int:
+        """FLOPs a decoded token spends on each token it attends over, in
+        all layers: QK^T and PV."""
+        return 4 * self.heads * self.head_dim * self.layers
 
 
 def leaf_specs(s: Shape) -> Dict[str, Tuple[Tuple[int, ...], Optional[int]]]:
@@ -114,24 +131,23 @@ def leaf_specs(s: Shape) -> Dict[str, Tuple[Tuple[int, ...], Optional[int]]]:
     return out
 
 
-def seed_key(seed: int) -> jax.Array:
-    """A PRNG key for any whole seed up to 64 bits."""
-    k = jax.random.fold_in(jax.random.PRNGKey(0), seed & 0xFFFFFFFF)
-    return jax.random.fold_in(k, (seed >> 32) & 0xFFFFFFFF)
+def arch_config(cfg: dict):
+    """The served model's ``ArchConfig`` for a configuration file."""
+    from repro.configs.base import ArchConfig, MoEConfig
 
-
-def leaf_key(key: jax.Array, name: str, layer: int = 0) -> jax.Array:
-    k = jax.random.fold_in(key, zlib.crc32(name.encode()) & 0x7FFFFFFF)
-    return jax.random.fold_in(k, layer)
-
-
-def leaf_values(key: jax.Array, shape: Tuple[int, ...],
-                fan_in: Optional[int]) -> jax.Array:
-    """One layer's leaf as served (bf16).  For an RMSNorm weight this is
-    the served model's stored offset: the weight is 1 + this."""
-    std = NORM_STD if fan_in is None else 1.0 / math.sqrt(fan_in)
-    return (jax.random.normal(key, shape, jnp.float32) * std
-            ).astype(jnp.bfloat16)
+    s = Shape.from_config(cfg)
+    moe = None
+    if s.moe:
+        moe = MoEConfig(num_experts=s.experts, top_k=s.top_k,
+                        d_ff_expert=s.ff,
+                        capacity_factor=float(cfg["moe_capacity_factor"]))
+    return ArchConfig(
+        name=f"bench-{cfg['name']}", family="moe" if s.moe else "dense",
+        source=cfg["source"], num_layers=s.layers, d_model=s.d,
+        num_heads=s.heads, num_kv_heads=s.kv_heads, head_dim=s.head_dim,
+        d_ff=s.ff, vocab_size=s.vocab, attn_kind="gqa", moe=moe,
+        mlp_act=s.act, mlp_gated=s.gated, norm_eps=s.eps,
+        rope_theta=s.rope_theta, tie_embeddings=False, dtype="bfloat16")
 
 
 def _make_tree(s: Shape, key: jax.Array) -> dict:

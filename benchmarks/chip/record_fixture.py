@@ -26,7 +26,7 @@ def main(out: str) -> int:
     sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
     import jax
     import jax.numpy as jnp
-    from benchmarks.chip import cell, model, xplane
+    from benchmarks.chip import cell, xplane
     from repro.models import transformer as tf
 
     dev = jax.devices()[0]
@@ -35,8 +35,9 @@ def main(out: str) -> int:
         return 2
     cfg = cell.load_json(os.path.join(ROOT, "benchmarks", "chip", "configs",
                                       "granite-moe-3b.json"))
-    arch = model.arch_config(cfg)
-    params = model.program_params(cfg, 1, dev)
+    layout = cell.load_layout(cfg)
+    arch = layout.arch_config(cfg)
+    params = layout.program_params(cfg, 1, dev)
     B, ps, blocks = 16, 16, 4
     slab = (arch.num_layers, B * blocks + 1, ps, arch.num_kv_heads,
             arch.resolved_head_dim)

@@ -1,17 +1,21 @@
-"""Plain float32 reference of a decoder configuration: token embedding,
-then per layer RMSNorm, grouped-query attention with rotary positions
-(rotate-half, theta from the file), RMSNorm and either a top-k softmax
-mixture of gated experts (renormalized over the k chosen, no token
-dropped) or a dense MLP; a final RMSNorm and the output head.
+"""Plain float32 reference of the layout ``decoder`` (``layouts/decoder.py``):
+token embedding, then per layer RMSNorm, grouped-query attention with
+rotary positions (rotate-half, theta from the file), RMSNorm and either
+a top-k softmax mixture of gated experts (renormalized over the k
+chosen, no token dropped) or a dense MLP; a final RMSNorm and the output
+head.
 
 It imports nothing of the program.  Weights are drawn from the seed by
-``model.leaf_values``, one layer at a time, and every product runs at
+``weights.leaf_values``, one layer at a time, and every product runs at
 ``HIGHEST`` precision.  ``quant="fp8"`` is the control, the precision
-below the bf16 the model is served in: every weight matrix and the
-input of every product with a weight rounded to float8 e4m3, with one
-scale per output channel (weights) or per token (inputs), the
-arithmetic still float32.  Attention's own products (queries by keys,
-probabilities by values) stay float32.
+below the bf16 the model is served in: every weight matrix and the input
+of every product rounded to float8 e4m3, the arithmetic still float32.
+Weights take one scale per output channel; the inputs of a product with
+a weight, and attention's queries, keys and values, one per token;
+attention's probabilities one per row.  ``quant="fp8_weights"`` rounds
+the weights and the inputs of their products only, leaving attention's
+own products in float32: the weaker control, kept to show what the
+attention rounding adds.
 """
 
 from __future__ import annotations
@@ -23,10 +27,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.chip.model import Shape, leaf_key, leaf_specs, leaf_values, seed_key
+from benchmarks.chip.layouts.decoder import Shape, leaf_specs
+from benchmarks.chip.weights import leaf_key, leaf_values, seed_key
 
 HIGHEST = jax.lax.Precision.HIGHEST
 PAD = 128                 # sequences pad to a multiple of this
+CONTROLS = ("fp8", "fp8_weights")
 
 # the contraction axes of each weight: fp8 scales are per output channel
 _IN_AXES = {"embed": (1,), "unembed": (0,), "layers.attn.wq": (0,),
@@ -63,7 +69,7 @@ def _weights(key, s: Shape, names: Sequence[str], layer: int,
                         ).astype(jnp.float32)
         if fan_in is None:
             w = 1.0 + w                  # RMSNorm weight
-        elif quant == "fp8":
+        elif quant in CONTROLS:
             w = _fp8(w, _in_axes(name, s))
         elif quant is not None:
             raise ValueError(f"unknown control precision {quant!r}")
@@ -90,20 +96,23 @@ def _act(name):
             "gelu": functools.partial(jax.nn.gelu, approximate=True)}[name]
 
 
-@functools.partial(jax.jit, static_argnames=("s", "quant"))
-def _layer(x, w, s: Shape, quant: bool):
-    """One decoder layer over one sequence x [T, d]."""
+@functools.partial(jax.jit, static_argnames=("s", "quant", "attn"))
+def _layer(x, w, s: Shape, quant: bool, attn: bool):
+    """One decoder layer over one sequence x [T, d]; ``quant`` rounds the
+    inputs of products with weights, ``attn`` attention's own."""
     T = x.shape[0]
     ein = functools.partial(jnp.einsum, precision=HIGHEST)
     a = _inputs(_rms(x, w["attn_norm"], s.eps), quant)
     q = _rope(ein("td,dhk->thk", a, w["wq"]), s.rope_theta)
     k = _rope(ein("td,dhk->thk", a, w["wk"]), s.rope_theta)
     v = ein("td,dhk->thk", a, w["wv"])
+    q, k, v = (_inputs(t, attn, (1, 2)) for t in (q, k, v))
     g = s.heads // s.kv_heads
     q = q.reshape(T, s.kv_heads, g, s.head_dim)
     sc = ein("tcgk,uck->cgtu", q, k) / np.sqrt(s.head_dim)
     causal = jnp.tril(jnp.ones((T, T), bool))
-    p = jax.nn.softmax(jnp.where(causal, sc, -jnp.inf), axis=-1)
+    p = _inputs(jax.nn.softmax(jnp.where(causal, sc, -jnp.inf), axis=-1),
+                attn)
     o = ein("cgtu,uck->tcgk", p, v).reshape(
         T, s.heads, s.head_dim)
     x = x + ein("thk,hkd->td", _inputs(o, quant, (1, 2)), w["wo"])
@@ -151,7 +160,8 @@ class Hidden:
         names = [n for n in leaf_specs(s) if n.startswith("layers.")]
         for l in range(s.layers):
             w = _weights(key, s, names, l, quant)
-            xs = [_layer(x, w, s, quant is not None) for x in xs]
+            xs = [_layer(x, w, s, quant is not None, quant == "fp8")
+                  for x in xs]
             del w
         self.shape, self.xs, self.lengths = s, xs, [len(t) for t in inputs]
         self.quant = quant is not None

@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """The on-chip benchmark: one run of one cell, in one process.
 
-  python benchmarks/chip/run.py --workload granite-moe-3b.multiround \\
+  python benchmarks/chip/run.py --workload granite-20b-stage.hyde \\
       --seed 7 --seconds 51 --trace 0
 
 The cell (``BENCHMARK.json`` ``workloads``) names a configuration file

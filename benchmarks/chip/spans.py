@@ -133,17 +133,21 @@ def labelled(bench_spans, traced: List[ProgramSpan]):
 
 def idle_cover(ops, window, bench_spans, traced: List[ProgramSpan]) -> dict:
     """Idle seconds inside ``bench.decode_wave`` and ``bench.retrieve``
-    calls, and the share of them labelled with a ``telerag.*`` span."""
+    calls, and the share of them labelled with a ``telerag.*`` span.  A
+    gap counts for the part of it that lies inside a call, labelled by
+    the innermost span at that part's middle."""
     from benchmarks.chip import xplane
 
     calls = [(s, e) for n, s, e in bench_spans if n in xplane.CALL_SPANS]
+    spans = labelled(bench_spans, traced)
     inside = named = 0.0
-    for label, s, e in xplane.idle_gaps(ops, window,
-                                        labelled(bench_spans, traced)):
-        mid = (s + e) / 2
-        if any(cs <= mid <= ce for cs, ce in calls):
-            inside += e - s
-            named += (e - s) if label.startswith(PREFIX) else 0.0
+    for _, s, e in xplane.idle_gaps(ops, window, spans):
+        for cs, ce in calls:
+            lo, hi = max(s, cs), min(e, ce)
+            if hi > lo:
+                inside += hi - lo
+                if xplane.span_at(spans, (lo + hi) / 2).startswith(PREFIX):
+                    named += hi - lo
     return {"idle_in_calls_s": inside * 1e-9,
             "share_labelled": named / inside if inside else None}
 
@@ -160,17 +164,18 @@ def run(c, *, seed: int, seconds: float, spans: bool, trace_dir, device,
     import numpy as np
 
     from benchmarks.chip import cell as cell_mod
-    from benchmarks.chip import datastore, harness, model, xplane
+    from benchmarks.chip import datastore, harness, xplane
     from benchmarks.chip.peaks import peak_for
     from benchmarks.chip.traffic import Traffic
     from repro.obs import SYSTEM_CLOCK
 
     cfg, ds = c.config, c.config["datastore"]
+    layout = cell_mod.load_layout(cfg)
     traffic = Traffic.from_dict(c.traffic)
     corpus = datastore.make_corpus(ds, seed)
     index = datastore.program_index(corpus, ds)
-    params = model.program_params(cfg, seed, device)
-    srv, runner, probe = harness.build(cfg, model.arch_config(cfg), params,
+    params = layout.program_params(cfg, seed, device)
+    srv, runner, probe = harness.build(cfg, layout.arch_config(cfg), params,
                                        index, device, seed)
     harness.warm_up(srv, traffic, corpus, cfg, seed)
     setup_s = time.perf_counter() - t_start
@@ -182,7 +187,7 @@ def run(c, *, seed: int, seconds: float, spans: bool, trace_dir, device,
     t0, t1, drains, waves, rounds, wave_s, h2d, traced = harness.run_window(
         srv, probe, runner, traffic, corpus, cfg, seed, seconds, trace_dir)
     w = harness.Window(
-        shape=model.Shape.from_config(cfg), t0=t0, t1=t1, drains=drains,
+        shape=layout.Shape.from_config(cfg), t0=t0, t1=t1, drains=drains,
         waves=waves, retrievals=rounds,
         latencies=[probe.last_touch[rid] - d.t0 for d in drains
                    for rid in d.request_ids],

@@ -43,6 +43,13 @@ def run(config: str, tmp_path, **kw) -> dict:
                             trace_dir=str(tmp_path / "trace"), **kw)
 
 
+# The numbers whose control fails its limit in the harness's own run.  At
+# the 22 positions tiny-dense decodes, its fp8 control's widest gap reads
+# 0.073, under the limit 0.1: there only the retrieval control fails.
+CONTROL_FAILS = {"tiny-moe": {"logit_gap", "retrieval_score_gap"},
+                 "tiny-dense": {"retrieval_score_gap"}}
+
+
 @pytest.mark.parametrize("config", ["tiny-moe", "tiny-dense"])
 def test_sound_run_is_correct_and_its_control_is_not(config, tmp_path):
     res = run(config, tmp_path, control=True)
@@ -50,13 +57,68 @@ def test_sound_run_is_correct_and_its_control_is_not(config, tmp_path):
     assert res["attempted"] > 0 and res["failed"] == 0
     n = res["numbers"]
     limits = {k: v["limit"] for k, v in res["compared"].items()}
-    ok, _ = check.verdict({"logit_gap": n["control_logit_gap"],
-                           "retrieval_score_gap":
-                               n["control_retrieval_score_gap"]}, limits)
+    assert set(limits) == set(check.COMPARED)
+    ok, _ = check.verdict({k: n[f"control_{k}"] for k in limits}, limits)
     assert not ok
+    failed = {k for k in limits
+              if not check.verdict({k: n[f"control_{k}"]},
+                                   {k: limits[k]})[0]}
+    assert CONTROL_FAILS[config] <= failed, n
     assert set(res["metrics"]) == {"tokens_per_s", "latency_p50_s",
                                    "latency_p90_s", "setup_s"}
     assert list(res)[-1] == "compared"
+
+
+@pytest.mark.parametrize("config", ["tiny-moe", "tiny-dense"])
+def test_the_decode_control_rounds_attention_too(config):
+    """Over 4 x 64 positions a seed: each decode control fails the
+    configuration's ``logit_gap`` limit, and the control that also rounds
+    attention's inputs lies further from the reference's logits than the
+    one that rounds only the products with weights.  At this size the
+    widest gap does not order the two: a few near-ties decide it, and
+    either control can read the larger."""
+    from benchmarks.chip.references import decoder as ref
+    cfg = tiny_cell(config).config
+    vocab = cfg["model"]["vocab_size"]
+    for seed in (SEED, SEED + 1, SEED + 2):
+        rng = np.random.default_rng(seed)
+        inputs = [rng.integers(0, vocab, 64, dtype=np.int32)
+                  for _ in range(4)]
+        want = np.concatenate(ref.Hidden(cfg, seed, inputs).logits())
+        err = {}
+        for quant in ref.CONTROLS:
+            got = np.concatenate(ref.Hidden(cfg, seed, inputs,
+                                            quant=quant).logits())
+            at = want[np.arange(len(want)), got.argmax(-1)]
+            assert np.max(want.max(-1) - at) > \
+                cfg["check"]["logit_gap_limit"], (quant, seed)
+            err[quant] = np.mean(np.abs(got - want))
+        assert err["fp8"] > err["fp8_weights"], (seed, err)
+
+
+def test_a_file_compares_exactly_the_fixed_numbers():
+    cfg = tiny_cell("tiny-dense").config
+    assert check.limits_of(cfg) == {"logit_gap": 0.1,
+                                    "retrieval_score_gap": 1e-05}
+    left_out = {**cfg, "check": {k: v for k, v in cfg["check"].items()
+                                 if k != "logit_gap_limit"}}
+    with pytest.raises(ValueError, match="logit_gap_limit"):
+        check.limits_of(left_out)
+    added = {**cfg, "check": {**cfg["check"], "decode_positions_limit": 1}}
+    with pytest.raises(ValueError, match="decode_positions_limit"):
+        check.limits_of(added)
+
+
+def test_a_limit_not_set_from_readings_fails_every_run():
+    """granite-moe-3b has no ``logit_gap`` limit that separates its
+    control: even a gap of 0 does not come out correct by its file."""
+    cfg = cell.load_json(os.path.join(HERE, "configs",
+                                      "granite-moe-3b.json"))
+    limits = check.limits_of(cfg)
+    assert limits["logit_gap"] is None
+    ok, lines = check.verdict({"logit_gap": 0.0, "retrieval_score_gap": 0.0},
+                              limits)
+    assert not ok and lines[0].endswith("FAIL")
 
 
 def _state_unchanged(monkeypatch):

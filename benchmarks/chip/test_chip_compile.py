@@ -1,6 +1,6 @@
 """Compile rehearsal of the benchmark's cells for a described TPU v5e:
 the 768-wide ``probe_and_topk`` at every retrieval batch size the cells
-meet, over the pool the harness builds, and the granite-20b-stage decode
+meet, over the pool the harness builds, and each configuration's decode
 step at published widths, with its weights.
 
 Nothing runs.  The topology is described inside a fixture, never at
@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from benchmarks.chip import cell, model
+from benchmarks.chip import cell
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -45,8 +45,8 @@ def pool_rows(cfg: dict) -> int:
     from repro.memory.pool import device_rows
     from repro.serving import KVCacheManager
     sv, ds = cfg["serving"], cfg["datastore"]
-    kv = KVCacheManager(model.arch_config(cfg)).nbytes(sv["micro_batch"],
-                                                       sv["max_len"])
+    arch = cell.load_layout(cfg).arch_config(cfg)
+    kv = KVCacheManager(arch).nbytes(sv["micro_batch"], sv["max_len"])
     per = page_nbytes(ds["page_size"], ds["dim"])
     return device_rows(ds["buffer_pages"] + -(-kv // per))
 
@@ -69,17 +69,18 @@ def test_probe_and_topk_768_compiles_for_v5e(one_chip, name, queries):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_granite_20b_stage_decode_step_compiles_for_v5e(one_chip):
+def decode_step_compiles(one_chip, name: str) -> None:
     from repro.models import transformer as tf
-    cfg = config("granite-20b-stage")
-    arch, sv = model.arch_config(cfg), cfg["serving"]
+    cfg = config(name)
+    layout = cell.load_layout(cfg)
+    arch, sv = layout.arch_config(cfg), cfg["serving"]
     B, ps = sv["micro_batch"], sv["kv_page_size"]
     blocks = -(-sv["max_len"] // ps)
     slab = (arch.num_layers, sv["slab_seqs"] * blocks + 1, ps,
             arch.num_kv_heads, arch.resolved_head_dim)
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     params = jax.tree.map(lambda x: s(x.shape, x.dtype),
-                          model.param_shapes(cfg))
+                          layout.param_shapes(cfg))
     step = jax.jit(lambda p, k, v, bt, lens, tok, live: tf.serve_step_paged(
         p, k, v, bt, lens, {"token": tok, "live_rows": live}, arch,
         kernel_mode="kernel"), donate_argnums=(1, 2))
@@ -90,3 +91,11 @@ def test_granite_20b_stage_decode_step_compiles_for_v5e(one_chip):
     mem = compiled.memory_analysis()
     held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert held < 15.5e9, f"{held} bytes on a 16 GB chip"
+
+
+def test_granite_20b_stage_decode_step_compiles_for_v5e(one_chip):
+    decode_step_compiles(one_chip, "granite-20b-stage")
+
+
+def test_granite_moe_3b_decode_step_compiles_for_v5e(one_chip):
+    decode_step_compiles(one_chip, "granite-moe-3b")

@@ -1,7 +1,8 @@
-"""The on-chip benchmark's harness, on the CPU: loading cells by name,
-the traffic generator, the FLOP and byte counts against hand-worked
-values, the weights the reference redraws, the trace reduction on a
-trace recorded on the chip, and the command's refusal without a TPU."""
+"""The on-chip benchmark's harness, on the CPU: loading cells and model
+layouts by name, the traffic generator, the FLOP and byte counts against
+hand-worked values, the weights a layout makes and the reference
+redraws, the trace reduction on a trace recorded on the chip, and the
+command's refusal without a TPU."""
 
 from __future__ import annotations
 
@@ -9,13 +10,14 @@ import json
 import os
 import subprocess
 import sys
+import zlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks.chip import cell, counts, model, xplane
+from benchmarks.chip import cell, counts, weights, xplane
 from benchmarks.chip.peaks import peak_for
 from benchmarks.chip.traffic import Traffic, windows
 
@@ -35,7 +37,7 @@ def test_every_cell_loads_by_name(bench):
         assert c.chips == w["chips"] == 1
         assert c.config["name"] == w["config"]
         assert c.traffic["name"] == w["traffic"]
-        model.arch_config(c.config)
+        cell.load_layout(c.config).arch_config(c.config)
         Traffic.from_dict(c.traffic)
         for trace in (False, True):
             for m in c.metrics(trace):
@@ -59,6 +61,36 @@ def test_each_reduced_key_is_a_cut_or_a_departure(bench):
 def test_unknown_workload_is_an_error(bench):
     with pytest.raises(KeyError):
         cell.load_cell("no-such.cell", bench)
+
+
+CONFIG_FILES = sorted(
+    os.path.join(d, f) for d in ("configs", "testdata")
+    for f in os.listdir(os.path.join(HERE, d))
+    if f.endswith(".json") and f != "tiny-traffic.json")
+
+
+@pytest.mark.parametrize("path", CONFIG_FILES)
+def test_every_configuration_resolves_through_its_named_layout(path):
+    cfg = cell.load_json(os.path.join(HERE, path))
+    layout = cell.load_layout(cfg)
+    assert layout.__name__ == f"benchmarks.chip.layouts.{cfg['layout']}"
+    s = layout.Shape.from_config(cfg)
+    arch = layout.arch_config(cfg)
+    assert (arch.num_layers, arch.num_heads, arch.num_kv_heads) == (
+        s.layers, s.heads, s.kv_heads)
+    leaves = jax.tree.leaves(layout.param_shapes(cfg))
+    assert sum(x.size for x in leaves) == s.param_count()
+
+
+@pytest.mark.parametrize("name", ["no-such-layout", None])
+def test_an_unknown_or_missing_layout_is_an_error(name):
+    cfg = cell.load_json(os.path.join(HERE, "testdata", "tiny-dense.json"))
+    if name is None:
+        del cfg["layout"]
+    else:
+        cfg["layout"] = name
+    with pytest.raises(KeyError, match="none of .*'decoder'"):
+        cell.load_layout(cfg)
 
 
 @pytest.mark.parametrize("name", ["multiround", "hyde"])
@@ -105,33 +137,34 @@ def test_requests_differ_by_seed_in_order_and_queries_only():
 # -- counts, against values worked by hand ------------------------------------
 
 def shape(name):
-    return model.Shape.from_config(cell.load_json(
-        os.path.join(HERE, "configs", f"{name}.json")))
+    cfg = cell.load_json(os.path.join(HERE, "configs", f"{name}.json"))
+    return cell.load_layout(cfg).Shape.from_config(cfg)
 
 
 def test_granite_moe_counts():
     s = shape("granite-moe-3b")
     # attention 6,291,456 + router 61,440 + 40 experts 94,371,840 + norms
-    assert counts.layer_params(s) == (100_727_808, 25_230_336)
-    assert counts.param_count(s) == 3_374_295_552
-    assert counts.matmul_params_per_token(s) == 882_774_528
+    assert s.layer_params() == (100_727_808, 25_230_336)
+    assert s.param_count() == 3_374_295_552
+    assert s.matmul_params_per_token() == 882_774_528
     assert counts.decode_token_flops(s, 1) == 1_765_745_664
 
 
 def test_granite_20b_stage_counts():
     s = shape("granite-20b-stage")
-    assert counts.layer_params(s)[0] == 379_072_512
-    assert counts.param_count(s) == 5_531_928_576
-    assert counts.matmul_params_per_token(s) == 5_229_772_800
+    assert s.layer_params()[0] == 379_072_512
+    assert s.param_count() == 5_531_928_576
+    assert s.matmul_params_per_token() == 5_229_772_800
     assert counts.decode_token_flops(s, 1) == 10_459_865_088
 
 
 @pytest.mark.parametrize("name", ["granite-moe-3b", "granite-20b-stage"])
 def test_param_count_is_the_weights_made(name):
     cfg = cell.load_json(os.path.join(HERE, "configs", f"{name}.json"))
-    leaves = jax.tree.leaves(model.param_shapes(cfg))
-    assert sum(x.size for x in leaves) == counts.param_count(
-        model.Shape.from_config(cfg))
+    layout = cell.load_layout(cfg)
+    leaves = jax.tree.leaves(layout.param_shapes(cfg))
+    assert sum(x.size for x in leaves) == layout.Shape.from_config(
+        cfg).param_count()
     assert all(x.dtype == jnp.bfloat16 for x in leaves)
 
 
@@ -167,19 +200,59 @@ def test_unknown_device_kind_is_an_error():
 
 
 def test_reference_redraws_the_served_weights():
+    from benchmarks.chip.references import decoder as ref
     cfg = cell.load_json(os.path.join(HERE, "testdata", "tiny-moe.json"))
-    s = model.Shape.from_config(cfg)
-    tree = model.program_params(cfg, 2**40 + 3, jax.devices()[0])
-    key = model.seed_key(2**40 + 3)
-    for name, (shp, fan_in) in model.leaf_specs(s).items():
+    layout = cell.load_layout(cfg)
+    s = layout.Shape.from_config(cfg)
+    tree = layout.program_params(cfg, 2**40 + 3, jax.devices()[0])
+    key = weights.seed_key(2**40 + 3)
+    for name, (shp, fan_in) in ref.leaf_specs(s).items():
         node = tree
         for part in name.split("."):
             node = node[part]
         for layer in range(s.layers if name.startswith("layers.") else 1):
             got = node[layer] if name.startswith("layers.") else node
-            want = model.leaf_values(model.leaf_key(key, name, layer), shp,
-                                     fan_in)
+            want = weights.leaf_values(weights.leaf_key(key, name, layer),
+                                       shp, fan_in)
             assert bool(jnp.array_equal(got, want)), (name, layer)
+
+
+# crc32 of each leaf's bf16 bits at seed 2**40 + 3, as the weights were
+# made before the layouts were named in the configuration files
+PINNED_LEAVES = {
+    "tiny-moe": {
+        "['embed']": 3335536517, "['final_norm']": 2641948502,
+        "['layers']['attn']['wk']": 2042051119,
+        "['layers']['attn']['wo']": 2610886845,
+        "['layers']['attn']['wq']": 2815202024,
+        "['layers']['attn']['wv']": 3287094584,
+        "['layers']['attn_norm']": 580096979,
+        "['layers']['mlp']['router']": 896475838,
+        "['layers']['mlp']['w_down']": 998746818,
+        "['layers']['mlp']['w_gate']": 378712331,
+        "['layers']['mlp']['w_up']": 2595723472,
+        "['layers']['mlp_norm']": 1178708074, "['unembed']": 3944596257},
+    "tiny-dense": {
+        "['embed']": 3335536517, "['final_norm']": 2641948502,
+        "['layers']['attn']['wk']": 1689732409,
+        "['layers']['attn']['wo']": 2610886845,
+        "['layers']['attn']['wq']": 2815202024,
+        "['layers']['attn']['wv']": 1828347581,
+        "['layers']['attn_norm']": 580096979,
+        "['layers']['mlp']['w_down']": 4073857534,
+        "['layers']['mlp']['w_up']": 2595723472,
+        "['layers']['mlp_norm']": 1178708074, "['unembed']": 3944596257}}
+
+
+@pytest.mark.parametrize("name", sorted(PINNED_LEAVES))
+def test_layout_makes_the_pinned_weights(name):
+    cfg = cell.load_json(os.path.join(HERE, "testdata", f"{name}.json"))
+    tree = cell.load_layout(cfg).program_params(cfg, 2**40 + 3,
+                                                jax.devices()[0])
+    got = {jax.tree_util.keystr(p): zlib.crc32(
+        np.asarray(v).view(np.uint16).tobytes())
+        for p, v in jax.tree_util.tree_flatten_with_path(tree)[0]}
+    assert got == PINNED_LEAVES[name]
 
 
 # -- trace reduction ----------------------------------------------------------
