@@ -93,7 +93,9 @@ class ArchConfig:
     attn_logit_softcap: Optional[float] = None
     final_logit_softcap: Optional[float] = None
     rope_theta: float = 10_000.0
-    rope_fraction: float = 1.0           # nemotron: partial rotary
+    rope_fraction: float = 1.0           # nemotron: partial rotary; 0 = NoPE
+    # score scale; None = 1/sqrt(head_dim) (granite's muP: 1/head_dim)
+    attention_multiplier: Optional[float] = None
 
     # mlp --------------------------------------------------------------------
     mlp_act: str = "silu"      # silu | gelu | relu2
@@ -108,10 +110,20 @@ class ArchConfig:
     # backbone blocks, with per-application LoRA deltas of this rank.
     shared_attn_every: int = 0
     shared_attn_lora_rank: int = 0
+    # hybrid (granite-4.0-h): the mixer of each layer, "mamba" (a Mamba-2
+    # mixer, ``ssm``) or "attention" (GQA), each followed by the MLP.
+    # None: every layer is the family's own block.
+    layer_types: Optional[Tuple[str, ...]] = None
 
     # misc -------------------------------------------------------------------
     norm_eps: float = 1e-6
     tie_embeddings: bool = False
+    embed_scale_sqrt_d: bool = False     # gemma: embeddings times sqrt(d)
+    # muP scalars (granite): embeddings times, each residual branch
+    # times, and logits divided by
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     dtype: str = "bfloat16"
     # long_500k eligibility (sub-quadratic attention); see DESIGN.md §4.
     subquadratic: bool = False
@@ -126,6 +138,11 @@ class ArchConfig:
     @property
     def has_attention(self) -> bool:
         return self.attn_kind != "none"
+
+    def layers_of(self, kind: str) -> Tuple[int, ...]:
+        """Indices of the layers whose mixer is ``kind`` (``layer_types``)."""
+        return tuple(i for i, t in enumerate(self.layer_types or ())
+                     if t == kind)
 
     def param_count(self) -> int:
         """Analytic parameter count (exact for our parameterization)."""
@@ -171,6 +188,8 @@ class ArchConfig:
             nmat = 3 if self.mlp_gated else 2
             per_layer += nmat * d * self.d_ff
         per_layer += 2 * d  # norms
+        if self.layer_types is not None:
+            return n + d + self._hybrid_layer_params()
         n += L * per_layer
         if self.shared_attn_every:
             n += 4 * d * d  # one shared attention block
@@ -179,6 +198,21 @@ class ArchConfig:
             n_apps = self.num_layers // self.shared_attn_every
             n += n_apps * self.shared_attn_lora_rank * 2 * d * 4
         return n
+
+    def _hybrid_layer_params(self) -> int:
+        """Every layer of a ``layer_types`` hybrid: its mixer (Mamba-2 with
+        conv, A, dt bias, D and gated norm; or GQA), the MLP, two norms."""
+        d, hd = self.d_model, self.resolved_head_dim
+        s = self.ssm
+        d_in = s.expand * d
+        H = d_in // s.head_dim
+        conv_ch = d_in + 2 * s.state_dim
+        mamba = (d * (2 * d_in + 2 * s.state_dim + H) + s.conv_width * conv_ch
+                 + conv_ch + 3 * H + d_in + d_in * d)
+        attn = 2 * d * self.num_heads * hd + 2 * d * self.num_kv_heads * hd
+        common = (3 if self.mlp_gated else 2) * d * self.d_ff + 2 * d
+        return (len(self.layers_of("mamba")) * (mamba + common)
+                + len(self.layers_of("attention")) * (attn + common))
 
     def active_param_count(self) -> int:
         """Active params per token (MoE: only top_k experts count)."""
@@ -222,6 +256,8 @@ class ArchConfig:
             kw["num_layers"] = 4
         if self.sliding_window:
             kw["sliding_window"] = 8
+        if self.layer_types is not None:
+            kw["layer_types"] = ("mamba", "attention", "mamba", "mamba")
         return dataclasses.replace(self, **kw)
 
 
@@ -264,5 +300,5 @@ def _ensure_loaded() -> None:
     from repro.configs import (  # noqa: F401
         gemma2_27b, minicpm3_4b, granite_20b, nemotron4_15b, granite_moe_3b,
         arctic_480b, rwkv6_3b, zamba2_2_7b, internvl2_1b, musicgen_large,
-        llama3_8b,
+        llama3_8b, granite_4_h_micro,
     )

@@ -22,6 +22,7 @@ GEMMA2_27B = register(ArchConfig(
     mlp_gated=True,
     rope_theta=10_000.0,
     tie_embeddings=True,
+    embed_scale_sqrt_d=True,
     # half the layers are 4096-token sliding window; global-layer KV is
     # sequence-sharded for long_500k (DESIGN.md §4).
     subquadratic=True,

@@ -21,8 +21,8 @@ Accepted modes (aliases in parentheses):
                                   parity tests use)
   * "ref" ("oracle")            — pure-jnp oracle
 
-Paged entry points (``flash_decode_paged``, ``probe_and_topk``) read the
-pool's pages IN PLACE through block tables / slot-cluster maps — no
+Paged entry points (``flash_decode_paged``, ``probe_and_topk``,
+``ssm_decode_update``) read the pool's pages or the state slab IN PLACE through block tables / slot-cluster maps — no
 compaction copy between ``memory/pool.py`` and the kernels; the dense
 forms keep their pad-and-flatten prep for callers that hold dense slabs.
 """
@@ -40,6 +40,7 @@ from repro.kernels.centroid_probe import centroid_scores as _probe_kernel
 from repro.kernels.flash_decode import flash_decode_paged as _flash_paged_kernel
 from repro.kernels.ivf_topk import ivf_topk_flat as _ivf_kernel
 from repro.kernels.probe_topk import probe_topk_fused as _probe_topk_kernel
+from repro.kernels.ssm_decode import ssm_decode_update as _ssm_kernel
 
 DEFAULT_MODE = "auto"
 MODE_ENV_VAR = "REPRO_KERNEL_MODE"
@@ -238,3 +239,21 @@ def flash_decode_spliced(q: jax.Array, k_pages: jax.Array,
     return ref_mod.flash_decode_spliced_ref(
         q, k_pages, v_pages, block_table, lengths, page_delta, page_valid,
         rope_fraction=rope_fraction, rope_theta=rope_theta)
+
+
+def ssm_decode_update(state: jax.Array, layer: jax.Array, slots: jax.Array,
+                      x: jax.Array, dt: jax.Array, B: jax.Array,
+                      C: jax.Array, A: jax.Array, D: jax.Array, *,
+                      mode: str = DEFAULT_MODE,
+                      ) -> Tuple[jax.Array, jax.Array]:
+    """One Mamba-2 decode step of the recurrent state slab
+    [L, S, H, P, N] f32: row b's slot ``slots[b]`` of layer ``layer``
+    is read and written in place.  x [B, H, P], dt [B, H] (after
+    softplus), B, C [B, N], A, D [H].  Returns (y [B, H, P] f32, the
+    updated slab)."""
+    m = _resolve_for("ssm_decode_update", mode)
+    if m == "ref":
+        return ref_mod.ssm_decode_update_ref(state, layer, slots, x, dt, B,
+                                             C, A, D)
+    return _ssm_kernel(state, layer, slots, x, dt, B, C, A, D,
+                       interpret=_interpret(m))

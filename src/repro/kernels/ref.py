@@ -153,3 +153,25 @@ def flash_decode_paged_ref(q: jax.Array, k_pages: jax.Array,
     k = k_pages[bt].reshape(B, MB * ps, KVH, Dh)
     v = v_pages[bt].reshape(B, MB * ps, KVH, Dh)
     return flash_decode_ref(q, k, v, lengths - 1, window)
+
+
+def ssm_decode_update_ref(state: jax.Array, layer: jax.Array,
+                          slots: jax.Array, x: jax.Array, dt: jax.Array,
+                          B: jax.Array, C: jax.Array, A: jax.Array,
+                          D: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """One Mamba-2 decode step of the state slab, f32.
+
+    state [L, S, H, P, N]; layer int32 scalar; slots [B] int32 (each
+    row's state slot); x [B, H, P]; dt [B, H] (after softplus); B, C
+    [B, N]; A, D [H].  Per row: ``S <- exp(dt A) S + dt x (outer) B``,
+    ``y = S C + D x``.  Returns (y [B, H, P] f32, state with the rows'
+    slots of layer ``layer`` replaced)."""
+    f32 = jnp.float32
+    x, dt, B, C = (t.astype(f32) for t in (x, dt, B, C))
+    S = state[layer, slots].astype(f32)                # [B, H, P, N]
+    dA = jnp.exp(dt * A.astype(f32)[None, :])          # [B, H]
+    S = (S * dA[..., None, None]
+         + (dt[..., None] * x)[..., None] * B[:, None, None, :])
+    y = jnp.sum(S * C[:, None, None, :], axis=-1) \
+        + D.astype(f32)[None, :, None] * x
+    return y, state.at[layer, slots].set(S.astype(state.dtype))

@@ -30,6 +30,15 @@ def attn_params(mk: Maker, cfg: ArchConfig, prefix: str = "attn") -> dict:
     }
 
 
+def scale_query(q: jax.Array, cfg: ArchConfig) -> jax.Array:
+    """Fold ``cfg.attention_multiplier`` into the query: the scoring
+    cores scale scores by 1/sqrt(head_dim) themselves."""
+    if cfg.attention_multiplier is None:
+        return q
+    return q * jnp.asarray(cfg.attention_multiplier
+                           * math.sqrt(cfg.resolved_head_dim), q.dtype)
+
+
 def _project_qkv(p: dict, x: jax.Array, cfg: ArchConfig, positions: jax.Array):
     H, KVH = cfg.num_heads, cfg.num_kv_heads
     G = H // KVH
@@ -38,6 +47,7 @@ def _project_qkv(p: dict, x: jax.Array, cfg: ArchConfig, positions: jax.Array):
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
     q = apply_rope(q, positions, fraction=cfg.rope_fraction, theta=cfg.rope_theta)
     k = apply_rope(k, positions, fraction=cfg.rope_fraction, theta=cfg.rope_theta)
+    q = scale_query(q, cfg)
     B, S = x.shape[:2]
     q = q.reshape(B, S, KVH, G, cfg.resolved_head_dim)
     return q, k, v
@@ -82,7 +92,7 @@ def attn_decode(p: dict, x: jax.Array, cfg: ArchConfig, *,
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
     q = apply_rope(q, positions, fraction=cfg.rope_fraction, theta=cfg.rope_theta)
     k = apply_rope(k, positions, fraction=cfg.rope_fraction, theta=cfg.rope_theta)
-    q = q.reshape(B, 1, KVH, G, Dh)
+    q = scale_query(q, cfg).reshape(B, 1, KVH, G, Dh)
 
     # scatter new k/v at pos (per-batch dynamic index)
     def put(cache, new):

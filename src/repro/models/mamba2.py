@@ -12,7 +12,7 @@ Recurrence (head h, P = head channels, N = state dim, ngroups = 1):
 
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -128,31 +128,69 @@ def mamba2_forward(p: dict, x: jax.Array, cfg: ArchConfig, *,
             conv_out, state_out.astype(state_in.dtype))
 
 
-def mamba2_step(p: dict, x: jax.Array, cfg: ArchConfig, *,
-                conv_in: jax.Array, state_in: jax.Array,
-                ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Exact O(1) decode step. x: [B,d]."""
+def _step_in(p: dict, x: jax.Array, cfg: ArchConfig, conv_in: jax.Array):
+    """A decode step up to the SSM: the in-projection and the conv over
+    (conv_in ++ this token).  Returns (z, x [B,H,P], B, C, dt after
+    softplus, A, conv_out)."""
     B, d = x.shape
     d_in, H, P, N = mamba2_dims(cfg)
     proj = jnp.einsum("bd,de->be", x, p["w_in"])
     z, xbc, dt = _split_in(cfg, proj)
-    # conv over (conv_in ++ xbc)
-    cw = p["conv_w"].shape[0]
     full = jnp.concatenate([conv_in.astype(xbc.dtype), xbc[:, None, :]], axis=1)
     conv_val = jnp.einsum("bwc,wc->bc", full, p["conv_w"]) + p["conv_b"]
     xbc = jax.nn.silu(conv_val)
-    conv_out = full[:, 1:, :]
     xc = xbc[..., :d_in].reshape(B, H, P)
     Bm = xbc[..., d_in:d_in + N]
     Cm = xbc[..., d_in + N:]
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
     a = -jnp.exp(p["a_log"].astype(jnp.float32))
+    return z, xc, Bm, Cm, dt, a, full[:, 1:, :]
+
+
+def _step_out(p: dict, y: jax.Array, z: jax.Array, x: jax.Array,
+              cfg: ArchConfig) -> jax.Array:
+    """A decode step after the SSM: gated RMSNorm and out-projection of
+    y [B,H,P] f32."""
+    y = y.reshape(x.shape[0], -1).astype(x.dtype)
+    y = rms_norm(y * jax.nn.silu(z), p["out_norm"], cfg.norm_eps)
+    return jnp.einsum("be,ed->bd", y, p["w_out"])
+
+
+def mamba2_step(p: dict, x: jax.Array, cfg: ArchConfig, *,
+                conv_in: jax.Array, state_in: jax.Array,
+                ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Exact O(1) decode step. x: [B,d]."""
+    z, xc, Bm, Cm, dt, a, conv_out = _step_in(p, x, cfg, conv_in)
     dec = jnp.exp(dt * a[None, :])                                # [B,H]
     S = state_in.astype(jnp.float32)
     S = S * dec[..., None, None] + jnp.einsum(
         "bh,bhp,bn->bhpn", dt, xc.astype(jnp.float32), Bm.astype(jnp.float32))
     y = jnp.einsum("bhpn,bn->bhp", S, Cm.astype(jnp.float32))
     y = y + p["d_skip"].astype(jnp.float32)[None, :, None] * xc.astype(jnp.float32)
-    y = y.reshape(B, d_in).astype(x.dtype)
-    y = rms_norm(y * jax.nn.silu(z), p["out_norm"], cfg.norm_eps)
-    return jnp.einsum("be,ed->bd", y, p["w_out"]), conv_out, S.astype(state_in.dtype)
+    return _step_out(p, y, z, x, cfg), conv_out, S.astype(state_in.dtype)
+
+
+def mamba2_step_paged(p: dict, x: jax.Array, cfg: ArchConfig, *,
+                      conv_rows: jax.Array, ssm_slab: jax.Array,
+                      layer: jax.Array, slots: jax.Array,
+                      kernel_mode: Optional[str] = None,
+                      ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """``mamba2_step`` against state slabs shared by every layer and
+    sequence: row b's state is slot ``slots[b]`` of mamba layer
+    ``layer``, in ssm_slab [Lm, S, H, P, N] f32 and in conv_rows
+    [Lm*S, (cw-1)*C] (row ``layer * S + slot``).  The conv rows are
+    gathered and scattered back; the SSM update is
+    ``kernels.ops.ssm_decode_update``, which reads and writes the slab
+    in place.  Returns (y [B,d], conv_rows, ssm_slab)."""
+    from repro.kernels import ops as kernel_ops
+
+    r = layer * ssm_slab.shape[1] + slots
+    cw = cfg.ssm.conv_width
+    z, xc, Bm, Cm, dt, a, conv_out = _step_in(
+        p, x, cfg, conv_rows[r].reshape(len(slots), cw - 1, -1))
+    conv_rows = conv_rows.at[r].set(
+        conv_out.reshape(len(slots), -1).astype(conv_rows.dtype))
+    y, ssm_slab = kernel_ops.ssm_decode_update(
+        ssm_slab, layer, slots, xc, dt, Bm, Cm, a,
+        p["d_skip"].astype(jnp.float32), mode=kernel_mode)
+    return _step_out(p, y, z, x, cfg), conv_rows, ssm_slab

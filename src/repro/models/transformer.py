@@ -37,6 +37,8 @@ Params = Dict[str, Any]
 
 
 def family_kind(cfg: ArchConfig) -> str:
+    if cfg.layer_types is not None:
+        return "hybrid"
     if cfg.ssm is not None and cfg.ssm.kind == "rwkv6":
         return "rwkv6"
     if cfg.shared_attn_every:
@@ -78,6 +80,42 @@ def _layer_builder(cfg: ArchConfig):
         return p
 
     return build
+
+
+def _hybrid_builders(cfg: ArchConfig) -> Dict[str, Any]:
+    """A ``layer_types`` hybrid's layer of each mixer kind: the mixer,
+    then the MLP, each after its RMSNorm."""
+    d = cfg.d_model
+    unknown = set(cfg.layer_types) - {"mamba", "attention"}
+    if unknown:
+        raise ValueError(f"unknown layer types {sorted(unknown)}")
+
+    def tail(mk: Maker) -> Params:
+        return {"mlp_norm": mk("mlp_norm", (d,), ("embed",)),
+                "mlp": mlp_params(mk, d, cfg.d_ff, cfg.mlp_gated)}
+
+    return {
+        "mamba": lambda mk: {"norm": mk("norm", (d,), ("embed",)),
+                             "mamba": mamba_mod.mamba2_params(mk, cfg),
+                             **tail(mk)},
+        "attention": lambda mk: {"attn_norm": mk("attn_norm", (d,), ("embed",)),
+                                 "attn": attn_mod.attn_params(mk, cfg),
+                                 **tail(mk)},
+    }
+
+
+def layer_runs(cfg: ArchConfig) -> Tuple[Tuple[str, int, int], ...]:
+    """A hybrid's layers as runs of one mixer kind, in order: (kind,
+    index of the run's first layer in that kind's stack, length)."""
+    runs: list = []
+    seen = {"mamba": 0, "attention": 0}
+    for t in cfg.layer_types:
+        if runs and runs[-1][0] == t:
+            runs[-1][2] += 1
+        else:
+            runs.append([t, seen[t], 1])
+        seen[t] += 1
+    return tuple(tuple(r) for r in runs)
 
 
 def _shared_block_builder(cfg: ArchConfig):
@@ -139,7 +177,13 @@ def init_params(cfg: ArchConfig, key: jax.Array,
     mk = lambda k: InitMaker(k, dtype=dtype)
     top = _top_builder(cfg)(mk(jax.random.fold_in(key, 0)))
 
-    if kind == "zamba2":
+    if kind == "hybrid":
+        layers = {}
+        for j, (t, build) in enumerate(_hybrid_builders(cfg).items()):
+            keys = jax.random.split(jax.random.fold_in(key, 4 + j),
+                                    len(cfg.layers_of(t)))
+            layers[t] = jax.vmap(lambda k, b=build: b(mk(k)))(keys)
+    elif kind == "zamba2":
         G, per = zamba2_groups(cfg)
         keys = jax.random.split(jax.random.fold_in(key, 1), G * per)
         layers = jax.vmap(lambda k: layer_build(mk(k)))(keys)
@@ -158,19 +202,23 @@ def init_params(cfg: ArchConfig, key: jax.Array,
 def param_axes(cfg: ArchConfig) -> Params:
     """Logical-axis tree structurally matching ``init_params`` output."""
     mk = AxesMaker()
-    layer_axes = _layer_builder(cfg)(mk)
     kind = family_kind(cfg)
     top = _top_builder(cfg)(mk)
+    stacked = lambda tree: jax.tree.map(lambda ax: ("layers",) + ax, tree,
+                                        is_leaf=lambda x: isinstance(x, tuple))
+    if kind == "hybrid":
+        top["layers"] = {t: stacked(build(mk))
+                         for t, build in _hybrid_builders(cfg).items()}
+        return top
+    layer_axes = _layer_builder(cfg)(mk)
     if kind == "zamba2":
         layer_axes = jax.tree.map(lambda ax: ("layers", "layers") + ax, layer_axes,
                                   is_leaf=lambda x: isinstance(x, tuple))
         build_shared, build_lora = _shared_block_builder(cfg)
         top["shared"] = build_shared(mk)
-        top["lora"] = jax.tree.map(lambda ax: ("layers",) + ax, build_lora(mk),
-                                   is_leaf=lambda x: isinstance(x, tuple))
+        top["lora"] = stacked(build_lora(mk))
     else:
-        layer_axes = jax.tree.map(lambda ax: ("layers",) + ax, layer_axes,
-                                  is_leaf=lambda x: isinstance(x, tuple))
+        layer_axes = stacked(layer_axes)
     top["layers"] = layer_axes
     return top
 
@@ -206,8 +254,10 @@ def embed_tokens(params: Params, tokens: jax.Array, cfg: ArchConfig) -> jax.Arra
         x = functools.reduce(jnp.add, embs)
     else:
         x = jnp.take(params["embed"], tokens, axis=0)
-    if cfg.tie_embeddings:  # gemma-style embedding scaling
+    if cfg.embed_scale_sqrt_d:
         x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
     return x
 
 
@@ -219,7 +269,28 @@ def unembed(params: Params, x: jax.Array, cfg: ArchConfig) -> jax.Array:
         logits = jnp.einsum("...d,vd->...v", x, params["embed"])
     else:
         logits = jnp.einsum("...d,dv->...v", x, params["unembed"])
+    if cfg.logits_scaling != 1.0:
+        logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
     return softcap(logits, cfg.final_logit_softcap)
+
+
+def _residual(h: jax.Array, y: jax.Array, cfg: ArchConfig) -> jax.Array:
+    """h plus a block's output, scaled by the muP residual multiplier."""
+    if cfg.residual_multiplier != 1.0:
+        y = y * jnp.asarray(cfg.residual_multiplier, y.dtype)
+    return h + y
+
+
+def _mlp_residual(lp: Params, h: jax.Array, cfg: ArchConfig,
+                  live_rows=None) -> jax.Array:
+    """The MLP (or MoE) block after its RMSNorm, added to h."""
+    m_in = rms_norm(h, lp["mlp_norm"], cfg.norm_eps)
+    if cfg.moe is not None:
+        m_out, _ = moe_mod.moe_forward(lp["mlp"], m_in, cfg,
+                                       live_rows=live_rows)
+    else:
+        m_out = mlp_forward(lp["mlp"], m_in, cfg.mlp_act, cfg.mlp_gated)
+    return _residual(h, m_out, cfg)
 
 
 # ---------------------------------------------------------------------------
@@ -321,6 +392,43 @@ def forward(params: Params, tokens: jax.Array, cfg: ArchConfig, *,
                 cache = {"k": kvs[0], "v": kvs[1]}
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         return x, aux, cache
+
+    if kind == "hybrid":
+        d_in, Hm, P, N = mamba_mod.mamba2_dims(cfg)
+        cw = cfg.ssm.conv_width
+
+        def mamba_body(h, lp):
+            m_in = rms_norm(h, lp["norm"], cfg.norm_eps)
+            y, co, so = mamba_mod.mamba2_forward(
+                lp["mamba"], m_in, cfg,
+                conv_in=jnp.zeros((B, cw - 1, d_in + 2 * N), h.dtype),
+                state_in=jnp.zeros((B, Hm, P, N), jnp.float32))
+            h = constrain(_mlp_residual(lp, _residual(h, y, cfg), cfg))
+            return h, (co, so) if want_cache else None
+
+        def attn_body(h, lp):
+            a_in = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
+            a_out, kv = attn_mod.attn_forward(lp["attn"], a_in, cfg,
+                                              positions=positions, window=0,
+                                              attn_chunk=attn_chunk)
+            h = constrain(_mlp_residual(lp, _residual(h, a_out, cfg), cfg))
+            return h, kv if want_cache else None
+
+        bodies = {"mamba": mamba_body, "attention": attn_body}
+        states: Dict[str, list] = {"mamba": [], "attention": []}
+        for t, i0, n in layer_runs(cfg):
+            f = jax.checkpoint(bodies[t]) if remat else bodies[t]
+            x, ys = jax.lax.scan(f, x, jax.tree.map(
+                lambda a: a[i0:i0 + n], params["layers"][t]))
+            states[t].append(ys)
+        cache = None
+        if want_cache:
+            cat = lambda ys: jax.tree.map(lambda *a: jnp.concatenate(a),
+                                          *ys)
+            (k, v), (conv, ssm) = cat(states["attention"]), cat(states["mamba"])
+            cache = {"k": k, "v": v, "conv": conv, "ssm": ssm}
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        return x, jnp.zeros((), jnp.float32), cache
 
     if kind == "rwkv6":
         K = cfg.ssm.head_dim
@@ -497,6 +605,15 @@ def init_cache(cfg: ArchConfig, batch: int, max_len: int,
             out["k_scale"] = maker((L, B, S, KVH), jnp.bfloat16)
             out["v_scale"] = maker((L, B, S, KVH), jnp.bfloat16)
         return out
+    if kind == "hybrid":
+        d_in, Hm, P, N = mamba_mod.mamba2_dims(cfg)
+        La, Lm = len(cfg.layers_of("attention")), len(cfg.layers_of("mamba"))
+        KVH, Dh = cfg.num_kv_heads, cfg.resolved_head_dim
+        return {"k": maker((La, B, S, KVH, Dh), dtype),
+                "v": maker((La, B, S, KVH, Dh), dtype),
+                "conv": maker((Lm, B, cfg.ssm.conv_width - 1, d_in + 2 * N),
+                              dtype),
+                "ssm": maker((Lm, B, Hm, P, N), jnp.float32)}
     if kind == "rwkv6":
         K = cfg.ssm.head_dim
         H = cfg.d_model // K
@@ -534,6 +651,11 @@ def cache_axes(cfg: ArchConfig, kv_quant: bool = False) -> Params:
             out["k_scale"] = sc
             out["v_scale"] = sc
         return out
+    if kind == "hybrid":
+        kv = ("layers", "batch", "kv_seq", "kv_heads", None)
+        return {"k": kv, "v": kv,
+                "conv": ("layers", "batch", None, "heads_flat"),
+                "ssm": ("layers", "batch", "heads_flat", None, None)}
     if kind == "rwkv6":
         return {"shift1": ("layers", "batch", "embed"),
                 "wkv": ("layers", "batch", "heads_flat", None, None),
@@ -590,6 +712,9 @@ def serve_step(params: Params, cache: Params, inputs: Dict[str, jax.Array],
     """
     import jax.sharding as jsh
     kind = family_kind(cfg)
+    if kind == "hybrid":
+        raise ValueError(f"{cfg.name}: a layer_types hybrid decodes on the "
+                         "paged path only (serve_step_hybrid_paged)")
     tok = inputs["token"]
     pos = inputs["pos"]
     kv_spec5 = (jsh.PartitionSpec(None, None, None, None, seq_axis)
@@ -804,6 +929,86 @@ def serve_step(params: Params, cache: Params, inputs: Dict[str, jax.Array],
     return logits, cache
 
 
+def _attn_qkv(ap: Params, a_in: jax.Array, cfg: ArchConfig,
+              positions: jax.Array):
+    """A decode step's attention projections of ``a_in`` [B,1,d], roped
+    at ``positions`` (not at all for NoPE), the query scaled for
+    ``attention_multiplier``: (q [B,KVH,G,Dh], k, v [B,KVH,Dh])."""
+    B = a_in.shape[0]
+    H, KVH, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
+    q = jnp.einsum("bsd,dhk->bshk", a_in, ap["wq"])
+    k = jnp.einsum("bsd,dhk->bshk", a_in, ap["wk"])
+    v = jnp.einsum("bsd,dhk->bshk", a_in, ap["wv"])
+    q = apply_rope(q, positions, fraction=cfg.rope_fraction,
+                   theta=cfg.rope_theta)
+    k = apply_rope(k, positions, fraction=cfg.rope_fraction,
+                   theta=cfg.rope_theta)
+    q = attn_mod.scale_query(q, cfg)
+    return q[:, 0].reshape(B, KVH, H // KVH, Dh), k[:, 0], v[:, 0]
+
+
+def _attn_out(ap: Params, out: jax.Array, a_in: jax.Array) -> jax.Array:
+    """Attention output [B,KVH,G,Dh] projected back to [B,1,d]."""
+    B, KVH, G, Dh = out.shape
+    out = out.reshape(B, 1, KVH * G, Dh).astype(a_in.dtype)
+    return jnp.einsum("bshk,hkd->bsd", out, ap["wo"])
+
+
+def _paged_gqa_step(params: Params, k_slab: jax.Array, v_slab: jax.Array,
+                    block_table: jax.Array, lengths: jax.Array,
+                    inputs: Dict[str, jax.Array], cfg: ArchConfig, attend,
+                    ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The layer scan of ``serve_step_paged`` and its spliced form;
+    ``attend(q, k_pages, v_pages)`` is their attention."""
+    tok = inputs["token"]
+    x = embed_tokens(params, tok[:, None], cfg)
+    L = cfg.num_layers
+    ps = k_slab.shape[2]
+    positions = lengths[:, None]                       # new token's position
+    slot = jnp.take_along_axis(block_table,
+                               (lengths // ps)[:, None], axis=1)[:, 0]
+    off = lengths % ps
+
+    # same carry/in-place-update discipline as the dense serve_step: the
+    # slab rides the scan carry and each layer's page view is updated
+    # with dynamic_update_index_in_dim so XLA single-buffers it
+    def body(carry, xs):
+        h, ks, vs = carry
+        lp, li = xs
+        a_in = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
+        q, k, v = _attn_qkv(lp["attn"], a_in, cfg, positions)
+        kl = jax.lax.dynamic_index_in_dim(ks, li, keepdims=False)
+        vl = jax.lax.dynamic_index_in_dim(vs, li, keepdims=False)
+        kl = kl.at[slot, off].set(k.astype(kl.dtype))
+        vl = vl.at[slot, off].set(v.astype(vl.dtype))
+        ks = jax.lax.dynamic_update_index_in_dim(ks, kl, li, 0)
+        vs = jax.lax.dynamic_update_index_in_dim(vs, vl, li, 0)
+        a_out = _attn_out(lp["attn"], attend(q, kl, vl), a_in)
+        h = _mlp_residual(lp, _residual(h, a_out, cfg), cfg,
+                          live_rows=inputs.get("live_rows"))
+        return (h, ks, vs), None
+
+    (x, k_slab, v_slab), _ = jax.lax.scan(
+        body, (x, k_slab, v_slab),
+        (params["layers"], jnp.arange(L, dtype=jnp.int32)))
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    logits = unembed(params, x[:, 0, :], cfg)
+    return logits, k_slab, v_slab
+
+
+def _require_paged_gqa(cfg: ArchConfig, entry: str) -> None:
+    if family_kind(cfg) == "hybrid":
+        raise ValueError(
+            f"{entry}: {cfg.name} carries recurrent (Mamba-2) state, which "
+            "a block table cannot hold or splice; its paged step is "
+            "serve_step_hybrid_paged")
+    if (family_kind(cfg) != "attn" or cfg.attn_kind != "gqa"
+            or cfg.local_global_pattern or cfg.sliding_window):
+        raise ValueError(
+            f"{entry} supports plain global-causal GQA archs only "
+            f"(family {family_kind(cfg)!r}, attn_kind {cfg.attn_kind!r})")
+
+
 def serve_step_paged(params: Params, k_slab: jax.Array, v_slab: jax.Array,
                      block_table: jax.Array, lengths: jax.Array,
                      inputs: Dict[str, jax.Array], cfg: ArchConfig, *,
@@ -825,69 +1030,19 @@ def serve_step_paged(params: Params, k_slab: jax.Array, v_slab: jax.Array,
 
     Returns (logits [B, V], k_slab, v_slab).  Plain global-causal GQA
     attention archs only (the same restriction as
-    ``KVCacheManager.init_paged``); sliding-window / split-cache / MLA /
-    SSM families stay on the dense ``serve_step``.
+    ``KVCacheManager.init_paged``); a ``layer_types`` hybrid decodes
+    through ``serve_step_hybrid_paged``; sliding-window / split-cache /
+    MLA / other SSM families stay on the dense ``serve_step``.
     """
     from repro.kernels import ops as kernel_ops
 
-    if (family_kind(cfg) != "attn" or cfg.attn_kind != "gqa"
-            or cfg.local_global_pattern or cfg.sliding_window):
-        raise ValueError(
-            "serve_step_paged supports plain global-causal GQA archs only "
-            f"(family {family_kind(cfg)!r}, attn_kind {cfg.attn_kind!r})")
+    _require_paged_gqa(cfg, "serve_step_paged")
     mode = kernel_ops.DEFAULT_MODE if kernel_mode is None else kernel_mode
-
-    tok = inputs["token"]
-    x = embed_tokens(params, tok[:, None], cfg)
-    B = x.shape[0]
-    L = cfg.num_layers
-    H, KVH, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
-    ps = k_slab.shape[2]
-    positions = lengths[:, None]                       # new token's position
-    slot = jnp.take_along_axis(block_table,
-                               (lengths // ps)[:, None], axis=1)[:, 0]
-    off = lengths % ps
-
-    # same carry/in-place-update discipline as the dense serve_step: the
-    # slab rides the scan carry and each layer's page view is updated
-    # with dynamic_update_index_in_dim so XLA single-buffers it
-    def body(carry, xs):
-        h, ks, vs = carry
-        lp, li = xs
-        ap = lp["attn"]
-        a_in = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
-        q = jnp.einsum("bsd,dhk->bshk", a_in, ap["wq"])
-        k = jnp.einsum("bsd,dhk->bshk", a_in, ap["wk"])
-        v = jnp.einsum("bsd,dhk->bshk", a_in, ap["wv"])
-        q = apply_rope(q, positions, fraction=cfg.rope_fraction,
-                       theta=cfg.rope_theta)
-        k = apply_rope(k, positions, fraction=cfg.rope_fraction,
-                       theta=cfg.rope_theta)
-        kl = jax.lax.dynamic_index_in_dim(ks, li, keepdims=False)
-        vl = jax.lax.dynamic_index_in_dim(vs, li, keepdims=False)
-        kl = kl.at[slot, off].set(k[:, 0].astype(kl.dtype))
-        vl = vl.at[slot, off].set(v[:, 0].astype(vl.dtype))
-        ks = jax.lax.dynamic_update_index_in_dim(ks, kl, li, 0)
-        vs = jax.lax.dynamic_update_index_in_dim(vs, vl, li, 0)
-        out = kernel_ops.flash_decode_paged(
-            q[:, 0].reshape(B, KVH, H // KVH, Dh), kl, vl,
-            block_table, lengths + 1, mode=mode)
-        out = out.reshape(B, 1, H, Dh).astype(h.dtype)
-        h = h + jnp.einsum("bshk,hkd->bsd", out, ap["wo"])
-        m_in = rms_norm(h, lp["mlp_norm"], cfg.norm_eps)
-        if cfg.moe is not None:
-            m_out, _ = moe_mod.moe_forward(lp["mlp"], m_in, cfg,
-                                           live_rows=inputs.get("live_rows"))
-        else:
-            m_out = mlp_forward(lp["mlp"], m_in, cfg.mlp_act, cfg.mlp_gated)
-        return (h + m_out, ks, vs), None
-
-    (x, k_slab, v_slab), _ = jax.lax.scan(
-        body, (x, k_slab, v_slab),
-        (params["layers"], jnp.arange(L, dtype=jnp.int32)))
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = unembed(params, x[:, 0, :], cfg)
-    return logits, k_slab, v_slab
+    lens1 = lengths + 1
+    return _paged_gqa_step(
+        params, k_slab, v_slab, block_table, lengths, inputs, cfg,
+        lambda q, kl, vl: kernel_ops.flash_decode_paged(
+            q, kl, vl, block_table, lens1, mode=mode))
 
 
 def serve_step_paged_spliced(params: Params, k_slab: jax.Array,
@@ -908,63 +1063,99 @@ def serve_step_paged_spliced(params: Params, k_slab: jax.Array,
     carry ``delta = 0`` and ``valid = ps`` — with an all-fresh table this
     is numerically ``serve_step_paged``.  The new token is roped and
     scattered at layout position ``lengths``, and ``inputs`` read, exactly
-    as in the unspliced form.  Same plain global-causal GQA restriction.  Attention runs
-    through the jnp oracle ``ops.flash_decode_spliced`` (no kernel yet).
+    as in the unspliced form.  Same plain global-causal GQA restriction:
+    a model with recurrent state is refused, since a spliced chunk
+    brings K/V pages but no state.  Attention runs through the jnp
+    oracle ``ops.flash_decode_spliced`` (no kernel yet).
     """
     from repro.kernels import ops as kernel_ops
 
-    if (family_kind(cfg) != "attn" or cfg.attn_kind != "gqa"
-            or cfg.local_global_pattern or cfg.sliding_window):
-        raise ValueError(
-            "serve_step_paged_spliced supports plain global-causal GQA archs "
-            f"only (family {family_kind(cfg)!r}, attn_kind {cfg.attn_kind!r})")
+    _require_paged_gqa(cfg, "serve_step_paged_spliced")
+    lens1 = lengths + 1
+    return _paged_gqa_step(
+        params, k_slab, v_slab, block_table, lengths, inputs, cfg,
+        lambda q, kl, vl: kernel_ops.flash_decode_spliced(
+            q, kl, vl, block_table, lens1, page_delta, page_valid,
+            rope_fraction=cfg.rope_fraction, rope_theta=cfg.rope_theta))
 
+
+def serve_step_hybrid_paged(params: Params, k_slab: jax.Array,
+                            v_slab: jax.Array, conv_slab: jax.Array,
+                            ssm_slab: jax.Array, block_table: jax.Array,
+                            lengths: jax.Array, state_slots: jax.Array,
+                            inputs: Dict[str, jax.Array], cfg: ArchConfig,
+                            *, kernel_mode: Optional[str] = None,
+                            ) -> Tuple[jax.Array, ...]:
+    """One decode step of a ``layer_types`` hybrid (Mamba-2 mixers and
+    GQA layers, each followed by the MLP) over the paged KV of its
+    attention layers and the state slabs of its Mamba-2 layers.
+
+    k_slab/v_slab [La, NP, ps, KVH*Dh]: the attention layers' pages,
+    written and attended through ``block_table``/``lengths`` as in
+    ``serve_step_paged``.  The slabs are carried as rows of one token
+    (written by a one-index row scatter) and read as one page axis of
+    La * NP pages, layer j's table offset by j * NP, so
+    ``flash_decode_paged`` attends the slab in place.  conv_slab
+    [Lm*S, (cw-1)*C] and ssm_slab [Lm, S, H, P, N] f32
+    (``kv_cache.paged_slab_shapes``): row b's recurrent state is slot
+    ``state_slots[b]`` (``mamba2_step_paged``; the SSM update
+    ``ssm_decode_update`` works on the slab in place).
+    Each run of same-kind layers is one scan over layer indices that
+    carries the whole slabs, so no layer's slice is copied in or out.
+
+    Returns (logits [B, V], k_slab, v_slab, conv_slab, ssm_slab)."""
+    from repro.kernels import ops as kernel_ops
+
+    if family_kind(cfg) != "hybrid" or cfg.attn_kind != "gqa":
+        raise ValueError("serve_step_hybrid_paged takes a layer_types "
+                         f"hybrid with GQA layers, not {cfg.name!r}")
+    mode = kernel_ops.DEFAULT_MODE if kernel_mode is None else kernel_mode
     tok = inputs["token"]
     x = embed_tokens(params, tok[:, None], cfg)
-    B = x.shape[0]
-    L = cfg.num_layers
-    H, KVH, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
-    ps = k_slab.shape[2]
-    positions = lengths[:, None]                       # new token's position
-    slot = jnp.take_along_axis(block_table,
-                               (lengths // ps)[:, None], axis=1)[:, 0]
-    off = lengths % ps
+    kv_shape = k_slab.shape
+    La, NP, ps = kv_shape[:3]
+    positions = lengths[:, None]
+    # the new token's K/V row in layer 0's pages; layer j's is j*NP*ps on
+    row = jnp.take_along_axis(block_table, (lengths // ps)[:, None],
+                              axis=1)[:, 0] * ps + lengths % ps
+    lens1 = lengths + 1
+    KVH, Dh = cfg.num_kv_heads, cfg.resolved_head_dim
+    pages = lambda rows: rows.reshape(La * NP, ps, KVH, Dh)
+    at = lambda stack, j: jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, j, keepdims=False), stack)
 
-    def body(carry, xs):
-        h, ks, vs = carry
-        lp, li = xs
-        ap = lp["attn"]
+    def attention_layer(carry, j):
+        h, kr, vr, conv, ssm = carry
+        lp = at(params["layers"]["attention"], j)
         a_in = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
-        q = jnp.einsum("bsd,dhk->bshk", a_in, ap["wq"])
-        k = jnp.einsum("bsd,dhk->bshk", a_in, ap["wk"])
-        v = jnp.einsum("bsd,dhk->bshk", a_in, ap["wv"])
-        q = apply_rope(q, positions, fraction=cfg.rope_fraction,
-                       theta=cfg.rope_theta)
-        k = apply_rope(k, positions, fraction=cfg.rope_fraction,
-                       theta=cfg.rope_theta)
-        kl = jax.lax.dynamic_index_in_dim(ks, li, keepdims=False)
-        vl = jax.lax.dynamic_index_in_dim(vs, li, keepdims=False)
-        kl = kl.at[slot, off].set(k[:, 0].astype(kl.dtype))
-        vl = vl.at[slot, off].set(v[:, 0].astype(vl.dtype))
-        ks = jax.lax.dynamic_update_index_in_dim(ks, kl, li, 0)
-        vs = jax.lax.dynamic_update_index_in_dim(vs, vl, li, 0)
-        out = kernel_ops.flash_decode_spliced(
-            q[:, 0].reshape(B, KVH, H // KVH, Dh), kl, vl,
-            block_table, lengths + 1, page_delta, page_valid,
-            rope_fraction=cfg.rope_fraction, rope_theta=cfg.rope_theta)
-        out = out.reshape(B, 1, H, Dh).astype(h.dtype)
-        h = h + jnp.einsum("bshk,hkd->bsd", out, ap["wo"])
-        m_in = rms_norm(h, lp["mlp_norm"], cfg.norm_eps)
-        if cfg.moe is not None:
-            m_out, _ = moe_mod.moe_forward(lp["mlp"], m_in, cfg,
-                                           live_rows=inputs.get("live_rows"))
-        else:
-            m_out = mlp_forward(lp["mlp"], m_in, cfg.mlp_act, cfg.mlp_gated)
-        return (h + m_out, ks, vs), None
+        q, k, v = _attn_qkv(lp["attn"], a_in, cfg, positions)
+        r = row + j * NP * ps
+        kr = kr.at[r].set(k.reshape(k.shape[0], -1).astype(kr.dtype))
+        vr = vr.at[r].set(v.reshape(v.shape[0], -1).astype(vr.dtype))
+        out = kernel_ops.flash_decode_paged(q, pages(kr), pages(vr),
+                                            block_table + j * NP, lens1,
+                                            mode=mode)
+        h = _residual(h, _attn_out(lp["attn"], out, a_in), cfg)
+        return (_mlp_residual(lp, h, cfg), kr, vr, conv, ssm), None
 
-    (x, k_slab, v_slab), _ = jax.lax.scan(
-        body, (x, k_slab, v_slab),
-        (params["layers"], jnp.arange(L, dtype=jnp.int32)))
+    def mamba_layer(carry, j):
+        h, kr, vr, conv, ssm = carry
+        lp = at(params["layers"]["mamba"], j)
+        m_in = rms_norm(h[:, 0], lp["norm"], cfg.norm_eps)
+        y, conv, ssm = mamba_mod.mamba2_step_paged(
+            lp["mamba"], m_in, cfg, conv_rows=conv, ssm_slab=ssm, layer=j,
+            slots=state_slots, kernel_mode=mode)
+        h = _residual(h, y[:, None], cfg)
+        return (_mlp_residual(lp, h, cfg), kr, vr, conv, ssm), None
+
+    bodies = {"mamba": mamba_layer, "attention": attention_layer}
+    carry = (x, k_slab.reshape(-1, kv_shape[3]),
+             v_slab.reshape(-1, kv_shape[3]), conv_slab, ssm_slab)
+    for t, i0, n in layer_runs(cfg):
+        carry, _ = jax.lax.scan(bodies[t], carry,
+                                jnp.arange(i0, i0 + n, dtype=jnp.int32))
+    x, k_rows, v_rows, conv_slab, ssm_slab = carry
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = unembed(params, x[:, 0, :], cfg)
-    return logits, k_slab, v_slab
+    return (logits, k_rows.reshape(kv_shape), v_rows.reshape(kv_shape),
+            conv_slab, ssm_slab)

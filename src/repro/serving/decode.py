@@ -33,6 +33,13 @@ each step's host work (``telerag.decode.dispatch``) and the token
 read-back (``telerag.decode.readback``) are host-clock spans.  The
 read-back is one batched transfer of the wave's ``[steps, rows]``
 tokens after the last step (``stats["readback_syncs"]`` counts them).
+
+A ``layer_types`` hybrid (Mamba-2 mixers beside GQA layers) decodes on
+the paged path only, through ``transformer.serve_step_hybrid_paged``:
+its lease also holds one recurrent state row per sequence, zeroed at
+acquire (``stats["state_rows_reset"]`` counts them), and padding rows
+update a scratch state row outside the free list.  It has no chunk-KV
+splice: a spliced chunk brings pages, not the recurrent state after it.
 """
 
 from __future__ import annotations
@@ -55,8 +62,12 @@ from repro.serving.sampler import sample
 def supports_paged_decode(cfg: ArchConfig) -> bool:
     """True iff the arch can decode through block-table KV: plain
     global-causal GQA attention (the ``init_paged`` /
-    ``serve_step_paged`` restriction) — sliding-window, split-cache,
-    MLA and SSM families stay dense."""
+    ``serve_step_paged`` restriction), or a ``layer_types`` hybrid of
+    Mamba-2 and such GQA layers (``serve_step_hybrid_paged``) —
+    sliding-window, split-cache, MLA and other SSM families stay
+    dense."""
+    if tf.family_kind(cfg) == "hybrid":
+        return cfg.attn_kind == "gqa"
     return (tf.family_kind(cfg) == "attn" and cfg.has_attention
             and cfg.attn_kind == "gqa" and not cfg.local_global_pattern
             and not cfg.sliding_window)
@@ -105,6 +116,7 @@ class DecodeRunner:
         self._spliced_step = None
         self._rows = 0                         # attach(): server micro_batch
         self._pad_slot: Dict[int, int] = {}    # replica -> scratch KV page
+        self._pad_state: Dict[int, int] = {}   # replica -> scratch state row
         # per-request generated tokens, per round: the differential
         # parity suite pins these exactly equal across paged/dense runs
         self.generated: Dict[int, List[Tuple[int, ...]]] = {}
@@ -113,7 +125,8 @@ class DecodeRunner:
         self.wave_step_seconds: List[float] = []
         self.stats = {"paged_waves": 0, "dense_waves": 0,
                       "paged_appends": 0, "dense_steps": 0,
-                      "spliced_waves": 0, "readback_syncs": 0}
+                      "spliced_waves": 0, "readback_syncs": 0,
+                      "state_rows_reset": 0}
 
     # -- wiring --------------------------------------------------------------
     def attach(self, server) -> "DecodeRunner":
@@ -130,19 +143,30 @@ class DecodeRunner:
         want = (eng0.cfg.paged_decode if self._paged_override is None
                 else self._paged_override)
         self.paged = bool(want) and supports_paged_decode(self.cfg)
+        recurrent = tf.family_kind(self.cfg) == "hybrid"
+        if recurrent and not self.paged:
+            raise ValueError(f"{self.cfg.name}: a hybrid with recurrent "
+                             "state decodes on the paged path only")
         self._kernel_mode = eng0.cfg.kernel_mode
         want_chunk = (self.paged and eng0.cfg.chunk_kv
                       and self.chunk_store is not None)
+        if want_chunk and recurrent:
+            raise ValueError(
+                f"{self.cfg.name}: chunk-KV splicing needs the recurrent "
+                "state after each chunk, which a chunk store does not hold")
         self.chunk_docs = eng0.cfg.chunk_kv_docs
         for r, eng in enumerate(server.engines):
             kv = KVCacheManager(self.cfg, pool=eng.pool)
             self._params[r] = jax.device_put(self.params, eng.device)
             if self.paged:
                 kv.init_paged(num_pages=self.slab_pages,
-                              page_size=self.page_size)
+                              page_size=self.page_size,
+                              state_rows=self.slab_seqs + 1)
                 # one page outside the free list: padding rows write and
                 # read only there, so they never touch a leased page
                 self._pad_slot[r] = kv.slab.free.pop(0)
+                if recurrent:           # and one state row, likewise
+                    self._pad_state[r] = kv.slab.state_free.pop(0)
             self._kv[r] = kv
             if want_chunk:
                 cache = ChunkKVCache(kv, self.chunk_store)
@@ -150,7 +174,16 @@ class DecodeRunner:
                 # the engine's spill chain and the policy's lookahead
                 # prefetch reach chunk residency through this attr
                 eng.chunk_kv = cache
-        if self.paged:
+        if self.paged and recurrent:
+            cfg, mode = self.cfg, self._kernel_mode
+            self._paged_step = jax.jit(
+                lambda p, k, v, conv, ssm, bt, lens, slots, tok, live:
+                    tf.serve_step_hybrid_paged(
+                        p, k, v, conv, ssm, bt, lens, slots,
+                        {"token": tok, "live_rows": live}, cfg,
+                        kernel_mode=mode),
+                donate_argnums=(1, 2, 3, 4))
+        elif self.paged:
             cfg, mode = self.cfg, self._kernel_mode
             self._paged_step = jax.jit(
                 lambda p, k, v, bt, lens, tok, live: tf.serve_step_paged(
@@ -173,7 +206,8 @@ class DecodeRunner:
     @property
     def slab_pages(self) -> int:
         """KV slab page slots: ``slab_seqs`` sequences of ``max_len``
-        plus the padding rows' scratch page."""
+        plus the padding rows' scratch page (a hybrid's state slab holds
+        ``slab_seqs`` rows plus a scratch row likewise)."""
         return self.slab_seqs * -(-self.max_len // self.page_size) + 1
 
     def kv(self, replica: int = 0) -> KVCacheManager:
@@ -238,10 +272,13 @@ class DecodeRunner:
                 for r, g in zip(records, gen_tokens)]
 
     def _padded_tables(self, replica: int, tables, n: int, rows: int):
-        """The lease's (block_table, lengths[, page_delta, page_valid])
-        grown from ``n`` to ``rows`` rows: padding rows hold one token on
-        the replica's scratch page (lengths 0, delta 0, valid = ps)."""
-        fill = (self._pad_slot[replica], 0, 0, self.page_size)
+        """The lease's (block_table, lengths[, page_delta, page_valid] or
+        [, state_slots]) grown from ``n`` to ``rows`` rows: padding rows
+        hold one token on the replica's scratch page (lengths 0, delta
+        0, valid = ps) and their state in its scratch state row."""
+        fill = (self._pad_slot[replica], 0) + (
+            (self._pad_state[replica],) if replica in self._pad_state
+            else (0, self.page_size))
         return tuple(np.concatenate([t, np.full((rows - n,) + t.shape[1:],
                                                 f, t.dtype)])
                      for t, f in zip(tables, fill))
@@ -262,6 +299,9 @@ class DecodeRunner:
         the same ``finally`` that frees the lease."""
         self.stats["paged_waves"] += 1
         lease = kv.acquire_paged(n, self.max_len, tenant=tenant)
+        state = lease.state_slots is not None
+        if state:
+            self.stats["state_rows_reset"] += len(lease.state_slots)
         pinned: List[int] = []
         toks: List[jax.Array] = []
         try:
@@ -276,7 +316,8 @@ class DecodeRunner:
             # tokens do not depend on the micro-batch it is padded to
             live = jax.device_put(np.int32(n), kv.device)
             span = self.recorder.span
-            with span("telerag.decode.steps", rows=rows, steps=steps):
+            with span("telerag.decode.steps", rows=rows, steps=steps,
+                      state_slots=len(lease.state_slots) if state else 0):
                 t0 = self.clock.perf()
                 for i in range(steps):
                     with span("telerag.decode.dispatch", step=i):
@@ -286,11 +327,17 @@ class DecodeRunner:
                                                          n, rows)
                         tables = [jax.device_put(t, kv.device)
                                   for t in tables]
-                        step = (self._spliced_step if lease.spliced_pages
-                                else self._paged_step)
-                        logits, kv.slab.k, kv.slab.v = step(
-                            params, kv.slab.k, kv.slab.v, *tables, tok,
-                            live)
+                        slab = kv.slab
+                        if state:
+                            (logits, slab.k, slab.v, slab.conv,
+                             slab.ssm) = self._paged_step(
+                                params, slab.k, slab.v, slab.conv, slab.ssm,
+                                *tables, tok, live)
+                        else:
+                            step = (self._spliced_step if lease.spliced_pages
+                                    else self._paged_step)
+                            logits, slab.k, slab.v = step(
+                                params, slab.k, slab.v, *tables, tok, live)
                         kv.append_paged(lease)  # scatter was fused in-jit
                         self.stats["paged_appends"] += 1
                         tok = sample(logits)

@@ -29,6 +29,14 @@ page slots plus per-sequence lengths — exactly the operands
 (PagedAttention-style), so decode attention reads leased pages with no
 contiguous copy and no [B, max_len] over-allocation.  The same pool
 byte accounting applies per lease.
+
+A ``layer_types`` hybrid (Mamba-2 mixers beside GQA layers) pages the
+K/V of its attention layers only, and its paged lease also holds one
+**recurrent state row** per sequence: a slot of the manager's state
+slabs (conv state in the model dtype, SSM state in f32, all Mamba-2
+layers stacked), zeroed at acquire and freed with the pages.  Pages and
+state rows are charged as one pool lease; running out of either raises
+``PoolExhausted``.
 """
 
 from __future__ import annotations
@@ -45,7 +53,7 @@ import numpy as np
 from repro.configs.base import ArchConfig
 from repro.memory.pool import DevicePagePool, PageLease, PoolExhausted
 from repro.models import transformer as tf
-from repro.obs.recorder import KVEvent
+from repro.obs.recorder import NULL_SPAN, KVEvent
 
 
 @dataclass
@@ -81,6 +89,8 @@ class KVCacheManager:
         self._pool_buckets: Dict[Tuple[int, int], Tuple[dict, Optional[PageLease]]] = {}
         self._nbytes_memo: Dict[Tuple[int, int], int] = {}
         self.slab: Optional["KVPageSlab"] = None   # init_paged() creates it
+        # recurrent state rows held by paged leases (hybrid archs)
+        self.stats = {"state_rows_in_use": 0, "state_rows_peak": 0}
 
     def _record(self, kind: str, batch: int, max_len: int, nbytes: int,
                 tenant: str, *, lease_id: int = -1, pages: int = 0,
@@ -214,42 +224,48 @@ class KVCacheManager:
 
     # -- paged KV (block-table leases over a shared KV page slab) ----------
 
-    def init_paged(self, num_pages: int, page_size: int = 16) -> "KVPageSlab":
-        """Allocate the manager's KV page slab: ``num_pages`` page slots
-        of ``page_size`` tokens each, all layers stacked —
-        k/v [L, num_pages, page_size, KVH, Dh].  GQA attention archs
-        only (SSM state is O(1) per request; nothing to page)."""
-        if (tf.family_kind(self.cfg) != "attn" or not self.cfg.has_attention
-                or self.cfg.attn_kind != "gqa"):
-            raise ValueError(
-                "paged KV supports plain GQA attention caches only "
-                f"(arch family {tf.family_kind(self.cfg)!r}, "
-                f"attn_kind {self.cfg.attn_kind!r})")
-        L = self.cfg.num_layers
-        KVH, Dh = self.cfg.num_kv_heads, self.cfg.resolved_head_dim
-        shape = (L, num_pages, page_size, KVH, Dh)
-        self.slab = KVPageSlab(
-            k=jnp.zeros(shape, self.dtype, device=self.device),
-            v=jnp.zeros(shape, self.dtype, device=self.device),
-            page_size=page_size, free=list(range(num_pages)))
+    def init_paged(self, num_pages: int, page_size: int = 16,
+                   state_rows: int = 0) -> "KVPageSlab":
+        """Allocate the manager's KV page slab (``paged_slab_shapes``):
+        ``num_pages`` page slots of ``page_size`` tokens each, all
+        attention layers stacked.  A ``layer_types`` hybrid also gets
+        ``state_rows`` recurrent state rows (other archs take none)."""
+        shapes = paged_slab_shapes(self.cfg, num_pages, page_size,
+                                   state_rows, self.dtype)
+        zeros = {k: jnp.zeros(a.shape, a.dtype, device=self.device)
+                 for k, a in shapes.items()}
+        self.slab = KVPageSlab(page_size=page_size,
+                               free=list(range(num_pages)), **zeros)
+        if "ssm" in zeros:
+            self.slab.state_free = list(range(state_rows))
         return self.slab
 
     def paged_page_nbytes(self) -> int:
         """Exact bytes of one KV page slot (k+v, all layers)."""
         slab = self._require_slab()
-        per = slab.k.dtype.itemsize
-        L, _, ps, KVH, Dh = slab.k.shape
-        return 2 * L * ps * KVH * Dh * per
+        return 2 * slab.k.size // slab.k.shape[1] * slab.k.dtype.itemsize
+
+    def state_row_nbytes(self) -> int:
+        """Exact bytes of one recurrent state row (conv + SSM state, all
+        Mamba-2 layers); 0 for an arch with none."""
+        slab = self._require_slab()
+        if slab.ssm is None:
+            return 0
+        rows = slab.ssm.shape[1]
+        return (slab.conv.size * slab.conv.dtype.itemsize
+                + slab.ssm.size * slab.ssm.dtype.itemsize) // rows
 
     def acquire_paged(self, batch: int, max_len: int, *,
                       tenant: str = "shared") -> "PagedCacheLease":
         """Lease a block-table decode cache: ceil(max_len/page_size)
         slab pages per sequence, handed back as a [batch, max_blocks]
         block table the paged kernels gather through — no contiguous
-        [B, S] cache is ever materialized.  Bytes are charged to the
-        pool ledger (category ``"kv"``, tenant-tagged) exactly like the
-        dense buckets; raises ``PoolExhausted`` when either the slab's
-        free list or the pool cannot cover it."""
+        [B, S] cache is ever materialized — plus, for an arch with
+        recurrent state, one zeroed state row per sequence
+        (``state_slots``).  Bytes are charged to the pool ledger
+        (category ``"kv"``, tenant-tagged) exactly like the dense
+        buckets; raises ``PoolExhausted`` when the slab's free pages or
+        state rows, or the pool, cannot cover it."""
         slab = self._require_slab()
         ps = slab.page_size
         max_blocks = -(-max_len // ps)
@@ -258,7 +274,13 @@ class KVCacheManager:
             raise PoolExhausted(
                 f"kv page slab exhausted: need {need} pages for "
                 f"({batch}, {max_len}), {len(slab.free)} free")
-        nbytes = need * self.paged_page_nbytes()
+        recurrent = slab.ssm is not None
+        if recurrent and len(slab.state_free) < batch:
+            raise PoolExhausted(
+                f"recurrent state slab exhausted: need {batch} rows, "
+                f"{len(slab.state_free)} free")
+        nbytes = (need * self.paged_page_nbytes()
+                  + batch * self.state_row_nbytes())
         page_lease = None
         if self.pool is not None:
             page_lease = self.pool.lease_bytes(nbytes, "kv",
@@ -277,6 +299,11 @@ class KVCacheManager:
                     bytes_needed=nbytes)
         slots = [slab.free.pop() for _ in range(need)]
         bt = np.asarray(slots, np.int32).reshape(batch, max_blocks)
+        rows = None
+        if recurrent:
+            rows = np.asarray([slab.state_free.pop() for _ in range(batch)],
+                              np.int32)
+            self._reset_state(rows)
         lease_id = next(_LEASE_IDS)
         self._record("kv.acquire", batch, max_len, nbytes, tenant,
                      lease_id=lease_id, pages=need)
@@ -284,7 +311,31 @@ class KVCacheManager:
                                lengths=np.zeros(batch, np.int32),
                                batch=batch, max_len=max_len, nbytes=nbytes,
                                page_lease=page_lease, tenant=tenant,
-                               lease_id=lease_id, owned_slots=tuple(slots))
+                               lease_id=lease_id, owned_slots=tuple(slots),
+                               state_slots=rows)
+
+    def _reset_state(self, rows: np.ndarray) -> None:
+        """Zero the state rows a lease takes (a new sequence starts from
+        zero state), in place; the row count is padded to a power of two
+        by repeating a row, so few shapes compile."""
+        slab = self._require_slab()
+        rec = self.pool.recorder if self.pool is not None else None
+        span = NULL_SPAN if rec is None else rec.span(
+            "telerag.kv.state_reset", rows=len(rows),
+            bytes=len(rows) * self.state_row_nbytes())
+        with span:
+            n = 1 << (len(rows) - 1).bit_length()
+            idx = np.concatenate([rows, np.full(n - len(rows), rows[0],
+                                                np.int32)])
+            Lm, S = slab.ssm.shape[:2]
+            conv_idx = (np.arange(Lm, dtype=np.int32)[:, None] * S
+                        + idx[None, :]).ravel()
+            slab.conv, slab.ssm = _zero_rows(
+                slab.conv, slab.ssm, jax.device_put(conv_idx, self.device),
+                jax.device_put(idx, self.device))
+        self.stats["state_rows_in_use"] += len(rows)
+        self.stats["state_rows_peak"] = max(self.stats["state_rows_peak"],
+                                            self.stats["state_rows_in_use"])
 
     def append_paged(self, lease: "PagedCacheLease",
                      k_new: Optional[jax.Array] = None,
@@ -358,6 +409,10 @@ class KVCacheManager:
         if int(lease.lengths.max(initial=0)) > 0:
             raise ValueError("splice_paged must run on a fresh lease "
                              "(before any append)")
+        if lease.state_slots is not None:
+            raise ValueError("splice_paged cannot splice into a lease with "
+                             "recurrent state: a chunk brings K/V pages, "
+                             "not the state after it")
         n_blocks = [sum(len(slots) for slots, _ in row) for row in row_chunks]
         total = sum(n_blocks)
         if total == 0:
@@ -409,6 +464,10 @@ class KVCacheManager:
         slab.free.extend(int(s) for s in lease.owned_slots)
         pages = len(lease.owned_slots)
         lease.owned_slots = ()
+        if lease.state_slots is not None:
+            slab.state_free.extend(int(s) for s in lease.state_slots)
+            self.stats["state_rows_in_use"] -= len(lease.state_slots)
+            lease.state_slots = lease.state_slots[:0]
         lease.block_table = np.full_like(lease.block_table, -1)
         self._record("kv.release", lease.batch, lease.max_len,
                      lease.nbytes, lease.tenant, lease_id=lease.lease_id,
@@ -441,17 +500,65 @@ def _append_token(k_slab, v_slab, k_new, v_new, slots, offs):
     return k_slab, v_slab
 
 
+def paged_slab_shapes(cfg: ArchConfig, num_pages: int, page_size: int,
+                      state_rows: int = 0, dtype=jnp.bfloat16,
+                      ) -> Dict[str, jax.ShapeDtypeStruct]:
+    """The paged slab's arrays.  GQA archs: k/v [L, num_pages,
+    page_size, KVH, Dh].  A ``layer_types`` hybrid: k/v [La, num_pages,
+    page_size, KVH*Dh] over its La attention layers, and ``state_rows``
+    recurrent state rows of its Lm Mamba-2 layers: conv [Lm*rows,
+    (cw-1)*C] (row l*rows + r is layer l's row r) and ssm [Lm, rows, H,
+    P, N] f32.  The hybrid's K/V and conv rows are laid out as rows of
+    one token or one window, so the decode step's row scatters and the
+    kernels read them in place."""
+    kind = tf.family_kind(cfg)
+    if kind not in ("attn", "hybrid") or not cfg.has_attention:
+        raise ValueError(
+            "paged KV supports plain GQA attention caches and layer_types "
+            f"hybrids only (arch family {kind!r}, attn_kind "
+            f"{cfg.attn_kind!r})")
+    if cfg.attn_kind != "gqa":
+        raise ValueError(f"paged KV needs GQA attention, not "
+                         f"{cfg.attn_kind!r}")
+    KVH, Dh = cfg.num_kv_heads, cfg.resolved_head_dim
+    S = jax.ShapeDtypeStruct
+    if kind == "attn":
+        kv = S((cfg.num_layers, num_pages, page_size, KVH, Dh), dtype)
+        return {"k": kv, "v": kv}
+    if state_rows < 1:
+        raise ValueError(f"{cfg.name} carries recurrent state: its paged "
+                         "slab needs state_rows >= 1")
+    kv = S((len(cfg.layers_of("attention")), num_pages, page_size,
+            KVH * Dh), dtype)
+    dense = jax.eval_shape(lambda: tf.init_cache(cfg, state_rows, 1, dtype))
+    Lm, _, W, C = dense["conv"].shape
+    return {"k": kv, "v": kv, "conv": S((Lm * state_rows, W * C), dtype),
+            "ssm": dense["ssm"]}
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _zero_rows(conv, ssm, conv_rows, rows):
+    """Zero state rows, in place (donated): ``conv_rows`` of the conv
+    rows, ``rows`` of every layer of the SSM state."""
+    return conv.at[conv_rows].set(0), ssm.at[:, rows].set(0)
+
+
 @dataclass
 class KVPageSlab:
     """The manager-owned paged KV arrays (all layers stacked) plus the
     host-side free list of page slots.  ``k[l]`` / ``v[l]`` are exactly
     the ``[NP, page_size, KVH, Dh]`` operands ``flash_decode_paged``
-    reads in place."""
+    reads in place.  A hybrid's slab also holds the recurrent state
+    slabs ``conv`` and ``ssm`` (``paged_slab_shapes``) with their free
+    list of rows (None and empty otherwise)."""
 
     k: jax.Array
     v: jax.Array
     page_size: int
     free: List[int] = field(default_factory=list)
+    conv: Optional[jax.Array] = None
+    ssm: Optional[jax.Array] = None
+    state_free: List[int] = field(default_factory=list)
 
     @property
     def num_pages(self) -> int:
@@ -488,13 +595,18 @@ class PagedCacheLease:
     page_delta: Optional[np.ndarray] = None
     page_valid: Optional[np.ndarray] = None
     spliced_pages: int = 0
+    # recurrent state row of each sequence (hybrid archs; None otherwise)
+    state_slots: Optional[np.ndarray] = None
 
     def tables(self) -> Tuple[np.ndarray, ...]:
         """The decode step's host-side table operands: (block_table,
         lengths) for ``serve_step_paged``, plus (page_delta, page_valid)
         for ``serve_step_paged_spliced`` once ``splice_paged`` spliced
-        pages in."""
+        pages in, or plus (state_slots,) for
+        ``serve_step_hybrid_paged``."""
         if self.spliced_pages:
             return (self.block_table, self.lengths, self.page_delta,
                     self.page_valid)
+        if self.state_slots is not None:
+            return self.block_table, self.lengths, self.state_slots
         return self.block_table, self.lengths
