@@ -14,12 +14,16 @@ the step's conv outputs and softplus'd step sizes, ``A < 0``):
     S <- exp(dt * A) * S + dt * (x outer B)          S: [P, N]
     y  = S C + D x                                   y: [P]
 
-TPU layout: a head's state [P, N] is one (P, N) tile of lanes N, read as
-a block of ``head_block`` heads.  ``x`` arrives lane-major ([1, P] per
-head) and is turned into a column by a diagonal select-and-sum (an
-exact f32 transpose on the VPU); ``y`` goes back to a row the same way.
-The per-head scalars ``dt``, ``A``, ``D`` arrive as [1, head_block]
-rows and are sliced per head.
+TPU layout: a block of ``head_block`` heads' state [hb, P, N] is read
+as one [hb * P, N] tile of lanes N (a reshape of leading dims, ``P`` a
+multiple of 8).  ``x``, ``dt``, ``A`` and ``D`` arrive lane-major, one
+value per (head, channel), as [1, hb * P] rows (the wrapper repeats the
+per-head ``dt``, ``A``, ``D`` over ``P``).  The decay ``exp(dt A)`` and
+``dt x`` are stacked into one [8, hb * P] tile whose single transpose
+makes them the state tile's columns, so the update is one broadcast
+multiply-add over the whole tile.  ``y`` is one MXU product of ``C``
+(broadcast to 8 rows) with the tile, contracting ``N`` at ``HIGHEST``
+precision, which leaves it lane-major beside ``D x``.
 
 Grid = (B, H / head_block): one state block is fetched, updated and
 written back per grid step.  Rows that share a slot (a wave's padding
@@ -52,25 +56,23 @@ def head_block(H: int, P: int, N: int) -> int:
 def _ssm_kernel(layer_ref, slot_ref, dt_ref, a_ref, d_ref, x_ref, b_ref,
                 c_ref, s_ref, y_ref, so_ref, *, hb: int, P: int):
     del layer_ref, slot_ref            # used by the index maps only
-    b_row = b_ref[0]                                   # [1, N]
-    c_row = c_ref[0]
-    dt = dt_ref[0, 0]                                  # [1, hb]
-    dA = jnp.exp(dt * a_ref[0])                        # [1, hb]
-    d = d_ref[0]
-    xs = x_ref[0]                                      # [hb, P]
-    eye = (jax.lax.broadcasted_iota(jnp.int32, (P, P), 0)
-           == jax.lax.broadcasted_iota(jnp.int32, (P, P), 1))
-    for h in range(hb):
-        x_row = xs[h:h + 1, :]                         # [1, P]
-        x_col = jnp.sum(jnp.where(eye, x_row, 0.0), axis=1,
-                        keepdims=True)                 # [P, 1]
-        s = (s_ref[0, 0, h] * dA[:, h:h + 1]
-             + (dt[:, h:h + 1] * x_col) * b_row)       # [P, N]
-        so_ref[0, 0, h] = s
-        y_col = (jnp.sum(s * c_row, axis=1, keepdims=True)
-                 + d[:, h:h + 1] * x_col)              # [P, 1]
-        y_ref[0, h:h + 1, :] = jnp.sum(jnp.where(eye, y_col, 0.0), axis=0,
-                                       keepdims=True)
+    N = b_ref.shape[-1]
+    dt = dt_ref[0, 0]                                  # [1, hb*P]
+    x = x_ref[0, 0]
+    # row 0 the decay, the other rows dt x; one transpose makes both
+    # columns of the state tile
+    rows = jnp.where(
+        jax.lax.broadcasted_iota(jnp.int32, (8, hb * P), 0) == 0,
+        jnp.exp(dt * a_ref[0]), dt * x)                # [8, hb*P]
+    cols = rows.T                                      # [hb*P, 8]
+    s = (cols[:, 0:1] * s_ref[0, 0].reshape(hb * P, N)
+         + cols[:, 1:2] * b_ref[0])                    # [hb*P, N]
+    so_ref[0, 0] = s.reshape(hb, P, N)
+    c8 = jnp.broadcast_to(c_ref[0], (8, N))
+    y = jax.lax.dot_general(c8, s, (((1,), (1,)), ((), ())),
+                            precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)
+    y_ref[0, 0] = y[0:1] + d_ref[0] * x
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -90,40 +92,32 @@ def ssm_decode_update(state: jax.Array, layer: jax.Array, slots: jax.Array,
     hb = head_block(H, P, N)
     nb = H // hb
     f32 = jnp.float32
-    dt4 = dt.astype(f32).reshape(Bn, nb, 1, hb)
-    a3 = A.astype(f32).reshape(nb, 1, hb)
-    d3 = D.astype(f32).reshape(nb, 1, hb)
+    lanes = lambda t: t.astype(f32).reshape(Bn, nb, 1, hb * P)
+    per_head = lambda t: jnp.repeat(t.astype(f32), P).reshape(nb, 1, hb * P)
     b3 = B.astype(f32).reshape(Bn, 1, N)
     c3 = C.astype(f32).reshape(Bn, 1, N)
     kern = functools.partial(_ssm_kernel, hb=hb, P=P)
+    row = pl.BlockSpec((1, 1, 1, hb * P), lambda b, j, li, sl: (b, j, 0, 0))
+    head = pl.BlockSpec((1, 1, hb * P), lambda b, j, li, sl: (j, 0, 0))
+    vec = pl.BlockSpec((1, 1, N), lambda b, j, li, sl: (b, 0, 0))
+    block = pl.BlockSpec((1, 1, hb, P, N),
+                         lambda b, j, li, sl: (li[0], sl[b], j, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(Bn, nb),
-        in_specs=[
-            pl.BlockSpec((1, 1, 1, hb), lambda b, j, li, sl: (b, j, 0, 0)),
-            pl.BlockSpec((1, 1, hb), lambda b, j, li, sl: (j, 0, 0)),
-            pl.BlockSpec((1, 1, hb), lambda b, j, li, sl: (j, 0, 0)),
-            pl.BlockSpec((1, hb, P), lambda b, j, li, sl: (b, j, 0)),
-            pl.BlockSpec((1, 1, N), lambda b, j, li, sl: (b, 0, 0)),
-            pl.BlockSpec((1, 1, N), lambda b, j, li, sl: (b, 0, 0)),
-            pl.BlockSpec((1, 1, hb, P, N),
-                         lambda b, j, li, sl: (li[0], sl[b], j, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, hb, P), lambda b, j, li, sl: (b, j, 0)),
-            pl.BlockSpec((1, 1, hb, P, N),
-                         lambda b, j, li, sl: (li[0], sl[b], j, 0, 0)),
-        ],
+        in_specs=[row, head, head, row, vec, vec, block],
+        out_specs=[row, block],
     )
     y, state = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((Bn, H, P), f32),
+        out_shape=[jax.ShapeDtypeStruct((Bn, nb, 1, hb * P), f32),
                    jax.ShapeDtypeStruct(state.shape, state.dtype)],
         # operand 8 counts the two scalar-prefetch operands
         input_output_aliases={8: 1},
         interpret=interpret,
         name="ssm_decode_update",
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), slots.astype(jnp.int32),
-      dt4, a3, d3, x.astype(f32), b3, c3, state)
-    return y, state
+      lanes(jnp.repeat(dt, P, axis=1)), per_head(A), per_head(D), lanes(x),
+      b3, c3, state)
+    return y.reshape(Bn, H, P), state

@@ -159,32 +159,58 @@ def test_the_dense_bucket_counts_state_beside_kv():
                                     + kv.state_row_nbytes())
 
 
-@pytest.mark.parametrize("L,S,H,P,N,B", [
-    (3, 5, 8, 16, 16, 4),
-    (2, 6, 16, 8, 128, 3),
-    (2, 4, 64, 64, 128, 2),       # granite-4.0-h-micro's heads, two blocks
-])
-def test_ssm_decode_update_matches_its_oracle_in_place(L, S, H, P, N, B):
+SSM_CASES = [
+    (3, 5, 8, 16, 16, 4, 0),
+    (2, 6, 16, 8, 128, 3, 0),
+    (2, 4, 64, 64, 128, 2, 0),    # granite-4.0-h-micro's heads, two blocks
+    (1, 3, 192, 64, 128, 2, 0),   # three blocks or more
+    (2, 5, 32, 8, 64, 3, 0),      # P of 8, N under a vreg's lanes
+    (2, 4, 24, 16, 32, 2, 0),     # P of 16
+    (2, 3, 16, 64, 64, 2, 0),     # N under a vreg's lanes
+    (2, 6, 64, 64, 128, 3, 4),    # padding rows share the scratch slot
+]
+
+
+@pytest.mark.parametrize(
+    "L,S,H,P,N,B,pad", SSM_CASES,
+    ids=["-".join(map(str, c[:6])) + (f"-pad{c[6]}" if c[6] else "")
+         for c in SSM_CASES])
+def test_ssm_decode_update_matches_its_oracle_in_place(L, S, H, P, N, B,
+                                                       pad):
+    """B live rows on distinct slots, and ``pad`` padding rows that all
+    write the last slot, as a wave's padding rows write the scratch
+    slot: the live rows' y and state are the oracle's, and the slots
+    no row names are left as they were."""
     rng = np.random.default_rng(L * 100 + H)
     f = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32)
-    state, x, b, c, d = f(L, S, H, P, N), f(B, H, P), f(B, N), f(B, N), f(H)
-    dt = jnp.asarray(rng.random((B, H)), jnp.float32)
+    R = B + pad
+    state, x, b, c, d = f(L, S, H, P, N), f(R, H, P), f(R, N), f(R, N), f(H)
+    dt = jnp.asarray(rng.random((R, H)), jnp.float32)
     a = -jnp.asarray(rng.random(H) + 0.5, jnp.float32)
-    slots = jnp.asarray(rng.permutation(S)[:B], jnp.int32)
+    scratch = S - 1
+    slots = np.concatenate([rng.permutation(S - bool(pad))[:B],
+                            np.full(pad, scratch)])
+    order = rng.permutation(R)
+    live = np.argsort(order)[:B]          # rows of the live slots
+    slots = jnp.asarray(slots[order], jnp.int32)
     layer = jnp.int32(L - 1)
-    y0, s0 = ref.ssm_decode_update_ref(state, layer, slots, x, dt, b, c, a, d)
+    y0, s0 = ref.ssm_decode_update_ref(state, layer, slots[live], x[live],
+                                       dt[live], b[live], c[live], a, d)
     y1, s1 = ops.ssm_decode_update(state, layer, slots, x, dt, b, c, a, d,
                                    mode="kernel_interpret")
-    np.testing.assert_allclose(np.asarray(y1), np.asarray(y0), rtol=1e-5,
-                               atol=1e-5)
-    np.testing.assert_allclose(np.asarray(s1), np.asarray(s0), rtol=1e-6,
-                               atol=1e-6)
-    untouched = np.ones((L, S), bool)
-    untouched[L - 1, np.asarray(slots)] = False
+    np.testing.assert_allclose(np.asarray(y1)[live], np.asarray(y0),
+                               rtol=1e-5, atol=1e-5)
+    held = np.ones((L, S), bool)
+    if pad:
+        held[L - 1, scratch] = False
+    np.testing.assert_allclose(np.asarray(s1)[held], np.asarray(s0)[held],
+                               rtol=1e-6, atol=1e-6)
+    untouched = held.copy()
+    untouched[L - 1, np.asarray(slots)[live]] = False
     assert np.array_equal(np.asarray(s1)[untouched],
                           np.asarray(state)[untouched])
-    assert not np.allclose(np.asarray(s1)[L - 1, np.asarray(slots)],
-                           np.asarray(state)[L - 1, np.asarray(slots)])
+    assert not np.allclose(np.asarray(s1)[L - 1, np.asarray(slots)[live]],
+                           np.asarray(state)[L - 1, np.asarray(slots)[live]])
 
 
 def test_paged_step_matches_the_full_sequence_forward():
