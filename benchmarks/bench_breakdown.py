@@ -8,7 +8,7 @@ import time
 import numpy as np
 
 import repro.core as core
-from repro.serving import PipelineExecutor, make_traces
+from repro.serving import RetrievalRuntime, make_traces
 from benchmarks.common import (bench_index, bench_queries, emit, make_engine,
                                paper_scale_tcc, write_csv,
                                summarize_rows, write_report)
@@ -27,9 +27,12 @@ def run(n_queries: int = 8):
 
     for pipe in ("hyde", "iter", "irg"):
         eng = make_engine(buffer_pages=1024)
-        ex = PipelineExecutor(eng)
-        res = ex.execute_batch(bench_queries(n_queries, seed=71),
-                               make_traces(pipe, n_queries, seed=72))
+        rt = RetrievalRuntime(eng)
+        q = bench_queries(n_queries, seed=71)
+        recs = [rt.submit(q[i], t) for i, t in
+                enumerate(make_traces(pipe, n_queries, seed=72))]
+        rt.run()
+        res = [r.result for r in recs]
         t_cc = paper_scale_tcc(eng.cfg.hw)
         llm = np.mean([sum(rt.t_llm_window for rt in r.rounds) for r in res])
         cpu_ret = np.mean([sum((rt.hits + rt.misses) * t_cc

@@ -95,8 +95,8 @@ def run_admission(n_queries: int = 8):
     assert not eng.admission.parked, "parked waves leaked past the drain"
     # the whole point of this smoke: the pressure path actually ran
     assert adm.stalled > 0 and adm.resumed > 0, adm
-    stalls = [rid for _, label, rid in runtime.event_log
-              if label == "pressure_stall"]
+    stalls = [e.request_id for e in eng.recorder.events
+              if e.kind == "request" and e.label == "pressure_stall"]
     rows = [{"pool_pages": pool_pages,
              "admitted": adm.admitted, "stalled": adm.stalled,
              "resumed": adm.resumed, "capped": adm.capped,

@@ -58,8 +58,7 @@ def _serve(params, q, traces, *, store: Optional[ChunkKVStore],
         nprobe=NPROBE, top_k=3, buffer_pages=640, pool_pages=8192,
         lookahead_rank=2 * NPROBE, chips=8, seed=7,
         paged_decode=True, chunk_kv=store is not None), 1, ARCH,
-        micro_batch=micro_batch, include_tail=True, decode_hook=runner,
-        continuous=True)
+        micro_batch=micro_batch, include_tail=True, decode_hook=runner)
     runner.attach(srv)
     resp = srv.serve([RagRequest(q=q[i], trace=traces[i], arrival_t=0.0)
                       for i in range(len(traces))])

@@ -11,7 +11,8 @@ import numpy as np
 
 import repro.core as core
 from repro.configs import get_arch
-from repro.serving import PipelineExecutor, calibration_windows, make_traces
+from repro.serving import (RetrievalRuntime, calibration_windows,
+                           make_traces)
 from benchmarks.common import (bench_index, bench_queries, emit, make_engine,
                                write_csv,
                                summarize_rows, write_report)
@@ -31,13 +32,14 @@ def run(n_queries: int = 32, arch: str = "llama3-8b"):
             cfg, core.TPU_V5E, gen_tokens=wins, batch=1, chips=4,
             hbm_headroom_bytes=idx.paged.all_cluster_bytes().sum() * 0.35)
         eng = make_engine(budget_bytes=int(budget), buffer_pages=2048)
-        ex = PipelineExecutor(eng)
+        rt = RetrievalRuntime(eng)
         qs = bench_queries(n_queries, seed=11)
         traces = make_traces(pipe, n_queries, seed=12)
         t0 = time.time()
         res = []
         for i in range(n_queries):      # Table 3 is single-query serving
-            res.extend(ex.execute_batch(qs[i:i + 1], traces[i:i + 1]))
+            res.append(rt.submit(qs[i], traces[i]).result)
+            rt.run()
         wall = (time.time() - t0) * 1e6 / n_queries
         hits = sum(rt.hits for r in res for rt in r.rounds)
         miss = sum(rt.misses for r in res for rt in r.rounds)

@@ -30,7 +30,7 @@ def run(n_requests: int = 24, replicas: int = 2, micro_batch: int = 4,
         seed: int = 71):
     srv = make_server(replicas=replicas, cache=True, buffer_pages=768,
                       scheduler=TeleRAGScheduler(),
-                      micro_batch=micro_batch, continuous=True)
+                      micro_batch=micro_batch)
     # hyde/iter mix: both pipelines prefetch, with different round
     # shapes; re-id so the mix's request ids stay unique (make_traces
     # numbers 0..n-1 per call and the recorder correlates by id)

@@ -13,8 +13,9 @@ The SLO-aware configuration (the server default: ``EdfDispatch``
 priority classes + earliest-deadline-first, plus per-tenant pool
 reservations giving the latency tenant a guaranteed page floor and
 capping the batch tenant's burst) is compared against a mixed baseline
-(``FifoDispatch`` — strict arrival order, no reservations) on the
-*identical* workload.  Asserts every request of BOTH tenants completes
+(``FifoDispatch``, no reservations) on the *identical* workload.  The
+replica's wave former serves priority classes first under both, so the
+two differ by the reservations.  Asserts every request of BOTH tenants completes
 under both configurations and that the latency tenant's deadline-miss
 rate under the SLO configuration is no worse than under the baseline;
 ``--smoke`` is the CI guard.

@@ -84,17 +84,14 @@ def make_engine(mode: str = "telerag", *, buffer_pages: int = 640,
 def make_server(mode: str = "telerag", *, replicas: int = 1,
                 scheduler=None, micro_batch=None, buffer_pages: int = 640,
                 budget_bytes=None, cache: bool = False, arch="llama3-8b",
-                chips: int = 4, seed: int = 0,
-                continuous: bool = False) -> TeleRAGServer:
+                chips: int = 4, seed: int = 0) -> TeleRAGServer:
     """A TeleRAGServer over the shared bench index (the serving
-    front-end the benches drive instead of raw executors).
-    ``continuous=True`` enables per-request continuous batching."""
+    front-end the benches drive instead of raw executors)."""
     cfg = bench_cfg(mode, buffer_pages=buffer_pages,
                     budget_bytes=budget_bytes, cache=cache, chips=chips,
                     seed=seed)
     return TeleRAGServer(bench_index(), cfg, replicas, get_arch(arch),
-                         scheduler=scheduler, micro_batch=micro_batch,
-                         continuous=continuous)
+                         scheduler=scheduler, micro_batch=micro_batch)
 
 
 def serve_requests(srv: TeleRAGServer, q, traces, arrivals=None):

@@ -26,10 +26,10 @@ def main() -> None:
         args.quick = True
         if args.only is None:
             args.only = ("overlap,overlap_trace,sched,admission,openloop,"
-                         "tenants,continuous,decode_microbench,chunk_kv")
+                         "tenants,decode_microbench,chunk_kv")
 
     from benchmarks import (bench_breakdown, bench_budget, bench_chunk_kv,
-                            bench_continuous, bench_decode_microbench,
+                            bench_decode_microbench,
                             bench_hitrate, bench_kernels, bench_latency,
                             bench_nprobe, bench_openloop, bench_overlap,
                             bench_overlap_trace, bench_sched, bench_scaling,
@@ -72,9 +72,6 @@ def main() -> None:
         "tenants": lambda: bench_tenants.run(
             n_latency=4 if args.quick else 8,
             n_batch=10 if args.quick else 24),
-        "continuous": lambda: bench_continuous.run(
-            n_requests=12 if args.quick else 32,
-            micro_batch=2 if args.quick else 4),
     }
     only = set(args.only.split(",")) if args.only else None
     print("name,us_per_call,derived")

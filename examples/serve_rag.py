@@ -14,9 +14,9 @@ deadline-carrying interactive tenant (with a guaranteed pool floor)
 shares the fleet with a bursty batch tenant; EDF dispatch + per-tenant
 reservations keep the interactive tenant's deadlines while both
 complete, and the per-tenant telemetry lines show the split.  Wave 5
-contrasts static-group execution with per-request continuous batching
-(`continuous=True`): the dynamic wave former re-batches at every round
-frontier, so heterogeneous round counts stop dragging batch-mates.
+is per-request continuous batching on heterogeneous round counts: the
+dynamic wave former re-batches at every round frontier, so a request
+with more rounds never drags its batch-mates.
 
 Run: PYTHONPATH=src python examples/serve_rag.py [--requests 24]
 """
@@ -140,8 +140,8 @@ def main():
     missed = [r.request_id for r in resp4 if r.deadline_missed]
     print(f"all {len(resp4)} served; deadline misses: {missed or 'none'}")
 
-    print("\n== wave 5: per-request continuous batching vs static "
-          "groups (heterogeneous round counts) ==")
+    print("\n== wave 5: per-request continuous batching "
+          "(heterogeneous round counts) ==")
     n5 = args.requests
     q5 = wave(n5)
     pipes = ["hyde", "iter", "irg", "flare"]
@@ -149,17 +149,15 @@ def main():
              for i in range(n5)]
     for i, t in enumerate(mixed):
         t.request_id = i
-    for continuous in (False, True):
-        srv5 = TeleRAGServer(index, cfg, 1, get_arch("llama3-8b"),
-                             micro_batch=args.micro_batch,
-                             continuous=continuous)
-        resp5 = srv5.serve([
-            RagRequest(q=q5[i], trace=mixed[i], arrival_t=0.002 * i)
-            for i in range(n5)])
-        label = "per-request" if continuous else "static-groups"
-        n_waves = sum(len(rt.wave_log) for rt in srv5.runtimes)
-        print(f"{label:>14}: {summarize_latency(resp5)} "
-              f"({n_waves} waves executed)")
+    srv5 = TeleRAGServer(index, cfg, 1, get_arch("llama3-8b"),
+                         micro_batch=args.micro_batch)
+    resp5 = srv5.serve([
+        RagRequest(q=q5[i], trace=mixed[i], arrival_t=0.002 * i)
+        for i in range(n5)])
+    n_waves = sum(len(rt.wave_log) for rt in srv5.runtimes)
+    sizes = [len(w.members) for rt in srv5.runtimes for w in rt.wave_log]
+    print(f"per-request: {summarize_latency(resp5)} ({n_waves} waves "
+          f"executed, {min(sizes)}-{max(sizes)} members each)")
 
     print("\n== unified telemetry snapshot ==")
     print(srv.telemetry().summary())

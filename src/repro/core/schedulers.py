@@ -21,11 +21,12 @@ waves (default: EDF within priority classes, FIFO among equals,
 ``micro_batch``-capped), so a straggler never drags its former
 batch-mates and mid-stream admits join in-flight work.
 
-Dispatch policy: once micro-batches are queued on a replica, a
-``DispatchPolicy`` orders them.  ``EdfDispatch`` (the default) runs
-priority classes first and earliest-deadline-first inside a class; with
-no deadlines set it degrades exactly to the legacy (priority, FIFO)
-tie-break, which is what keeps the deprecated shims pinned equivalent.
+Dispatch policy: a ``DispatchPolicy`` orders the micro-batches one
+arrival wave routes to a replica as the server submits them into its
+runtime.  ``EdfDispatch`` (the default) runs priority classes first and
+earliest-deadline-first inside a class, then enqueue order.  The wave
+former then re-sorts the ready requests by (priority, deadline,
+arrival), so the dispatch order decides only ties the former leaves.
 """
 
 from __future__ import annotations
@@ -284,12 +285,12 @@ class RoundRobinScheduler(TeleRAGScheduler):
 
 
 class DispatchPolicy:
-    """Orders a replica's *queued* micro-batches: when the replica
-    runtime drains, the server dispatches the batch with the smallest
-    ``key``.  Keys are compared lexicographically; ``deadline_t`` is an
-    absolute event-clock deadline in seconds (``inf`` = no SLO) and
-    ``order`` is the batch's global enqueue sequence (the FIFO anchor
-    that makes every policy total and deterministic)."""
+    """Orders a replica's *queued* micro-batches: the server submits
+    them into the replica runtime smallest ``key`` first.  Keys are
+    compared lexicographically; ``deadline_t`` is an absolute
+    event-clock deadline in seconds (``inf`` = no SLO) and ``order`` is
+    the batch's global enqueue sequence (the FIFO anchor that makes
+    every policy total and deterministic)."""
 
     name: str = "base"
 
@@ -315,8 +316,7 @@ class FifoDispatch(DispatchPolicy):
 class EdfDispatch(DispatchPolicy):
     """Priority classes first, earliest-deadline-first within a class,
     FIFO among equals.  With no deadlines set (every ``deadline_t`` is
-    ``inf``) this is exactly the legacy (priority, order) tie-break, so
-    single-tenant callers see unchanged dispatch order."""
+    ``inf``) this is the (priority, order) tie-break."""
 
     name = "edf"
 

@@ -15,8 +15,7 @@ replica is one device: its page pool, KV slab and params live there.
 Decode is **asynchronous and real**: the hook returns per-request
 ``DecodeEvent``s (observed steps + measured wall seconds), so each
 request's generation windows on the event clock come from the decode
-that actually ran.  By default the server runs per-request continuous
-batching (``--static-groups`` restores group-granular execution).
+that actually ran.  The server runs per-request continuous batching.
 
 The decode hook is a ``serving.DecodeRunner`` on the **paged KV
 substrate**: each wave leases a block table over a shared page slab and
@@ -107,7 +106,7 @@ def pool_pages(cfg: ArchConfig, page_nbytes: int, *, batch: int,
 def build_server(cfg: ArchConfig, index: IVFIndex, *,
                  devices: Sequence[jax.Device], batch: int, max_len: int,
                  nprobe: int, seed: int, params=None, scheduler=None,
-                 static_groups: bool = False, dense_decode: bool = False,
+                 dense_decode: bool = False,
                  ) -> Tuple[TeleRAGServer, DecodeRunner]:
     """One replica per device behind a ``TeleRAGServer``, each decoding
     ``cfg`` through a shared ``decode_runner``, each pool sized by
@@ -127,8 +126,8 @@ def build_server(cfg: ArchConfig, index: IVFIndex, *,
         kernel_mode="auto", cache_enabled=True, chips=1,
         paged_decode=not dense_decode, seed=seed), len(devices), cfg,
         scheduler=scheduler, micro_batch=batch, include_tail=True,
-        decode_hook=runner, continuous=not static_groups,
-        wall_clock=SystemClock(), devices=list(devices))
+        decode_hook=runner, wall_clock=SystemClock(),
+        devices=list(devices))
     runner.attach(srv)
     for eng in srv.engines:
         eng.calibrate_tcc()
@@ -157,9 +156,6 @@ def main(argv: Optional[Sequence[str]] = None):
     ap.add_argument("--clusters", type=int, default=CLUSTERS)
     ap.add_argument("--nprobe", type=int, default=NPROBE)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--static-groups", action="store_true",
-                    help="group-granular execution instead of "
-                         "per-request continuous batching")
     ap.add_argument("--dense-decode", action="store_true",
                     help="decode on the dense [B, max_len] KV bucket path "
                          "instead of the paged block-table substrate")
@@ -188,8 +184,7 @@ def main(argv: Optional[Sequence[str]] = None):
     dev = jax.devices()[0]
     srv, runner = build_server(
         cfg, index, devices=[dev], batch=args.batch, max_len=args.max_len,
-        nprobe=args.nprobe, seed=args.seed,
-        static_groups=args.static_groups, dense_decode=args.dense_decode)
+        nprobe=args.nprobe, seed=args.seed, dense_decode=args.dense_decode)
     eng = srv.engines[0]
 
     q = make_queries(store, args.requests, args.seed)

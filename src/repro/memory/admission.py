@@ -239,10 +239,9 @@ class AdmissionController:
              tenant: str = "shared") -> None:
         """Record a stalled wave (``key``) waiting for ``npages`` to
         become free; ``tenant`` keeps the resume stats attributable.
-        ``key`` is opaque to the controller: the runtime parks a
-        dynamically-formed wave object (whose members re-enter the
-        ready set individually on resume) or a ``(cohort, round)``
-        tuple in never-re-form mode."""
+        ``key`` is opaque to the controller: the runtime parks its
+        wave object, whose members re-enter the ready set individually
+        on resume."""
         self.parked.append((key, int(npages), tenant))
 
     def unpark_all(self) -> List[Tuple[object, int]]:

@@ -16,11 +16,11 @@ from repro.obs.analyze import OverlapReport, OverlapRound, analyze
 from repro.obs.clock import SYSTEM_CLOCK, EventClock, SystemClock
 from repro.obs.export import to_jsonl, to_perfetto, write_jsonl, write_trace
 from repro.obs.metrics import Counter, Gauge, Histogram, MetricsRegistry
-from repro.obs.recorder import (LEGACY_LABELS, NULL_SPAN, AdmissionEvent,
-                                ChunkKVEvent, CounterSample, DecodeStep,
-                                FlightRecorder, HostRequest, HostSpan,
-                                KVEvent, PoolEvent, RequestEvent, SpanEvent,
-                                TraceEvent, TransferRecord, WaveEvent)
+from repro.obs.recorder import (NULL_SPAN, AdmissionEvent, ChunkKVEvent,
+                                CounterSample, DecodeStep, FlightRecorder,
+                                HostRequest, HostSpan, KVEvent, PoolEvent,
+                                RequestEvent, SpanEvent, TraceEvent,
+                                TransferRecord, WaveEvent)
 from repro.obs.render import (render_replica_line, render_telemetry,
                               render_tenant_line)
 
@@ -29,7 +29,7 @@ __all__ = [
     "DecodeStep",
     "EventClock", "SYSTEM_CLOCK", "SystemClock",
     "FlightRecorder", "Gauge", "Histogram", "HostRequest", "HostSpan",
-    "KVEvent", "LEGACY_LABELS", "NULL_SPAN",
+    "KVEvent", "NULL_SPAN",
     "MetricsRegistry", "OverlapReport", "OverlapRound", "PoolEvent",
     "RequestEvent", "render_replica_line", "render_telemetry",
     "render_tenant_line", "SpanEvent", "to_jsonl",

@@ -18,11 +18,6 @@ event-loop step.  Events may therefore be *appended* slightly out of
 future timestamp); consumers that need time order use
 ``sorted_events()``.
 
-``legacy_tuples()`` is the compatibility shim for the retired
-``RetrievalRuntime.event_log`` list: the same ``(t, label,
-request_id)`` 3-tuples, in emission order, filtered to one replica's
-lane — existing tests and benches keep iterating it unchanged.
-
 Host-clock spans are the recorder's second, opt-in stream.  Event-clock
 stamps are modeled; ``span(name, **args)`` times real host work on an
 injected real clock (``enable_host_spans``).  Off by default, a span is
@@ -38,15 +33,6 @@ from __future__ import annotations
 
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple
-
-# the request-lifecycle labels the retired ``runtime.event_log`` carried;
-# ``legacy_tuples()`` reproduces exactly these (a server-side "submit"
-# mark is NOT one of them — it never appeared in the legacy log)
-LEGACY_LABELS = frozenset({
-    "admit", "prefetch", "generate", "retrieve", "complete",
-    "pressure_stall", "pressure_resume", "prefetch_demoted",
-})
-
 
 @dataclass(frozen=True)
 class TraceEvent:
@@ -419,16 +405,6 @@ class FlightRecorder:
                     and e.label not in out):
                 out[e.label] = e.t
         return out
-
-    def legacy_tuples(self, replica: Optional[int] = None,
-                      ) -> List[Tuple[float, str, int]]:
-        """The retired ``runtime.event_log`` view: ``(t, label,
-        request_id)`` tuples in emission order, filtered to one
-        replica's lane (None = all lanes) and to the labels the legacy
-        log carried."""
-        return [(e.t, e.label, e.request_id) for e in self.events
-                if e.kind == "request" and e.label in LEGACY_LABELS
-                and (replica is None or e.replica == replica)]
 
     def clear(self) -> None:
         """Drop all events and host spans (the clock cursor is kept —

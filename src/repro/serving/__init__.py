@@ -9,9 +9,6 @@ from repro.serving.chunk_kv import (ChunkKVCache, ChunkKVStats,
 from repro.serving.decode import DecodeRunner, supports_paged_decode
 from repro.serving.kv_cache import (CacheLease, KVCacheManager, KVPageSlab,
                                     PagedCacheLease)
-from repro.serving.pipelines import (GlobalBatchReport,
-                                     MultiReplicaOrchestrator,
-                                     PipelineExecutor, PIPELINE_NAMES)
 from repro.serving.policies import (LatencyContext, RetrievalPolicy,
                                     get_policy, policy_names,
                                     register_policy)
@@ -28,8 +25,6 @@ __all__ = [
     "ChunkKVCache", "ChunkKVStats", "ChunkResidency",
     "DecodeRunner", "supports_paged_decode",
     "CacheLease", "KVCacheManager", "KVPageSlab", "PagedCacheLease",
-    "GlobalBatchReport", "MultiReplicaOrchestrator", "PipelineExecutor",
-    "PIPELINE_NAMES",
     "LatencyContext", "RetrievalPolicy", "get_policy", "policy_names",
     "register_policy",
     "DecodeEvent", "RequestRecord", "RequestState", "RetrievalRuntime",

@@ -1,10 +1,7 @@
 """Unified serving front-end: the paper's Fig. 7 system as ONE surface.
 
-The public API used to be three disjoint layers callers had to
-hand-wire — ``TeleRAGEngine`` (resources), ``RetrievalRuntime`` (one
-replica's event loop), and ``MultiReplicaOrchestrator.run_global_batch``
-(a *blocking* global batch that drained replicas serially in lockstep).
-``TeleRAGServer`` replaces that with a client-facing facade and a
+``TeleRAGServer`` is a client-facing facade over the replica engines
+(``TeleRAGEngine``) and their event loops (``RetrievalRuntime``), with a
 **continuous dispatcher on a shared global event clock**:
 
   * clients ``submit()`` typed ``RagRequest``s carrying an open-loop
@@ -13,29 +10,20 @@ replica's event loop), and ``MultiReplicaOrchestrator.run_global_batch``
     into micro-batches and the cache-aware scheduler routes them to
     replicas (the existing ``SchedulerPolicy``, reading live per-replica
     cache residency and ledger occupancy at the wave's clock time);
-  * micro-batches queue per replica and execute on per-replica
-    ``RetrievalRuntime``s that the dispatcher *merge-steps* — it always
-    advances the runtime holding the globally-earliest event — so
-    replica timelines interleave on one clock instead of draining one
-    replica at a time.  Open-loop throughput and latency-under-load
-    (queue wait + service) are measurable for the first time.
+  * micro-batches are submitted into per-replica ``RetrievalRuntime``s
+    that the dispatcher *merge-steps* — it always advances the runtime
+    holding the globally-earliest event — so replica timelines
+    interleave on one clock.  Open-loop throughput and latency-under-
+    load (queue wait + service) are measurable.
 
-Within a replica the server runs one of two dispatch disciplines.  The
-default (``continuous=False``) keeps one micro-batch in flight at a
-time; queued batches dispatch the instant the runtime drains, and
-``end_batch`` consolidation runs between batches exactly as the legacy
-executor did — which is what pins the legacy-equivalence guarantee: for
-simultaneous arrivals the server reproduces ``run_global_batch``'s doc
-ids and round telemetry to 1e-6 (tests/test_api.py).
-
-``continuous=True`` is **per-request continuous batching inside the
-replica**: routed micro-batches are submitted into the live runtime
-immediately, the runtime's dynamic wave former
-(``SchedulerPolicy.reform_wave``) re-batches whichever requests are
-ready at every round frontier — so a straggler never delays its former
-batch-mates, new arrivals join in-flight work mid-stream, and the
-dispatcher consumes per-request *completion events* instead of batch
-drains.  See the "request lifecycle" section of docs/ARCHITECTURE.md.
+Within a replica the server runs **per-request continuous batching**:
+routed micro-batches are submitted into the live runtime immediately,
+the runtime's dynamic wave former (``SchedulerPolicy.reform_wave``)
+re-batches whichever requests are ready at every round frontier — so a
+straggler never delays its former batch-mates, new arrivals join
+in-flight work mid-stream, and the dispatcher counts per-request
+*completion events*.  See the "request lifecycle" section of
+docs/ARCHITECTURE.md.
 
 ``ServerTelemetry`` unifies what previously lived in four places —
 ``buffer.stats``, ``cache.hit_rate``, ``ledger.snapshot()``,
@@ -357,8 +345,9 @@ class ServerTelemetry:
 
 @dataclass(frozen=True)
 class WaveDispatch:
-    """Routing record of one arrival wave (what run_global_batch's
-    report used to expose for the whole batch)."""
+    """Routing record of one arrival wave: each micro-batch's replica
+    and cache overlap, the batches re-routed off dead replicas, and the
+    scheduler's host time."""
 
     t: float
     assignments: List[Tuple[int, int, int]]   # (batch_idx, replica, overlap)
@@ -457,7 +446,7 @@ class TeleRAGServer:
                  batch_window_s: float = 0.0,
                  decode_hook: Optional[Callable] = None,
                  dispatch: Optional[DispatchPolicy] = None,
-                 continuous: bool = False,
+                 continuous: bool = True,
                  trace: Optional[FlightRecorder] = None,
                  wall_clock=None,
                  devices: Optional[Sequence] = None):
@@ -472,23 +461,19 @@ class TeleRAGServer:
         dispatch — prefetch is dispatched exactly once, by the policy;
         it may return per-request ``DecodeEvent``s whose observed
         timing drives the event clock in place of the modeled window.
-        ``dispatch`` orders each replica's queued micro-batches; the
-        default ``EdfDispatch`` runs priority classes then earliest
-        deadline first, which degrades to the legacy (priority, FIFO)
-        order when no request sets a deadline.
+        ``dispatch`` orders the micro-batches one wave routes to a
+        replica as they are submitted to its runtime; the default
+        ``EdfDispatch`` runs priority classes then earliest deadline
+        first, then enqueue order.
 
-        ``continuous=True`` enables per-request continuous batching
-        inside each replica: routed micro-batches are submitted into
-        the replica runtime *immediately* (no one-batch-at-a-time
-        serialization), the runtime's dynamic wave former re-batches
+        Routed micro-batches are submitted into the replica runtime
+        *immediately*: the runtime's dynamic wave former re-batches
         whichever requests are ready at every round frontier
         (``SchedulerPolicy.reform_wave``, ``micro_batch``-capped,
-        tenant-pure), and the dispatcher consumes **per-request
-        completion events** instead of waiting for batch drains.
-        ``continuous=False`` (the default) keeps the legacy
-        group-granular execution that the deprecated shims are pinned
-        against: one micro-batch in flight per replica, ``end_batch``
-        consolidation between batches.
+        tenant-pure), and the dispatcher counts **per-request
+        completion events**.  ``continuous`` accepts only ``True``:
+        static-group execution (one micro-batch in flight per replica)
+        was removed, and ``False`` raises ``ValueError``.
 
         ``wall_clock`` is the injected real-time source for the few
         measurements that are genuinely about THIS machine (scheduler
@@ -499,9 +484,13 @@ class TeleRAGServer:
 
         ``devices`` gives each replica its own jax device (one per
         replica; None = every replica on the first device)."""
+        if continuous is not True:
+            raise ValueError(
+                "TeleRAGServer(continuous=False) is not supported: "
+                "static-group execution was removed; every replica runs "
+                "per-request continuous batching")
         self.index = index
         self.cfg = cfg
-        self.continuous = bool(continuous)
         # ONE flight recorder across the whole server: every replica's
         # runtime, pool, admission controller, transfer engine and KV
         # manager emits into the same stream, correlated by replica id
@@ -520,18 +509,13 @@ class TeleRAGServer:
                         for i in range(num_replicas)]
         for i, eng in enumerate(self.engines):
             eng.attach_recorder(self.recorder, i)
-        # under continuous dispatch the runtime's wave former IS the
-        # scheduler policy (its reform_wave hook); the static path keeps
-        # runtimes scheduler-free because the server already grouped
+        # the runtime's wave former IS the scheduler policy (its
+        # reform_wave hook)
         self.runtimes = [
             RetrievalRuntime(
-                eng, include_tail=include_tail,
-                reform=self.continuous,
-                scheduler=(scheduler if self.continuous else None),
-                micro_batch=(micro_batch if self.continuous else None),
-                on_complete=((lambda rec, _r=r:
-                              self._on_request_complete(_r, rec))
-                             if self.continuous else None),
+                eng, include_tail=include_tail, scheduler=scheduler,
+                micro_batch=micro_batch,
+                on_complete=self._on_request_complete,
                 on_generate=(None if decode_hook is None else
                              (lambda recs, toks, rnd, _r=r:
                               decode_hook(_r, recs, toks, rnd))))
@@ -550,7 +534,6 @@ class TeleRAGServer:
         self._inbox: List[_Submitted] = []
         self._queues: List[List[_QueuedBatch]] = [
             [] for _ in range(num_replicas)]
-        self._busy = [False] * num_replicas
         self._rr = 0                       # round-robin cursor (no scheduler)
         self._global_now = 0.0
         # lifetime counts live in the registry; telemetry() reads them
@@ -629,7 +612,8 @@ class TeleRAGServer:
                     rt = self.runtimes[r]
                     rt.step()
                     if not rt.has_work():
-                        self._complete_batch(r)
+                        # consolidate the drained engine (end_batch)
+                        rt.collect()
         except BaseException:
             # a failed drain must not swallow work the caller handed us:
             # requests never dispatched to a replica go back to the inbox
@@ -692,7 +676,7 @@ class TeleRAGServer:
         time.  Micro-batches are tenant-pure: similarity grouping runs
         within each tenant's slice of the wave, so admission
         reservations and ledger attribution are well-defined per batch
-        (a single-tenant wave reduces to the legacy grouping exactly)."""
+        (a single-tenant wave is grouped whole)."""
         t0 = self.wall.perf()
         q = np.stack([np.asarray(s.request.q) for s in members])
         mb = self.micro_batch or len(members)
@@ -718,8 +702,8 @@ class TeleRAGServer:
                 batch_clusters = [set() for _ in groups]
             caches = [e.buffer.resident_clusters() for e in self.engines]
             occupancy = [e.ledger.occupancy() for e in self.engines]
-            # the untenanted sentinel gets no spread penalty: legacy
-            # single-tenant routing must see exactly the PR-3 scores
+            # the untenanted sentinel gets no spread penalty: its
+            # routing reads cache overlap and ledger occupancy alone
             tenant_occupancy = [
                 [0.0 for _ in self.engines]
                 if members[g[0]].request.tenant == "shared" else
@@ -770,7 +754,12 @@ class TeleRAGServer:
             self.recorder.emit(CounterSample(
                 t=wave_t, kind="counter", replica=r,
                 name="queue_depth", value=float(len(self._queues[r]))))
-            self._maybe_dispatch(r)
+            self._dispatch(r)
+
+    def _on_request_complete(self, rec: RequestRecord) -> None:
+        """Per-request completion event from a replica runtime: the
+        dispatcher's unit of progress."""
+        self._c_completed.inc()
 
     @staticmethod
     def _deadline_abs(s: _Submitted) -> float:
@@ -780,18 +769,11 @@ class TeleRAGServer:
             return float("inf")
         return s.arrival_abs + float(s.request.deadline_s)
 
-    def _maybe_dispatch(self, r: int) -> None:
-        """Feed the replica's best queued micro-batch to its runtime the
-        moment it is idle — at the later of the wave's clock time and
-        the runtime's own clock.  "Best" is the ``DispatchPolicy``'s
-        call: the default EDF order runs priority classes first and the
-        earliest absolute deadline within a class (pure head-of-line
-        FIFO when nothing carries a deadline).  Under ``continuous``
-        dispatch there is no idle gate: every queued micro-batch is
-        submitted into the (possibly mid-flight) runtime immediately —
-        its requests join waves at the next round frontier."""
-        if not self.continuous and self._busy[r]:
-            return
+    def _dispatch(self, r: int) -> None:
+        """Submit every queued micro-batch of replica ``r`` into its
+        (possibly mid-flight) runtime, at the later of the wave's clock
+        time and the runtime's own clock, in the ``DispatchPolicy``'s
+        order; the requests join waves at the next round frontier."""
         qr = self._queues[r]
         rt = self.runtimes[r]
         submitted = False
@@ -811,34 +793,11 @@ class TeleRAGServer:
                                      host_submit_s=s.host_submit_s)
             submitted = True
             self._c_batches.inc()
-            if not self.continuous:
-                rt.begin(rebase=False)
-                self._busy[r] = True
-                return
         if submitted:
             # one begin() for everything this call queued: begin scans
             # ALL pending submissions, so per-batch calls would push
             # duplicate admit events (O(k^2) heap traffic per wave)
             rt.begin(rebase=False)
-
-    def _on_request_complete(self, r: int, rec: RequestRecord) -> None:
-        """Per-request completion event from a continuous replica
-        runtime — the dispatcher's unit of progress under per-request
-        batching (the legacy path instead counts whole batch drains in
-        ``_complete_batch``)."""
-        self._c_completed.inc()
-
-    def _complete_batch(self, r: int) -> None:
-        """A replica drained its in-flight work: consolidate the engine
-        (end_batch, as the legacy per-group executor did) and dispatch
-        the next queued batch at the replica's clock.  Under continuous
-        dispatch completions were already counted per request, so this
-        only consolidates."""
-        recs = self.runtimes[r].collect()
-        if not self.continuous:
-            self._c_completed.inc(len(recs))
-        self._busy[r] = False
-        self._maybe_dispatch(r)
 
     def _response(self, s: _Submitted) -> RagResponse:
         """Fold one finished submission into a RagResponse, stamping

@@ -1,8 +1,8 @@
 """Event-driven retrieval runtime: per-request continuous batching over
 a priority event queue (§4.1/§4.2 made operational).
 
-Replaces the lockstep ``execute_batch`` loop.  Requests are **admitted**
-at arrival time and walked through a per-request state machine
+Requests are **admitted** at arrival time and walked through a
+per-request state machine
 
     QUEUED -> ADMITTED -> PREFETCHING -> GENERATING -> RETRIEVING
            ->  (ready: next round | COMPLETE)
@@ -50,15 +50,6 @@ Prefetch copies are ``TransferEvent``s on the engine's double-buffered
 link, so overlap between a transfer and a generation window is a fact
 of the event timeline (two intersecting intervals), not a ``max()``.
 
-**Never-re-form mode** (``reform=False``): the degenerate setting runs
-the same wave executor on *static cohorts* — the request's admission
-group is its wave for every round, frontiers fire at the cohort's
-earliest finisher, and each member keeps its own round start — which
-reproduces the legacy group-granular executor bit-for-bit (doc ids
-exact, telemetry to 1e-6; pinned by tests/test_runtime.py and
-tests/test_api.py).  ``PipelineExecutor`` and ``run_global_batch`` run
-in this mode.
-
 A request's admit→complete latency is read off the event clock
 (``RequestRecord.latency``), which is what the serve drivers report.
 """
@@ -69,7 +60,7 @@ import heapq
 import itertools
 from dataclasses import dataclass, field
 from enum import Enum
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -163,7 +154,6 @@ class RequestRecord:                   # live state, and `q` is an ndarray
     complete_t: float = float("nan")
     state: RequestState = RequestState.QUEUED
     timeline: List[Span] = field(default_factory=list)
-    round_start: List[float] = field(default_factory=list)
     tenant: str = "shared"
     priority: int = 0
     deadline_t: float = float("inf")
@@ -236,16 +226,6 @@ def tail_gen_tokens(trace: RequestTrace) -> int:
 
 
 @dataclass(eq=False)
-class _Cohort:
-    """Never-re-form mode's static admission group: its members stay
-    wave-mates for every round (the legacy ``_Group`` semantics)."""
-
-    gid: int
-    members: List[RequestRecord]
-    scheduled_rounds: set = field(default_factory=set)
-
-
-@dataclass(eq=False)
 class _Wave:
     """One dynamically-formed micro-batch: the requests executing a
     round frontier together (mixed ``rounds`` indices are normal — a
@@ -286,43 +266,32 @@ class RetrievalRuntime:
                                                  List[int], int],
                                                 Optional[Sequence[
                                                     DecodeEvent]]]] = None,
-                 reform: bool = True,
                  on_complete: Optional[Callable[["RequestRecord"],
                                                 None]] = None):
-        """``reform=True`` (the default) runs the dynamic wave former:
-        every round frontier re-batches the currently-ready requests.
-        ``reform=False`` is the degenerate never-re-form mode — the
-        admission group is the wave for every round — which reproduces
-        the legacy group-granular executor exactly (the deprecated
-        shims run in this mode).  ``on_generate`` is the decode hook:
-        called once per wave frontier, right after the async prefetch
-        dispatch, with the wave's records and their generation-window
-        token counts; serve drivers run REAL decode here (the copy is
-        genuinely in flight underneath) and may return per-request
-        ``DecodeEvent``s whose observed timing replaces the modeled
-        generation window on the event clock.  ``on_complete`` fires at
-        each request's completion event (the server's continuous
-        dispatcher consumes these instead of waiting for batch
-        drains)."""
+        """Every round frontier re-batches the currently-ready requests
+        with ``scheduler``'s ``reform_wave`` (the base EDF/tenant-aware
+        former when None), at most ``micro_batch`` to a wave.
+        ``on_generate`` is the decode hook: called once per wave
+        frontier, right after the async prefetch dispatch, with the
+        wave's records and their generation-window token counts; serve
+        drivers run REAL decode here (the copy is genuinely in flight
+        underneath) and may return per-request ``DecodeEvent``s whose
+        observed timing replaces the modeled generation window on the
+        event clock.  ``on_complete`` fires at each request's completion
+        event (the server counts completions there)."""
         self.engine = engine
-        self.scheduler = scheduler
         self.micro_batch = micro_batch
         self._ctx = ctx
         self.include_tail = include_tail
         self.on_generate = on_generate
         self.on_complete = on_complete
-        self.reform = reform
-        # the wave former: the scheduler policy when given (its
-        # reform_wave hook), else the base EDF/tenant-aware default
-        self._former = scheduler if scheduler is not None \
-            else SchedulerPolicy()
+        self._former = scheduler if scheduler is not None else _BASE_FORMER
         # rewrite noise is keyed by (seed, request, round, sub-query), so
         # a request's retrieval queries do not depend on which replica
         # served it or in what order
         self._rewrite_seed = engine.cfg.seed + 1
         self._now = 0.0                      # drained clock across run()s
         self._seq = itertools.count()
-        self._gid = itertools.count()
         self._wid = itertools.count()
         self._heap: List[Tuple[float, int, str, tuple]] = []
         self._pending: List[RequestRecord] = []
@@ -345,14 +314,6 @@ class RetrievalRuntime:
         """This runtime's lane in the shared recorder (the engine's
         replica id; -1 for a standalone engine)."""
         return self.engine.replica_id
-
-    @property
-    def event_log(self) -> List[Tuple[float, str, int]]:
-        """Legacy view of the request-lifecycle stream: ``(t, label,
-        request_id)`` tuples in emission order, exactly what the old
-        ad-hoc list recorded.  The typed events are the source of
-        truth; this is a compatibility shim."""
-        return self.recorder.legacy_tuples(self.replica_id)
 
     def _emit_req(self, t: float, label: str, rec: RequestRecord, *,
                   round_index: int = -1, wave_id: int = -1) -> None:
@@ -415,7 +376,7 @@ class RetrievalRuntime:
 
     def begin(self, *, rebase: bool = True) -> None:
         """Seed admit events for everything submitted since the last
-        wave.  ``rebase=True`` (the legacy ``run()`` path) offsets the
+        wave.  ``rebase=True`` (the ``run()`` path) offsets the
         pending arrival times by the current clock; ``rebase=False``
         treats them as *absolute* event-clock times — the
         ``TeleRAGServer`` dispatches on one shared global clock and has
@@ -469,8 +430,6 @@ class RetrievalRuntime:
         self.recorder.tick(self._now)
         if kind == "admit":
             self._on_admit(t)
-        elif kind == "round":
-            self._on_round(*payload, now=t)
         elif kind == "frontier":
             self._on_frontier(*payload, now=t)
         elif kind == "ready":
@@ -503,73 +462,28 @@ class RetrievalRuntime:
         return self.collect()
 
     # ---- admission of arrivals ---------------------------------------------
-    def _admit_record(self, m: RequestRecord, now: float) -> None:
-        """Common per-request admission bookkeeping (both modes)."""
-        m.admit_t = now
-        m.state = RequestState.ADMITTED
-        m.plan = round_plan(m.trace)
-        m.cur_q = np.array(m.q, copy=True)
-        m.next_round = 0
-        m.ready_t = now
-        m.round_start = [now] + [float("nan")] * max(0, len(m.plan) - 1)
-        self._span(m, "admit", now, now)
-        self._emit_req(now, "admit", m)
-
     def _on_admit(self, now: float) -> None:
+        """Per-request admission: every arrival is individually ready
+        and joins whatever wave the next frontier forms (mid-stream
+        admission into an in-flight replica is the normal path —
+        decode-only requests included)."""
         ready = [r for r in self._pending if r.arrival_t <= now + 1e-12]
         if not ready:
             return
         self._pending = [r for r in self._pending if r not in ready]
-        if self.reform:
-            # per-request admission: every arrival is individually ready
-            # and joins whatever wave the next frontier forms (mid-stream
-            # admission into an in-flight replica is the normal path —
-            # decode-only requests included)
-            for m in ready:
-                self._admit_record(m, now)
-            self._ready.extend(ready)
-            self._push(now, "frontier", (False,))
-            return
-        # never-re-form mode: the admission group IS the wave for every
-        # round (legacy semantics, pinned equivalent)
-        q = np.stack([r.q for r in ready])
-        if self.scheduler is None:
-            groups_idx = [list(range(len(ready)))]
-        else:
-            groups_idx = self.scheduler.group(
-                q, self.micro_batch or len(ready))
-        for gi in groups_idx:
-            members = [ready[i] for i in gi]
-            for m in members:
-                self._admit_record(m, now)
-            # decode-only traces ride the normal per-request path as
-            # tail-only singleton waves (no special-case completion)
-            with_rounds = [m for m in members if m.plan]
-            for m in members:
-                if not m.plan:
-                    self._exec_wave(
-                        _Wave(wid=next(self._wid), t=now, members=[m],
-                              rounds=[0], tenant=m.tenant),
-                        now=now, starts=[now])
-            if with_rounds:
-                g = _Cohort(gid=next(self._gid), members=with_rounds)
-                g.scheduled_rounds.add(0)
-                self._push(now, "round", (g, 0))
+        for m in ready:
+            m.admit_t = now
+            m.state = RequestState.ADMITTED
+            m.plan = round_plan(m.trace)
+            m.cur_q = np.array(m.q, copy=True)
+            m.next_round = 0
+            m.ready_t = now
+            self._span(m, "admit", now, now)
+            self._emit_req(now, "admit", m)
+        self._ready.extend(ready)
+        self._push(now, "frontier", (False,))
 
     # ---- frontiers ---------------------------------------------------------
-    def _on_round(self, g: _Cohort, rnd: int, force: bool = False, *,
-                  now: float) -> None:
-        """Never-re-form frontier: the cohort's active members execute
-        round ``rnd`` as one wave, each from its own round start."""
-        members = [m for m in g.members if rnd < len(m.plan)]
-        if not members:
-            return
-        wave = _Wave(wid=next(self._wid), t=now, members=members,
-                     rounds=[rnd] * len(members), tenant=members[0].tenant)
-        self._exec_wave(wave, now=now,
-                        starts=[m.round_start[rnd] for m in members],
-                        force=force, cohort=g)
-
     def _on_frontier(self, force: bool = False, *, now: float) -> None:
         """Dynamic round frontier: re-batch whichever requests are ready
         *now* into fresh waves (the former orders/partitions; members a
@@ -593,8 +507,7 @@ class RetrievalRuntime:
             wave = _Wave(wid=next(self._wid), t=now, members=members,
                          rounds=[m.next_round for m in members],
                          tenant=members[0].tenant)
-            self._exec_wave(wave, now=now, starts=[now] * len(members),
-                            force=force)
+            self._exec_wave(wave, now=now, force=force)
         self._ready.extend(r for i, r in enumerate(ready)
                            if i not in placed)
 
@@ -610,15 +523,13 @@ class RetrievalRuntime:
 
     # ---- the wave executor -------------------------------------------------
     @staticmethod
-    def _member_cluster_sets(plan, n_members: int, *, wave_level: bool,
+    def _member_cluster_sets(plan, n_members: int,
                              ) -> Tuple[List[List[int]], List[List[int]]]:
-        """Per-member (resident-hit, fetch) cluster lists for pinning.
-        ``wave_level=True`` (never-re-form mode) gives every member the
-        wave's full sets — the legacy release timing, where a shared
-        working set frees only when the LAST group member completes.
-        Otherwise each member gets the clusters its own ranked row
-        contributed, so its exclusive pages free at its own completion."""
-        if wave_level or plan.ranked is None:
+        """Per-member (resident-hit, fetch) cluster lists for pinning:
+        each member gets the clusters its own ranked row contributed, so
+        its exclusive pages free at its own completion (every member
+        gets the wave's full sets when the plan carries no ranking)."""
+        if plan.ranked is None:
             return ([list(plan.resident_hits)] * n_members,
                     [list(plan.fetch)] * n_members)
         hits_all = set(map(int, plan.resident_hits))
@@ -631,13 +542,11 @@ class RetrievalRuntime:
         return hit_sets, fetch_sets
 
     def _exec_wave(self, wave: _Wave, *, now: float,
-                   starts: Sequence[float], force: bool = False,
-                   cohort: Optional[_Cohort] = None) -> None:
+                   force: bool = False) -> None:
         """``_run_wave`` inside the wave's ``telerag.wave`` host span."""
         with self.recorder.span("telerag.wave", replica=self.replica_id,
                                 wave=wave.wid):
-            self._run_wave(wave, now=now, starts=starts, force=force,
-                           cohort=cohort)
+            self._run_wave(wave, now=now, force=force)
 
     def _host_done(self, reqs: Sequence[RequestRecord]) -> None:
         """Stamp the end of a call that worked for ``reqs`` on the host
@@ -649,15 +558,11 @@ class RetrievalRuntime:
                     r.host.done_s = t
 
     def _run_wave(self, wave: _Wave, *, now: float,
-                  starts: Sequence[float], force: bool = False,
-                  cohort: Optional[_Cohort] = None) -> None:
+                  force: bool = False) -> None:
         """Execute one wave's round frontier: reserve the wave's pool
         headroom (or park its members ``PRESSURE_STALLED``), run the
         engine data ops for the whole wave, and schedule each member's
-        per-request events from its own round start.  ``starts`` is the
-        per-member round start (== ``now`` for dynamically formed
-        waves; the member's own round clock in never-re-form mode,
-        where a cohort frontier fires at its earliest finisher)."""
+        per-request events from the frontier time ``now``."""
         eng = self.engine
         policy = eng.policy
         members, rounds = wave.members, wave.rounds
@@ -707,13 +612,11 @@ class RetrievalRuntime:
         if ret and policy.prefetches and not demoted:
             plan = eng.plan_lookahead(act_q, [gen_tokens[j] for j in ret],
                                       wave_key=keys)
-            # per-request working sets: in reform mode each member pins
-            # only the clusters ITS OWN ranked row needs, so a finished
-            # request's exclusive pages free immediately instead of
-            # waiting for the whole wave (never-re-form mode keeps
-            # wave-level sets — the legacy group release timing)
-            hit_sets, fetch_sets = self._member_cluster_sets(
-                plan, len(ret), wave_level=cohort is not None)
+            # per-request working sets: each member pins only the
+            # clusters ITS OWN ranked row needs, so a finished request's
+            # exclusive pages free immediately instead of waiting for
+            # the whole wave
+            hit_sets, fetch_sets = self._member_cluster_sets(plan, len(ret))
             # pin the plan's resident hits BEFORE admission: the spill
             # that makes room for this wave's reservation must not evict
             # the clusters the plan counts on finding on-device
@@ -736,9 +639,8 @@ class RetrievalRuntime:
                 # the plan is recomputed from scratch on resume anyway
                 for m, pins in zip(keys, hit_pins):
                     eng.buffer.release_pins(m, pins)
-                eng.admission.park(
-                    (cohort, rounds[0]) if cohort is not None else wave,
-                    plan.pages_planned, tenant=wave.tenant)
+                eng.admission.park(wave, plan.pages_planned,
+                                   tenant=wave.tenant)
                 for j in ret:
                     req = members[j]
                     req.state = RequestState.PRESSURE_STALLED
@@ -746,7 +648,7 @@ class RetrievalRuntime:
                                    wave_id=wave.wid)
                 # decode-only wave-mates need no pool pages: they must
                 # not be swallowed by the park — run them as their own
-                # wave right now (only dynamic waves mix tail members)
+                # wave right now
                 tails = [j for j in range(batch) if j not in set(ret)]
                 if tails:
                     self._exec_wave(
@@ -754,7 +656,7 @@ class RetrievalRuntime:
                               members=[members[j] for j in tails],
                               rounds=[rounds[j] for j in tails],
                               tenant=wave.tenant),
-                        now=now, starts=[starts[j] for j in tails])
+                        now=now)
                 return
 
         # the wave is logged only once it actually executes — a parked
@@ -807,13 +709,8 @@ class RetrievalRuntime:
                     evs = self._generate_with_kv_relief(
                         members, gen_tokens, rounds[0], tenant=wave.tenant)
                 except PoolExhausted:
-                    if cohort is not None:
-                        # never-re-form mode: cohorts cannot split or
-                        # dissolve, so pressure cannot shed or park
-                        raise
                     self._shed_on_kv_pressure(
-                        wave, keys, hit_pins, fetch_pins, ticket,
-                        now=now, starts=starts)
+                        wave, keys, hit_pins, fetch_pins, ticket, now=now)
                     return
                 self._host_done(members)
                 if evs is not None:
@@ -871,10 +768,9 @@ class RetrievalRuntime:
         # 4) per-request telemetry + event-clock scheduling
         t_transfer = nbytes / eng.cfg.hw.host_link_bw
         mean_pages = float(np.mean(eng.index.paged.cluster_num_pages))
-        continuing: List[float] = []
         wave_end = now
         for j in range(batch):
-            req, rnd, rs = members[j], rounds[j], starts[j]
+            req, rnd = members[j], rounds[j]
             win = eng.llm_window_seconds(gen_tokens[j], batch)
             if decode_evs is not None and decode_evs[j].tokens > 0:
                 # an event with no observed steps (the hook had nothing
@@ -882,7 +778,7 @@ class RetrievalRuntime:
                 win = decode_evs[j].window(gen_tokens[j])
             if decode_evs is not None:
                 self.recorder.emit(DecodeStep(
-                    t=rs, kind="decode", replica=self.replica_id,
+                    t=now, kind="decode", replica=self.replica_id,
                     request_id=req.request_id, tenant=req.tenant,
                     wave_id=wave.wid, tokens=decode_evs[j].tokens,
                     seconds=decode_evs[j].seconds, batch=batch))
@@ -891,11 +787,11 @@ class RetrievalRuntime:
                 # generation window, then completion — the same wave
                 # machinery, no special-case branch
                 if win > 0:
-                    self._span(req, "generate_tail", rs, rs + win,
+                    self._span(req, "generate_tail", now, now + win,
                                wave_id=wave.wid)
-                    self._push(rs, "mark", (req, RequestState.GENERATING,
-                                            "generate"))
-                req.complete_t = rs + win
+                    self._push(now, "mark", (req, RequestState.GENERATING,
+                                             "generate"))
+                req.complete_t = now + win
                 self._span(req, "complete", req.complete_t,
                            req.complete_t)
                 self._push(req.complete_t, "mark",
@@ -915,27 +811,27 @@ class RetrievalRuntime:
                 t_dev_search=eng._dev_search_seconds(
                     int(hits * mean_pages)),
                 t_merge=2e-5,
-                wave_id=wave.wid, round_start_t=rs)
+                wave_id=wave.wid, round_start_t=now)
             req.result.rounds.append(rt)
             req.result.doc_ids.extend(res.doc_ids[r] for r in rows)
 
-            gen_end = rs + rt.t_llm_window
+            gen_end = now + rt.t_llm_window
             ready = None
             if policy.prefetches and ev is not None:
-                ready = eng.transfer.ready_t(ev, rs)
+                ready = eng.transfer.ready_t(ev, now)
             retrieve_start = (gen_end if ready is None
                               else max(gen_end, ready))
             round_end = retrieve_start + policy.search_seconds(rt, self.ctx)
             rt.round_end_t = round_end
 
             if policy.prefetches and not demoted:
-                self._span(req, "prefetch_dispatch", rs, rs, rnd,
+                self._span(req, "prefetch_dispatch", now, now, rnd,
                            wave_id=wave.wid)
-                self._push(rs, "mark",
+                self._push(now, "mark",
                            (req, RequestState.PREFETCHING, "prefetch"))
-            self._span(req, "generate", rs, gen_end, rnd,
+            self._span(req, "generate", now, gen_end, rnd,
                        wave_id=wave.wid)
-            self._push(rs, "mark", (req, RequestState.GENERATING, "generate"))
+            self._push(now, "mark", (req, RequestState.GENERATING, "generate"))
             if retrieve_start > gen_end:
                 self._span(req, "transfer_wait", gen_end, retrieve_start,
                            rnd, wave_id=wave.wid)
@@ -947,12 +843,8 @@ class RetrievalRuntime:
 
             req.next_round = rnd + 1
             if rnd + 1 < len(req.plan):
-                req.round_start[rnd + 1] = round_end
                 req.ready_t = round_end
-                if cohort is not None:
-                    continuing.append(round_end)
-                else:
-                    self._push(round_end, "ready", (req,))
+                self._push(round_end, "ready", (req,))
             else:
                 complete_t = round_end
                 if self.include_tail:
@@ -985,14 +877,6 @@ class RetrievalRuntime:
             rows = [r for r, o in enumerate(owners) if o == j]
             members[j].cur_q = q_out[rows[0]]
 
-        # 6) never-re-form mode: the cohort's earliest finisher opens the
-        #    shared next-round frontier (dynamic waves instead schedule
-        #    per-request "ready" events above)
-        if cohort is not None and continuing \
-                and (rounds[0] + 1) not in cohort.scheduled_rounds:
-            cohort.scheduled_rounds.add(rounds[0] + 1)
-            self._push(min(continuing), "round", (cohort, rounds[0] + 1))
-
     def _generate_with_kv_relief(self, members, gen_tokens, rnd: int, *,
                                  tenant: str):
         """Run the decode hook; on a *pool-bytes* shortfall
@@ -1023,8 +907,7 @@ class RetrievalRuntime:
             return self.on_generate(list(members), list(gen_tokens), rnd)
 
     def _shed_on_kv_pressure(self, wave: _Wave, keys, hit_pins, fetch_pins,
-                             ticket, *, now: float,
-                             starts: Sequence[float]) -> None:
+                             ticket, *, now: float) -> None:
         """The decode hook's ``acquire_paged`` failed at this wave's
         round frontier: the KV slab/pool cannot hold the whole batch's
         block tables.  Shed half — the older half re-executes right now
@@ -1066,7 +949,7 @@ class RetrievalRuntime:
                 _Wave(wid=next(self._wid), t=now,
                       members=wave.members[:keep],
                       rounds=wave.rounds[:keep], tenant=wave.tenant),
-                now=now, starts=list(starts[:keep]))
+                now=now)
 
     # ---- admission / memory-pressure plumbing ------------------------------
     def _on_pages_freed(self, pages: int) -> None:
@@ -1080,40 +963,27 @@ class RetrievalRuntime:
         """Wake every parked request.  The stall interval becomes a
         ``pressure_stall`` span and the round restarts from the resume
         time, so admission delay shows up in admit→complete latency.
-        Dynamically-formed waves dissolve on wake: their members rejoin
-        whatever wave the resume frontier forms (possibly alongside
-        requests admitted while they slept)."""
+        Parked waves dissolve on wake: their members rejoin whatever
+        wave the resume frontier forms (possibly alongside requests
+        admitted while they slept)."""
         woke_ready = False
-        for key, _npages in self.engine.admission.unpark_all():
-            if isinstance(key, _Wave):
-                for j, m in enumerate(key.members):
-                    # KV-parked waves wake EVERY member: their decode
-                    # (tail members included) never ran.  Admission
-                    # parks ran tail members as their own wave before
-                    # parking, so those stay skipped.
-                    if not key.kv_parked and key.rounds[j] >= len(m.plan):
-                        continue
-                    rs = m.ready_t
-                    if now > rs + 1e-15:
-                        self._span(m, "pressure_stall", rs, now,
-                                   key.rounds[j], wave_id=key.wid)
-                    m.ready_t = now
-                    m.state = RequestState.ADMITTED
-                    self._emit_req(now, "pressure_resume", m)
-                    self._ready.append(m)
-                    woke_ready = True
-            else:
-                g, rnd = key
-                for m in g.members:
-                    if rnd >= len(m.plan):
-                        continue
-                    rs = m.round_start[rnd]
-                    if now > rs + 1e-15:
-                        self._span(m, "pressure_stall", rs, now, rnd)
-                        m.round_start[rnd] = now
-                    m.state = RequestState.ADMITTED
-                    self._emit_req(now, "pressure_resume", m)
-                self._push(now, "round", (g, rnd, force))
+        for wave, _npages in self.engine.admission.unpark_all():
+            for j, m in enumerate(wave.members):
+                # KV-parked waves wake EVERY member: their decode (tail
+                # members included) never ran.  Admission parks ran tail
+                # members as their own wave before parking, so those
+                # stay skipped.
+                if not wave.kv_parked and wave.rounds[j] >= len(m.plan):
+                    continue
+                rs = m.ready_t
+                if now > rs + 1e-15:
+                    self._span(m, "pressure_stall", rs, now,
+                               wave.rounds[j], wave_id=wave.wid)
+                m.ready_t = now
+                m.state = RequestState.ADMITTED
+                self._emit_req(now, "pressure_resume", m)
+                self._ready.append(m)
+                woke_ready = True
         if woke_ready:
             self._push(now, "frontier", (force,))
 

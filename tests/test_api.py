@@ -1,36 +1,28 @@
 """Unified serving front-end: TeleRAGServer facade, typed
-request/response lifecycle, cross-replica continuous dispatch, and the
-legacy shims.
+request/response lifecycle, and cross-replica continuous dispatch.
 
-Pins the redesign's acceptance contract:
-  * simultaneous arrivals reproduce the legacy serial
-    ``run_global_batch`` drain — doc ids exactly, round telemetry to
-    1e-6 — with multiple micro-batches per replica;
+Pins the front end's contract:
+  * what a request retrieves does not depend on how it was served:
+    replicas, routing and arrival times change the waves, never the
+    doc ids;
   * staggered arrivals interleave replica work on ONE shared event
-    clock (impossible under the old one-replica-at-a-time drain);
+    clock;
   * per-request arrival→complete latency is monotone in offered load;
   * results come back in submission order everywhere;
-  * the deprecated shims warn and agree with the server.
+  * a dead replica is routed nothing, and comes back with mark_alive;
+  * the recorder holds each replica's request lifecycle on its own lane;
+  * static-group execution (``continuous=False``) is refused.
 """
-
-import warnings
 
 import numpy as np
 import pytest
 
 from repro.configs import get_arch
-from repro.core.ivf import probe
 from repro.core.schedulers import TeleRAGScheduler
-from repro.serving import (EngineConfig, GlobalBatchReport,
-                           MultiReplicaOrchestrator, PipelineExecutor,
-                           RagRequest, RequestState, TeleRAGEngine,
+from repro.serving import (EngineConfig, RagRequest, RequestState,
                            TeleRAGServer, make_traces)
 from repro.serving.trace import RequestTrace, StageTrace
 from tests.conftest import unit_queries
-
-TELEMETRY_FIELDS = ("round_index", "batch", "gen_tokens", "t_llm_window",
-                    "bytes_prefetched", "t_prefetch", "hits", "misses",
-                    "t_host_search", "t_dev_search", "t_merge")
 
 
 def _cfg(seed=5, **kw):
@@ -41,124 +33,47 @@ def _cfg(seed=5, **kw):
     return EngineConfig(**defaults)
 
 
-def _legacy_serial_global_batch(index, cfg, arch, n_replicas, q_in, traces,
-                                micro_batch):
-    """The pre-redesign ``run_global_batch``: route once, then drain one
-    replica at a time through per-replica lockstep executors.  Kept here
-    as the oracle the continuous dispatcher must reproduce for
-    simultaneous arrivals."""
-    engines = [TeleRAGEngine(index, cfg, arch) for _ in range(n_replicas)]
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        execs = [PipelineExecutor(e) for e in engines]
-    sched = TeleRAGScheduler()
-    groups = sched.group(q_in, micro_batch)
-    nprobe_sched = min(64, index.num_clusters)
-    batch_clusters = []
-    for g in groups:
-        ranked = probe(q_in[g], index, nprobe_sched)
-        batch_clusters.append(set(int(c) for r in ranked for c in r))
-    caches = [e.buffer.resident_clusters() for e in engines]
-    occupancy = [e.ledger.occupancy() for e in engines]
-    assigns = sched.assign(batch_clusters, caches, occupancy=occupancy)
-    by_id = {}
-    for a in assigns:
-        g = groups[a.batch_index]
-        res = execs[a.replica].execute_batch(q_in[g],
-                                             [traces[i] for i in g])
-        for i, r in zip(g, res):
-            by_id[traces[i].request_id] = (r, a.replica)
-    return by_id
-
-
 # ---------------------------------------------------------------------------
-# Acceptance: simultaneous arrivals == legacy serial drain, to 1e-6
+# Acceptance: served doc ids do not depend on replicas, routing or arrivals
 # ---------------------------------------------------------------------------
 
 
-def test_simultaneous_arrivals_match_legacy_serial_drain(
-        small_store, small_index, rng):
-    """12 requests, micro-batch 2, 2 replicas => 3 micro-batches per
-    replica: the continuous dispatcher serializes within each replica
-    (with end_batch between batches) while replicas interleave, so doc
-    ids and round telemetry must reproduce the legacy drain exactly."""
-    q = unit_queries(small_store, rng, 12)
-    traces = make_traces("iter", 12, seed=11)
-    legacy = _legacy_serial_global_batch(
-        small_index, _cfg(), get_arch("llama3-8b"), 2, q, traces, 2)
+@pytest.mark.parametrize("pipe", ("hyde", "iter", "irg"))
+def test_served_doc_ids_do_not_depend_on_replicas_or_arrivals(
+        small_store, small_index, rng, pipe):
+    """The same 6 requests served on one replica all at once, and on two
+    replicas behind the cache-aware router in two staggered waves, ride
+    different waves but retrieve the same doc ids in every round: the
+    rewrite noise is keyed by request and round, and hybrid search is
+    exact whatever is resident."""
+    q = unit_queries(small_store, rng, 6)
+    traces = make_traces(pipe, 6, seed=11)
 
-    srv = TeleRAGServer(small_index, _cfg(), 2, get_arch("llama3-8b"),
+    one = TeleRAGServer(small_index, _cfg(), 1, get_arch("llama3-8b"))
+    at_once = one.serve([RagRequest(q=q[i], trace=traces[i])
+                         for i in range(6)])
+    two = TeleRAGServer(small_index, _cfg(), 2, get_arch("llama3-8b"),
                         scheduler=TeleRAGScheduler(), micro_batch=2)
-    resp = srv.serve([RagRequest(q=q[i], trace=traces[i])
-                      for i in range(12)])
-    assert len(resp) == 12
-    assert {r.replica for r in resp} == {0, 1}
-    per_replica_batches = {}
-    for r in resp:
-        per_replica_batches.setdefault(r.replica, set()).add(r.admit_t)
-    assert max(len(v) for v in per_replica_batches.values()) >= 3
-
-    for r in resp:
-        ref, ref_replica = legacy[r.request_id]
-        assert r.replica == ref_replica
-        assert len(r.doc_ids) == len(ref.doc_ids)
-        for got, want in zip(r.doc_ids, ref.doc_ids):
+    staggered = two.serve([RagRequest(q=q[i], trace=traces[i],
+                                      arrival_t=0.003 * (i // 3))
+                           for i in range(6)])
+    assert {r.replica for r in at_once} == {0}
+    assert {r.replica for r in staggered} == {0, 1}
+    assert {r.rounds[0].batch for r in at_once} == {6}
+    assert max(r.rounds[0].batch for r in staggered) <= 2
+    for a, b in zip(at_once, staggered):
+        assert a.request_id == b.request_id
+        assert a.state == b.state == RequestState.COMPLETE
+        assert len(a.rounds) == len(b.rounds)
+        assert len(a.doc_ids) == len(b.doc_ids) > 0
+        for got, want in zip(b.doc_ids, a.doc_ids):
             np.testing.assert_array_equal(got, want)
-        assert len(r.rounds) == len(ref.rounds)
-        for got, want in zip(r.rounds, ref.rounds):
-            for f in TELEMETRY_FIELDS:
-                assert getattr(got, f) == pytest.approx(getattr(want, f),
-                                                        abs=1e-6), f
 
 
-def test_run_global_batch_shim_matches_server_and_warns(
-        small_store, small_index, rng):
-    q = unit_queries(small_store, rng, 8)
-    traces = make_traces("hyde", 8, seed=3)
-    srv = TeleRAGServer(small_index, _cfg(seed=2), 2, get_arch("llama3-8b"),
-                        scheduler=TeleRAGScheduler(), micro_batch=4)
-    resp = srv.serve([RagRequest(q=q[i], trace=traces[i]) for i in range(8)])
-
-    orch = MultiReplicaOrchestrator(small_index, _cfg(seed=2), 2,
-                                    get_arch("llama3-8b"))
-    with pytest.warns(DeprecationWarning):
-        rep = orch.run_global_batch(q, make_traces("hyde", 8, seed=3),
-                                    micro_batch=4)
-    results = rep.all_results()
-    assert [r.request_id for r in results] == [r.request_id for r in resp]
-    for a, b in zip(resp, results):
-        for got, want in zip(a.doc_ids, b.doc_ids):
-            np.testing.assert_array_equal(got, want)
-        for got, want in zip(a.rounds, b.rounds):
-            for f in TELEMETRY_FIELDS:
-                assert getattr(got, f) == pytest.approx(getattr(want, f),
-                                                        abs=1e-6), f
-    # report bookkeeping survives the shim translation
-    assert sorted(a[0] for a in rep.assignments) == [0, 1]
-    assert rep.schedule_overhead_s >= 0
-    assert len(rep.records) == 8
-
-
-def test_shim_respects_server_level_mark_dead(small_store, small_index,
-                                              rng):
-    """A replica mark_dead()ed on the server stays dead through the
-    legacy shim even when the call passes no dead_replicas."""
-    q = unit_queries(small_store, rng, 8)
-    orch = MultiReplicaOrchestrator(small_index, _cfg(seed=6), 2,
-                                    get_arch("llama3-8b"))
-    orch.server.mark_dead(1)
-    with pytest.warns(DeprecationWarning):
-        rep = orch.run_global_batch(q, make_traces("hyde", 8, seed=5),
-                                    micro_batch=4)
-    assert all(a[1] != 1 for a in rep.assignments)
-    assert len(rep.all_results()) == 8
-    assert orch.server.dead == {1}          # per-call state restored
-
-
-def test_pipeline_executor_is_deprecated(small_index):
-    eng = TeleRAGEngine(small_index, _cfg(), get_arch("llama3-8b"))
-    with pytest.warns(DeprecationWarning):
-        PipelineExecutor(eng)
+def test_static_group_execution_is_refused(small_index):
+    with pytest.raises(ValueError, match="static-group execution"):
+        TeleRAGServer(small_index, _cfg(), 1, get_arch("llama3-8b"),
+                      continuous=False)
 
 
 # ---------------------------------------------------------------------------
@@ -178,9 +93,7 @@ def test_staggered_arrivals_interleave_replicas_on_shared_clock(
         small_store, small_index):
     """Wave B arrives mid-way through wave A's generation window and
     runs on the OTHER replica: B's spans must lie inside A's — overlap
-    on the shared clock that the old serial drain could never express
-    (it admitted every wave of a call at its own replica-local zero and
-    blocked between calls)."""
+    on the shared clock."""
     cfg = _cfg(seed=7, buffer_pages=512, cache_enabled=False)
     srv = TeleRAGServer(small_index, cfg, 2, get_arch("llama3-8b"))
     cents = small_index.centroids / np.linalg.norm(
@@ -240,24 +153,14 @@ def test_latency_monotone_in_offered_load(small_store, small_index, rng):
 # ---------------------------------------------------------------------------
 
 
-def test_global_batch_report_all_results_submission_order():
-    """all_results() must not depend on replica-dict iteration order."""
-    from repro.serving import RequestResult
-    r = {i: RequestResult(i, "hyde") for i in range(4)}
-    rep = GlobalBatchReport(
-        per_replica_results={1: [r[2], r[0]], 0: [r[3], r[1]]},
-        schedule_overhead_s=0.0, assignments=[],
-        submission_ids=[0, 1, 2, 3])
-    assert [x.request_id for x in rep.all_results()] == [0, 1, 2, 3]
-
-
+@pytest.mark.parametrize("replicas", (1, 2, 3))
 def test_server_drain_returns_submission_order(small_store, small_index,
-                                               rng):
+                                               rng, replicas):
     """Later-submitted requests can arrive (and finish) earlier; the
     drain still answers in submission order."""
     q = unit_queries(small_store, rng, 4)
     traces = make_traces("hyde", 4, seed=13)
-    srv = TeleRAGServer(small_index, _cfg(cache_enabled=False), 2,
+    srv = TeleRAGServer(small_index, _cfg(cache_enabled=False), replicas,
                         get_arch("llama3-8b"), micro_batch=1)
     # reverse arrival order: request 0 arrives last
     resp = srv.serve([RagRequest(q=q[i], trace=traces[i],
@@ -265,6 +168,66 @@ def test_server_drain_returns_submission_order(small_store, small_index,
                       for i in range(4)])
     assert [r.request_id for r in resp] == [t.request_id for t in traces]
     assert resp[3].complete_t < resp[0].complete_t
+
+
+# ---------------------------------------------------------------------------
+# Replica health and the per-replica event stream
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("replicas,dead", ((2, 0), (2, 1), (3, 1)))
+def test_dead_replica_is_routed_nothing_until_marked_alive(
+        small_store, small_index, rng, replicas, dead):
+    """No micro-batch is routed to, and no wave runs on, a replica
+    marked dead: its share re-routes to the live ones and every request
+    completes.  After ``mark_alive`` the router uses it again."""
+    q = unit_queries(small_store, rng, 8)
+    srv = TeleRAGServer(small_index, _cfg(), replicas,
+                        get_arch("llama3-8b"), micro_batch=2)
+    srv.mark_dead(dead)
+    resp = srv.serve([RagRequest(q=q[i], trace=t) for i, t in
+                      enumerate(make_traces("iter", 8, seed=29))])
+    assert all(r.state == RequestState.COMPLETE for r in resp)
+    assert {r.replica for r in resp} == set(range(replicas)) - {dead}
+    assert srv.wave_log[0].requeued
+    assert all(a[1] != dead for w in srv.wave_log for a in w.assignments)
+    assert not srv.runtimes[dead].wave_log
+
+    srv.mark_alive(dead)
+    resp = srv.serve([RagRequest(q=q[i], trace=t) for i, t in
+                      enumerate(make_traces("hyde", 8, seed=30))])
+    assert all(r.state == RequestState.COMPLETE for r in resp)
+    assert dead in {r.replica for r in resp}
+    assert srv.runtimes[dead].wave_log
+
+
+@pytest.mark.parametrize("replicas", (1, 2, 3))
+def test_each_replica_lane_admits_and_completes_its_requests_once(
+        small_store, small_index, rng, replicas):
+    """In the server's one recorder, each replica's request events carry
+    that replica's id, are stamped in non-decreasing event-clock time,
+    and admit and complete every request the replica served exactly
+    once; the server's own ``submit`` marks sit on lane -1."""
+    q = unit_queries(small_store, rng, 6)
+    traces = make_traces("iter", 6, seed=31)
+    srv = TeleRAGServer(small_index, _cfg(), replicas,
+                        get_arch("llama3-8b"), micro_batch=2)
+    resp = srv.serve([RagRequest(q=q[i], trace=traces[i],
+                                 arrival_t=0.002 * i) for i in range(6)])
+    assert {r.replica for r in resp} == set(range(replicas))
+    events = srv.recorder.of("request")
+    for rep in range(replicas):
+        served = sorted(r.request_id for r in resp if r.replica == rep)
+        lane = [e for e in events if e.replica == rep]
+        times = [e.t for e in lane]
+        assert times == sorted(times)
+        for label in ("admit", "complete"):
+            assert sorted(e.request_id for e in lane
+                          if e.label == label) == served
+    assert {e.replica for e in events} == set(range(-1, replicas))
+    assert sorted(e.request_id for e in events if e.replica == -1) \
+        == sorted(t.request_id for t in traces)
+    assert all(e.label == "submit" for e in events if e.replica == -1)
 
 
 # ---------------------------------------------------------------------------

@@ -701,8 +701,7 @@ def _serve_chunk(small_index, q, traces, *, params, store, micro_batch=3,
         nprobe=8, top_k=3, buffer_pages=256, pool_pages=4096,
         lookahead_rank=16, kernel_mode="ref", chips=8, seed=7,
         paged_decode=True, chunk_kv=store is not None), 1, ARCH,
-        micro_batch=micro_batch, include_tail=True, decode_hook=runner,
-        continuous=True)
+        micro_batch=micro_batch, include_tail=True, decode_hook=runner)
     runner.attach(srv)
     resp = srv.serve([RagRequest(q=q[i], trace=traces[i], arrival_t=0.0)
                       for i in range(n)])

@@ -1,7 +1,7 @@
 """Per-request continuous batching: dynamic wave forming, straggler
 isolation, mid-stream admission, pressure-park rejoin, decode events
-driving the clock, decode-only requests, per-tenant KV accounting, and
-the never-re-form mode's equivalence to the legacy group path."""
+driving the clock, decode-only requests, per-tenant KV accounting,
+and KV-pressure shedding."""
 
 import numpy as np
 import pytest
@@ -37,14 +37,14 @@ def _two_round_trace(request_id, gen0, gen1=64, sigma=0.0):
 
 
 # ---------------------------------------------------------------------------
-# Acceptance: straggler isolation — a slow batch-mate no longer drags
-# the fast request's next round (impossible under static groups)
+# Acceptance: straggler isolation — a slow batch-mate never drags the
+# fast request's next round
 # ---------------------------------------------------------------------------
 
 
-def _run_straggler(small_index, q, *, reform):
+def _run_straggler(small_index, q):
     eng = _engine(small_index)
-    runtime = RetrievalRuntime(eng, reform=reform)
+    runtime = RetrievalRuntime(eng)
     slow = runtime.submit(q[0], _two_round_trace(0, gen0=4000))
     fast = runtime.submit(q[1], _two_round_trace(1, gen0=64))
     runtime.run()
@@ -55,12 +55,10 @@ def _run_straggler(small_index, q, *, reform):
 def test_straggler_isolation_fast_request_reforms_alone(
         small_store, small_index, rng):
     """Request B (fast) starts — and here even finishes — its round 1
-    before slow batch-mate A finishes round 0.  Under static groups the
-    round-1 frontier is one shared event executing BOTH members as one
-    batch; under wave re-forming B's round 1 runs in a wave of its own
-    the moment B is ready."""
+    before slow batch-mate A finishes round 0: wave re-forming runs B's
+    round 1 in a wave of its own the moment B is ready."""
     q = unit_queries(small_store, rng, 2)
-    eng, runtime, slow, fast = _run_straggler(small_index, q, reform=True)
+    eng, runtime, slow, fast = _run_straggler(small_index, q)
 
     slow_r0_end = slow.result.rounds[0].round_end_t
     fast_r1 = fast.result.rounds[1]
@@ -78,13 +76,6 @@ def test_straggler_isolation_fast_request_reforms_alone(
     assert slow.result.rounds[1].wave_id != fast_r1.wave_id
     assert slow.result.rounds[1].round_start_t > fast_r1.round_start_t
 
-    # contrast: the never-re-form mode keeps B batched with A for every
-    # round (the legacy group semantics the shims are pinned to)
-    _, _, slow_s, fast_s = _run_straggler(small_index, q, reform=False)
-    assert fast_s.result.rounds[1].batch == 2
-    # re-forming can only help the fast request (smaller decode batch)
-    assert fast.complete_t <= fast_s.complete_t + 1e-12
-
 
 # ---------------------------------------------------------------------------
 # Acceptance: mid-stream admission joins an in-flight wave
@@ -95,7 +86,7 @@ def test_midstream_admission_joins_inflight_wave(small_store, small_index,
                                                  rng):
     """A request arriving exactly at a round frontier is wave-formed
     WITH the in-flight requests' next rounds — mixed round indices in
-    one wave, which no static-group executor can express."""
+    one wave."""
     q = unit_queries(small_store, rng, 3)
     # probe run: find the (deterministic) round-1 frontier time
     eng = _engine(small_index)
@@ -166,40 +157,6 @@ def test_parked_request_rejoins_wave_on_wake(small_store, small_index, rng):
         w = next(w for w in runtime.wave_log if w.wid == rt0.wave_id)
         assert w.t == pytest.approx(resume)
         assert r.request_id in w.request_ids
-
-
-# ---------------------------------------------------------------------------
-# Acceptance: never-re-form == dynamic former on single-wave workloads
-# (the degenerate mode really is the same executor)
-# ---------------------------------------------------------------------------
-
-
-def test_never_reform_and_dynamic_agree_on_single_round_waves(
-        small_store, small_index, rng):
-    """For simultaneous single-round requests the dynamic former forms
-    exactly the admission group, so both modes must produce identical
-    doc ids and round telemetry — the degenerate mode is a special case
-    of one executor, not a second code path."""
-    q = unit_queries(small_store, rng, 4)
-    results = []
-    for reform in (False, True):
-        eng = _engine(small_index)
-        runtime = RetrievalRuntime(eng, reform=reform)
-        traces = make_traces("hyde", 4, seed=11)
-        recs = [runtime.submit(q[i], traces[i]) for i in range(4)]
-        runtime.run()
-        results.append(recs)
-    legacy, dynamic = results
-    for a, b in zip(legacy, dynamic):
-        assert len(a.result.doc_ids) == len(b.result.doc_ids)
-        for da, db in zip(a.result.doc_ids, b.result.doc_ids):
-            np.testing.assert_array_equal(da, db)
-        for ra, rb in zip(a.result.rounds, b.result.rounds):
-            for f in ("batch", "gen_tokens", "t_llm_window", "hits",
-                      "misses", "t_prefetch", "t_host_search"):
-                assert getattr(ra, f) == pytest.approx(getattr(rb, f),
-                                                       abs=1e-9), f
-        assert a.latency == pytest.approx(b.latency, abs=1e-9)
 
 
 # ---------------------------------------------------------------------------
@@ -282,16 +239,15 @@ def _decode_only_trace(request_id, gen=64):
                         rewrite_sigma=0.0)
 
 
-@pytest.mark.parametrize("reform", (False, True))
 def test_decode_only_requests_complete_with_their_window(
-        small_store, small_index, rng, reform):
+        small_store, small_index, rng):
     """A trace with zero retrieval rounds is a decode-only request on
     the normal path (no special-case admit branch): it completes after
     its generation window (include_tail) instead of instantaneously,
-    and under wave forming it joins the decode batch like anyone."""
+    and it joins the decode batch like anyone."""
     q = unit_queries(small_store, rng, 2)
     eng = _engine(small_index)
-    runtime = RetrievalRuntime(eng, include_tail=True, reform=reform)
+    runtime = RetrievalRuntime(eng, include_tail=True)
     normal = runtime.submit(q[0], make_traces("hyde", 1, seed=3)[0])
     dec = runtime.submit(q[1], _decode_only_trace(99))
     runtime.run()
@@ -302,10 +258,9 @@ def test_decode_only_requests_complete_with_their_window(
     assert dec.complete_t == pytest.approx(tail[0].end)
     assert dec.complete_t > dec.admit_t
     assert normal.state == RequestState.COMPLETE
-    if reform:
-        # it was wave-formed together with the normal request
-        w = runtime.wave_log[0]
-        assert sorted(w.request_ids) == sorted([normal.request_id, 99])
+    # it was wave-formed together with the normal request
+    w = runtime.wave_log[0]
+    assert sorted(w.request_ids) == sorted([normal.request_id, 99])
 
 
 def test_decode_only_wavemate_survives_a_pressure_park(small_store,
@@ -421,7 +376,7 @@ def test_continuous_server_forwards_scheduler_as_wave_former(
 
     q = unit_queries(small_store, rng, 4)
     srv = TeleRAGServer(small_index, _cfg(), 1, get_arch("llama3-8b"),
-                        scheduler=Spy(), micro_batch=2, continuous=True)
+                        scheduler=Spy(), micro_batch=2)
     resp = srv.serve([RagRequest(q=q[i], pipeline="hyde")
                       for i in range(4)])
     assert all(r.state == RequestState.COMPLETE for r in resp)
@@ -481,7 +436,7 @@ def test_server_telemetry_surfaces_tenant_kv_bytes(small_store, small_index,
         small_index,
         _cfg(buffer_pages=64, pool_pages=2048,
              tenant_shares={"a": (8, None)}),
-        1, arch, decode_hook=decode_hook, continuous=True)
+        1, arch, decode_hook=decode_hook)
     resp = srv.serve([RagRequest(q=q[i], pipeline="hyde", tenant="a")
                       for i in range(2)])
     assert all(r.state == RequestState.COMPLETE for r in resp)
@@ -506,8 +461,7 @@ def test_continuous_server_completes_all_and_counts_per_request(
     traces = (make_traces("hyde", 4, seed=3)
               + make_traces("iter", 4, seed=4))
     srv = TeleRAGServer(small_index, _cfg(seed=3), 2,
-                        get_arch("llama3-8b"), micro_batch=2,
-                        continuous=True)
+                        get_arch("llama3-8b"), micro_batch=2)
     resp = srv.serve([RagRequest(q=q[i], trace=traces[i],
                                  arrival_t=0.005 * (i % 3))
                       for i in range(8)])
@@ -516,30 +470,6 @@ def test_continuous_server_completes_all_and_counts_per_request(
     tele = srv.telemetry()
     assert tele.completed == 8
     assert tele.dispatched_batches >= 2
-
-
-def test_continuous_mode_mean_latency_no_worse_than_static(
-        small_store, small_index, rng):
-    """Same workload through both disciplines: per-request waves never
-    queue behind a busy replica and decode at their true batch size, so
-    mean arrival→complete latency must not regress.  (The pool is sized
-    so lookahead admission is not the binding constraint — under a
-    saturated pool the admission controller serializes waves and the
-    comparison measures memory pressure, not batching discipline.)"""
-    q = unit_queries(small_store, rng, 6)
-    means = {}
-    for continuous in (False, True):
-        srv = TeleRAGServer(small_index,
-                            _cfg(seed=9, cache_enabled=False,
-                                 buffer_pages=512), 1,
-                            get_arch("llama3-8b"), micro_batch=2,
-                            continuous=continuous)
-        traces = make_traces("iter", 6, seed=13)
-        resp = srv.serve([RagRequest(q=q[i], trace=traces[i])
-                          for i in range(6)])
-        assert all(r.state == RequestState.COMPLETE for r in resp)
-        means[continuous] = float(np.mean([r.latency_s for r in resp]))
-    assert means[True] <= means[False] * (1 + 1e-9)
 
 
 # ---------------------------------------------------------------------------
@@ -695,24 +625,5 @@ def test_kv_exhaustion_on_singleton_wave_still_raises(small_store,
     runtime = RetrievalRuntime(eng, on_generate=hook)
     runtime.submit(unit_queries(small_store, rng, 1)[0],
                    make_traces("hyde", 1, seed=5)[0])
-    with pytest.raises(PoolExhausted):
-        runtime.run()
-
-
-def test_kv_exhaustion_in_never_reform_mode_still_raises(small_store,
-                                                         small_index, rng):
-    """Never-re-form cohorts cannot split, so the shed/park path must
-    not engage: the exception propagates exactly as before."""
-    from repro.memory.pool import PoolExhausted
-
-    eng = _engine(small_index, pool_pages=4096)
-    kv = KVCacheManager(get_arch("llama3-8b").reduced(), pool=eng.pool)
-    kv.init_paged(num_pages=8, page_size=8)     # two seqs; the wave is 4
-    hook, _calls = _paged_hook(kv)
-    runtime = RetrievalRuntime(eng, reform=False, on_generate=hook)
-    q = unit_queries(small_store, rng, 4)
-    traces = make_traces("hyde", 4, seed=5)
-    for i in range(4):
-        runtime.submit(q[i], traces[i])
     with pytest.raises(PoolExhausted):
         runtime.run()

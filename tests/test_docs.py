@@ -35,7 +35,7 @@ def test_every_public_export_has_a_docstring():
     missing = []
     for mod_name, name, obj in _exported_objects():
         if isinstance(obj, (str, tuple, dict, list, int, float)):
-            continue                       # constants (e.g. PIPELINE_NAMES)
+            continue                       # constants (e.g. PIPELINES)
         if not inspect.getdoc(obj):
             missing.append(f"{mod_name}.{name}")
     assert not missing, f"exports without docstrings: {missing}"

@@ -59,7 +59,7 @@ def serve(small_index, q, traces, params, *, micro_batch):
         nprobe=8, top_k=3, buffer_pages=256, pool_pages=4096,
         lookahead_rank=16, kernel_mode="ref", chips=8, seed=7,
         paged_decode=True), 1, ARCH, micro_batch=micro_batch,
-        include_tail=True, decode_hook=runner, continuous=True)
+        include_tail=True, decode_hook=runner)
     runner.attach(srv)
     srv.recorder.enable_host_spans(SYSTEM_CLOCK)
     inner, logits = runner._paged_step, []

@@ -10,9 +10,8 @@ from repro.configs import get_arch
 from repro.core.schedulers import TeleRAGScheduler
 from repro.memory import (AdmissionController, DevicePagePool, MemoryLedger,
                           PoolExhausted)
-from repro.serving import (EngineConfig, KVCacheManager, PipelineExecutor,
-                           RequestState, RetrievalRuntime, TeleRAGEngine,
-                           make_traces)
+from repro.serving import (EngineConfig, KVCacheManager, RequestState,
+                           RetrievalRuntime, TeleRAGEngine, make_traces)
 from tests.conftest import unit_queries
 
 
@@ -110,9 +109,11 @@ def test_engine_ledger_tracks_buffer_and_weights(small_store, small_index,
                         get_arch("llama3-8b"))
     assert eng.ledger.bytes_of("weights") == \
         get_arch("llama3-8b").param_count() * 2
-    ex = PipelineExecutor(eng)
+    runtime = RetrievalRuntime(eng)
     q = unit_queries(small_store, rng, 2)
-    ex.execute_batch(q, make_traces("hyde", 2, seed=1))
+    for i, t in enumerate(make_traces("hyde", 2, seed=1)):
+        runtime.submit(q[i], t)
+    runtime.run()
     # end_batch evicted everything: prefetch charge fully credited back
     assert eng.ledger.bytes_of("prefetch") == 0
     assert eng.ledger.peak_bytes > eng.ledger.bytes_of("weights")
@@ -256,13 +257,8 @@ def _pressured_runtime(small_index, pool_clusters=6):
                                      lookahead_rank=16, kernel_mode="ref",
                                      chips=8, seed=3),
                         get_arch("llama3-8b"))
-    # never-re-form mode: this suite pins the legacy group-granular
-    # release ordering (a wave's shared pins free when its LAST member
-    # completes); the per-request fine-grained release is covered in
-    # tests/test_continuous.py
     return eng, RetrievalRuntime(
-        eng, scheduler=TeleRAGScheduler(cache_aware=False), micro_batch=2,
-        reform=False)
+        eng, scheduler=TeleRAGScheduler(cache_aware=False), micro_batch=2)
 
 
 def test_pressure_stall_event_ordering_and_completion(small_store,
@@ -282,11 +278,13 @@ def test_pressure_stall_event_ordering_and_completion(small_store,
     assert eng.admission.stats.stalled >= 1
     assert eng.admission.stats.resumed >= 1
 
-    stall_t = {rid: t for t, label, rid in runtime.event_log
+    marks = [(e.t, e.label, e.request_id) for e in eng.recorder.events
+             if e.kind == "request"]
+    stall_t = {rid: t for t, label, rid in marks
                if label == "pressure_stall"}
-    resume_t = {rid: t for t, label, rid in runtime.event_log
+    resume_t = {rid: t for t, label, rid in marks
                 if label == "pressure_resume"}
-    complete_t = {rid: t for t, label, rid in runtime.event_log
+    complete_t = {rid: t for t, label, rid in marks
                   if label == "complete"}
     assert stall_t, "no request ever entered PRESSURE_STALLED"
     stalled_ids = set(stall_t)
@@ -294,11 +292,13 @@ def test_pressure_stall_event_ordering_and_completion(small_store,
                             if rid not in stalled_ids]
     for rid in stalled_ids:
         # pinned event ordering: stall at admit-time pressure, resume
-        # exactly on the page-free event of the completing wave (its
-        # pins release when its LAST member completes), complete after
+        # exactly on the page-free event of a completing request of the
+        # first wave (each member releases its own pins at its own
+        # completion), complete after
         assert rid in resume_t and rid in complete_t
         assert stall_t[rid] <= resume_t[rid] <= complete_t[rid]
-        assert resume_t[rid] == pytest.approx(max(first_wave_completes))
+        assert any(resume_t[rid] == pytest.approx(t)
+                   for t in first_wave_completes)
         rec = next(r for r in recs if r.request_id == rid)
         stall_spans = rec.spans("pressure_stall")
         assert stall_spans and stall_spans[0].end == \
@@ -315,22 +315,27 @@ def test_pressure_stall_event_ordering_and_completion(small_store,
 def test_overprovisioned_pool_matches_default_telemetry(small_store,
                                                         small_index, rng):
     """Pool size must be invisible to telemetry when memory is ample:
-    execute_batch under a 4x over-provisioned pool reproduces the
-    default-sized run's RoundTelemetry to 1e-6 (the pre-refactor
-    values, pinned transitively by test_runtime's legacy-model check)."""
+    two drains under a 4x over-provisioned pool reproduce the
+    default-sized run's RoundTelemetry to 1e-6."""
     q = unit_queries(small_store, rng, 4)
     results = []
-    for pool_pages in (160, 640):
+    # ample: 400 pages hold every wave the four iter requests re-form
+    # into without a stall (160 stall them)
+    for pool_pages in (400, 1600):
         # cache on: the second batch also exercises consolidate's quota,
         # which must key off buffer_pages, never the pool extent
-        cfg = EngineConfig(nprobe=16, top_k=3, buffer_pages=160,
+        cfg = EngineConfig(nprobe=16, top_k=3, buffer_pages=400,
                            pool_pages=pool_pages, lookahead_rank=32,
                            kernel_mode="ref", chips=8, seed=5,
                            cache_enabled=True)
         eng = TeleRAGEngine(small_index, cfg, get_arch("llama3-8b"))
-        ex = PipelineExecutor(eng)
-        res = ex.execute_batch(q.copy(), make_traces("iter", 4, seed=11))
-        res += ex.execute_batch(q.copy(), make_traces("iter", 4, seed=12))
+        runtime = RetrievalRuntime(eng)
+        res = []
+        for seed in (11, 12):
+            recs = [runtime.submit(q[i].copy(), t) for i, t in
+                    enumerate(make_traces("iter", 4, seed=seed))]
+            runtime.run()
+            res += [r.result for r in recs]
         assert eng.admission.stats.stalled == 0
         assert eng.admission.stats.capped == 0
         results.append(res)

@@ -5,9 +5,8 @@ Pins the observability subsystem's contracts:
     spans, touching endpoints) — the overlap accounting rests on it;
   * recorder correctness on a served run: every admitted request's
     lifecycle marks are ordered admit <= generate-dispatch <= complete,
-    every dispatched wave has a form and a complete, every issued
-    transfer lands, and ``runtime.event_log`` is exactly the
-    ``legacy_tuples`` view;
+    every dispatched wave has a form and a complete, and every issued
+    transfer lands;
   * ``ServerTelemetry``/``TenantTelemetry`` are registry-backed views
     numerically equal to the response stream they summarize;
   * the Perfetto export passes ``tools/check_trace.py`` (the CI gate)
@@ -56,7 +55,7 @@ def _serve_mix(small_store, small_index, rng, n=10, replicas=2,
     """A hyde/iter mix through a continuous 2-replica server; returns
     (server, responses)."""
     srv = TeleRAGServer(small_index, _cfg(), replicas, get_arch("llama3-8b"),
-                        micro_batch=3, continuous=True, trace=trace)
+                        micro_batch=3, trace=trace)
     q = unit_queries(small_store, rng, n)
     half = n // 2
     # make_traces numbers ids 0..n-1 per call — re-id so the mix's
@@ -144,44 +143,6 @@ def test_no_orphan_wave_or_transfer_events(small_store, small_index, rng):
     for ev in dispatched:
         if ev.transfer_id >= 0:
             assert (ev.replica, ev.transfer_id) in issues
-
-
-def test_event_log_is_the_legacy_view(small_store, small_index, rng):
-    """Each replica runtime's ``event_log`` property IS the recorder's
-    per-lane legacy view: same tuples, legacy labels only, time-ordered
-    within the lane, no server-side ``submit`` marks leaking in."""
-    from repro.obs.recorder import LEGACY_LABELS  # noqa: PLC0415
-
-    srv, _ = _serve_mix(small_store, small_index, rng)
-    total = 0
-    for i, rt in enumerate(srv.runtimes):
-        log = rt.event_log
-        assert log == srv.recorder.legacy_tuples(i)
-        total += len(log)
-        for t, label, rid in log:
-            assert label in LEGACY_LABELS
-            assert isinstance(t, float) and isinstance(rid, int)
-        times = [t for t, _, _ in log]
-        assert times == sorted(times)
-    assert total > 0, "served run must populate the legacy view"
-
-
-def test_runtime_event_log_shim(small_store, small_index, rng):
-    """A standalone runtime (no server) still records through its
-    engine's own recorder and exposes the shim."""
-    from repro.serving import TeleRAGEngine  # noqa: PLC0415
-    from repro.serving.runtime import RetrievalRuntime  # noqa: PLC0415
-
-    eng = TeleRAGEngine(small_index, _cfg(), get_arch("llama3-8b"))
-    rt = RetrievalRuntime(eng)
-    q = unit_queries(small_store, rng, 4)
-    for i, tr in enumerate(make_traces("hyde", 4, seed=9)):
-        rt.submit(q[i], tr)
-    rt.run()
-    log = rt.event_log
-    assert log, "shim must reproduce the legacy tuples"
-    assert log == rt.recorder.legacy_tuples(rt.replica_id)
-    assert {label for _, label, _ in log} >= {"admit", "complete"}
 
 
 def test_shared_recorder_injection(small_store, small_index, rng):
@@ -372,7 +333,7 @@ def _serve_decode(small_store, small_index, dense_params, *, spans,
         nprobe=8, top_k=3, buffer_pages=256, pool_pages=4096,
         lookahead_rank=16, kernel_mode="ref", chips=8, seed=7,
         cache_enabled=True), 1, arch, micro_batch=3, include_tail=True,
-        decode_hook=decode, continuous=True, wall_clock=wall_clock)
+        decode_hook=decode, wall_clock=wall_clock)
     runner.attach(srv)
     if hasattr(decode, "wrap"):
         decode.wrap(srv.engines[0])
@@ -513,7 +474,8 @@ def test_host_spans_leave_the_event_stream_unchanged(small_store,
         return out
 
     assert stream(on.recorder) == stream(off.recorder)
-    assert on.recorder.legacy_tuples() == off.recorder.legacy_tuples()
+    # the request-lifecycle events, lease-free, compare as they are
+    assert on.recorder.of("request") == off.recorder.of("request")
 
 
 def test_perfetto_host_lanes_validate(small_store, small_index, dense_params,

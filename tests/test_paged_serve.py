@@ -64,7 +64,7 @@ def _serve(small_index, q, traces, *, paged, params, micro_batch=3,
         nprobe=8, top_k=3, buffer_pages=256, pool_pages=4096,
         lookahead_rank=16, kernel_mode="ref", chips=8, seed=7,
         paged_decode=paged), 1, arch, micro_batch=micro_batch,
-        include_tail=True, decode_hook=runner, continuous=True)
+        include_tail=True, decode_hook=runner)
     runner.attach(srv)
     resp = srv.serve([RagRequest(
         q=q[i], trace=traces[i],
@@ -376,8 +376,7 @@ def _direct_runner(small_index, params, mode, *, micro_batch=3):
         nprobe=8, top_k=3, buffer_pages=256, pool_pages=4096,
         lookahead_rank=16, kernel_mode="ref", chips=8, seed=7,
         paged_decode=mode != "dense", chunk_kv=store is not None), 1, ARCH,
-        micro_batch=micro_batch, include_tail=True, decode_hook=runner,
-        continuous=True)
+        micro_batch=micro_batch, include_tail=True, decode_hook=runner)
     runner.attach(srv)
     waves = []
 

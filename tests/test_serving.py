@@ -5,10 +5,19 @@ import pytest
 
 import repro.core as core
 from repro.configs import get_arch
-from repro.serving import (EngineConfig, MultiReplicaOrchestrator,
-                           PipelineExecutor, TeleRAGEngine, make_traces,
-                           calibration_windows, PIPELINES)
+from repro.core.schedulers import TeleRAGScheduler
+from repro.serving import (EngineConfig, RagRequest, RequestState,
+                           RetrievalRuntime, TeleRAGEngine, TeleRAGServer,
+                           make_traces, calibration_windows, PIPELINES)
 from tests.conftest import unit_queries
+
+
+def _execute(runtime, q, traces):
+    """Serve one batch of simultaneous requests; their results in
+    submission order."""
+    recs = [runtime.submit(q[i], t) for i, t in enumerate(traces)]
+    runtime.run()
+    return [r.result for r in recs]
 
 
 @pytest.fixture()
@@ -21,10 +30,9 @@ def engine(small_index):
 @pytest.mark.parametrize("pipe", PIPELINES)
 def test_pipeline_executes_and_speedup_model(small_store, small_index, rng,
                                              engine, pipe):
-    ex = PipelineExecutor(engine)
     q = unit_queries(small_store, rng, 4)
     traces = make_traces(pipe, 4, seed=1)
-    res = ex.execute_batch(q, traces)
+    res = _execute(RetrievalRuntime(engine), q, traces)
     assert len(res) == 4
     for r, t in zip(res, traces):
         assert len(r.rounds) == t.rounds
@@ -47,8 +55,7 @@ def test_modes_agree_on_results(small_store, small_index, rng):
                            lookahead_rank=24, kernel_mode="ref", mode=mode,
                            seed=5)
         eng = TeleRAGEngine(small_index, cfg, get_arch("llama3-8b"))
-        ex = PipelineExecutor(eng)
-        res = ex.execute_batch(q.copy(), [t for t in traces])
+        res = _execute(RetrievalRuntime(eng), q.copy(), traces)
         outs[mode] = np.stack([np.sort(np.concatenate(r.doc_ids))
                                for r in res])
     np.testing.assert_array_equal(outs["telerag"], outs["cpu_baseline"])
@@ -59,10 +66,9 @@ def test_multi_round_incremental_prefetch(small_store, small_index, rng,
                                           engine):
     """IRG does 3 rounds on drifting queries: later rounds should reuse
     earlier fetches (bytes decrease or stay flat per round)."""
-    ex = PipelineExecutor(engine)
     q = unit_queries(small_store, rng, 2)
     traces = make_traces("irg", 2, seed=3)
-    res = ex.execute_batch(q, traces)
+    res = _execute(RetrievalRuntime(engine), q, traces)
     per_round = {}
     for r in res:
         for rt in r.rounds:
@@ -81,31 +87,30 @@ def test_cache_improves_second_batch(small_store, small_index, rng):
                        lookahead_rank=32, kernel_mode="ref",
                        cache_enabled=True, seed=2)
     eng = TeleRAGEngine(small_index, cfg, get_arch("llama3-8b"))
-    ex = PipelineExecutor(eng)
+    runtime = RetrievalRuntime(eng)
     q = unit_queries(small_store, rng, 4)
-    ex.execute_batch(q, make_traces("hyde", 4, seed=4))
+    _execute(runtime, q, make_traces("hyde", 4, seed=4))
     h2d_first = eng.buffer.stats.bytes_h2d
     # same neighbourhood of queries again: cached clusters cut transfers
     q2 = q + 0.02 * rng.standard_normal(q.shape).astype(np.float32)
     q2 /= np.linalg.norm(q2, axis=-1, keepdims=True)
-    ex.execute_batch(q2, make_traces("hyde", 4, seed=5))
+    _execute(runtime, q2, make_traces("hyde", 4, seed=5))
     h2d_second = eng.buffer.stats.bytes_h2d - h2d_first
     assert h2d_second <= h2d_first
 
 
 def test_engine_snapshot_restore_roundtrip(small_store, small_index, rng,
                                            engine):
-    ex = PipelineExecutor(engine)
     q = unit_queries(small_store, rng, 2)
     engine.cfg.cache_enabled = True
-    ex.execute_batch(q, make_traces("iter", 2, seed=6))
+    _execute(RetrievalRuntime(engine), q, make_traces("iter", 2, seed=6))
     snap = engine.snapshot()
     eng2 = TeleRAGEngine(small_index, engine.cfg, get_arch("llama3-8b"))
     eng2.restore(snap)
     assert eng2.buffer.resident_clusters() == engine.buffer.resident_clusters()
     assert eng2.cache.hotness == engine.cache.hotness
     # restored replica serves correctly
-    res = PipelineExecutor(eng2).execute_batch(q, make_traces("hyde", 2, seed=7))
+    res = _execute(RetrievalRuntime(eng2), q, make_traces("hyde", 2, seed=7))
     assert all(len(r.doc_ids) > 0 for r in res)
 
 
@@ -115,10 +120,9 @@ def test_restore_rebinds_subscribers_and_keeps_admission_stats(
     replacement pool through the public rebind API — long-lived runtimes
     keep waking on pressure events — and (b) restore admission telemetry
     instead of silently zeroing it."""
-    ex = PipelineExecutor(engine)
     q = unit_queries(small_store, rng, 2)
     engine.cfg.cache_enabled = True
-    ex.execute_batch(q, make_traces("iter", 2, seed=6))
+    _execute(RetrievalRuntime(engine), q, make_traces("iter", 2, seed=6))
     freed = []
     engine.pool.subscribe(freed.append)
     snap = engine.snapshot()
@@ -146,13 +150,17 @@ def test_orchestrator_with_dead_replica(small_store, small_index, rng):
     cfg = EngineConfig(nprobe=12, top_k=3, buffer_pages=128,
                        lookahead_rank=24, kernel_mode="ref",
                        cache_enabled=True)
-    orch = MultiReplicaOrchestrator(small_index, cfg, 3,
-                                    get_arch("llama3-8b"))
+    srv = TeleRAGServer(small_index, cfg, 3, get_arch("llama3-8b"),
+                        scheduler=TeleRAGScheduler(), micro_batch=4)
+    srv.mark_dead(1)
     q = unit_queries(small_store, rng, 12)
-    rep = orch.run_global_batch(q, make_traces("hyde", 12, seed=8),
-                                micro_batch=4, dead_replicas={1})
-    assert all(a[1] != 1 for a in rep.assignments)
-    assert len(rep.all_results()) == 12
+    traces = make_traces("hyde", 12, seed=8)
+    resp = srv.serve([RagRequest(q=q[i], trace=t)
+                      for i, t in enumerate(traces)])
+    assert all(a[1] != 1 for w in srv.wave_log for a in w.assignments)
+    assert len(resp) == 12
+    assert all(r.replica != 1 and r.state == RequestState.COMPLETE
+               for r in resp)
 
 
 def test_calibration_windows_positive():

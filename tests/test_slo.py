@@ -3,7 +3,7 @@ pool reservations, and the per-tenant telemetry contract.
 
 Pins the PR-4 acceptance criteria:
   * scheduling is demonstrably SLO-aware — the identical workload with
-    swapped priorities produces a different admission/dispatch order
+    swapped priorities produces a different served order
     AND a different deadline-miss count;
   * a tenant's guaranteed page floor is never violated by another
     tenant's burst (reservation accounting AND the spill path);
@@ -12,8 +12,12 @@ Pins the PR-4 acceptance criteria:
     flags exactly;
   * a round whose members are already past deadline demotes its
     lookahead prefetch (no pool pages, no link bytes);
-  * single-tenant defaults leave dispatch order unchanged (the legacy
-    shim equivalence in tests/test_api.py rides on this).
+  * without deadlines the served order is priority class, then FIFO.
+
+Admission onto a replica is immediate under per-request continuous
+batching, so the served order is read from the wave each request rode
+(round 0's ``round_start_t``, then its ``wave_id``), not from
+``admit_t``.
 """
 
 import numpy as np
@@ -40,6 +44,13 @@ def _solo_latency(small_index, q, trace):
     return srv.serve([RagRequest(q=q, trace=trace)])[0].latency_s
 
 
+def _served_order(resp):
+    """Request ids in the order their first round started."""
+    return [r.request_id for r in sorted(
+        resp, key=lambda r: (r.rounds[0].round_start_t,
+                             r.rounds[0].wave_id))]
+
+
 # ---------------------------------------------------------------------------
 # Deadline/priority-aware dispatch ordering
 # ---------------------------------------------------------------------------
@@ -49,8 +60,8 @@ def test_swapped_priorities_change_dispatch_order_and_miss_count(
         small_store, small_index, rng):
     """Two simultaneous requests, one replica, micro_batch=1: request A
     carries a deadline only one of them can meet (~1.5x solo service).
-    When A outranks B it dispatches first and meets its deadline; the
-    identical workload with priorities swapped dispatches B first and A
+    When A outranks B its wave runs first and meets its deadline; the
+    identical workload with priorities swapped runs B first and A
     misses — same data ops, different order, different miss count."""
     q = unit_queries(small_store, rng, 2)
     traces = make_traces("hyde", 2, seed=31)
@@ -73,9 +84,12 @@ def test_swapped_priorities_change_dispatch_order_and_miss_count(
     fast, tele_fast = serve(prio_a=0, prio_b=1)       # A outranks B
     slow, tele_slow = serve(prio_a=1, prio_b=0)       # swapped
 
-    # different dispatch (admission) order on the replica
-    assert fast[0].admit_t < fast[1].admit_t
-    assert slow[0].admit_t > slow[1].admit_t
+    # different served order on the replica
+    ids = [t.request_id for t in traces]
+    assert _served_order(fast) == ids
+    assert _served_order(slow) == ids[::-1]
+    assert fast[0].rounds[0].round_start_t < fast[1].rounds[0].round_start_t
+    assert slow[0].rounds[0].round_start_t > slow[1].rounds[0].round_start_t
     # ... and a different miss count for the identical workload
     assert not fast[0].deadline_missed
     assert slow[0].deadline_missed
@@ -86,30 +100,32 @@ def test_swapped_priorities_change_dispatch_order_and_miss_count(
 def test_edf_orders_by_deadline_within_priority_class(
         small_store, small_index, rng):
     """Three same-priority requests in one wave, served one at a time:
-    EDF dispatches tightest deadline first regardless of submission
-    order; FifoDispatch preserves submission order on the same stream."""
+    the tightest deadline is served first regardless of submission
+    order.  The wave former orders the ready requests by (priority,
+    deadline, arrival) itself, so ``FifoDispatch`` serves the same
+    stream in the same order: the dispatch policy does not change it."""
     q = unit_queries(small_store, rng, 3)
     traces = make_traces("hyde", 3, seed=37)
     deadlines = [30.0, 10.0, 20.0]          # submission order != EDF order
 
-    def admit_order(dispatch):
+    def served_order(dispatch):
         srv = TeleRAGServer(small_index, _cfg(), 1, get_arch("llama3-8b"),
                             micro_batch=1, dispatch=dispatch)
         resp = srv.serve([RagRequest(q=q[i], trace=traces[i],
                                      deadline_s=deadlines[i])
                           for i in range(3)])
-        return [r.request_id for r in sorted(resp, key=lambda r: r.admit_t)]
+        assert all(r.admit_t == r.arrival_t for r in resp)
+        return _served_order(resp)
 
     ids = [t.request_id for t in traces]
-    assert admit_order(EdfDispatch()) == [ids[1], ids[2], ids[0]]
-    assert admit_order(FifoDispatch()) == ids
+    assert served_order(EdfDispatch()) == [ids[1], ids[2], ids[0]]
+    assert served_order(FifoDispatch()) == [ids[1], ids[2], ids[0]]
 
 
-def test_default_dispatch_without_deadlines_is_legacy_order(
+def test_default_dispatch_without_deadlines_is_priority_then_fifo_order(
         small_store, small_index, rng):
-    """No deadlines anywhere: the default EdfDispatch must reproduce the
-    legacy (priority, FIFO) dispatch order exactly — this is what keeps
-    the deprecated shims pinned equivalent."""
+    """No deadlines anywhere: requests are served by priority class,
+    then in submission order within a class."""
     q = unit_queries(small_store, rng, 4)
     traces = make_traces("hyde", 4, seed=41)
     prios = [1, 0, 1, 0]
@@ -117,9 +133,8 @@ def test_default_dispatch_without_deadlines_is_legacy_order(
                         micro_batch=1)
     resp = srv.serve([RagRequest(q=q[i], trace=traces[i], priority=prios[i])
                       for i in range(4)])
-    got = [r.request_id for r in sorted(resp, key=lambda r: r.admit_t)]
     want = [traces[i].request_id for i in (1, 3, 0, 2)]  # prio, then FIFO
-    assert got == want
+    assert _served_order(resp) == want
 
 
 # ---------------------------------------------------------------------------
@@ -322,27 +337,40 @@ def test_tenant_telemetry_counters_match_response_flags(
 
 def test_missed_in_queue_distinguished_from_missed_in_service(
         small_store, small_index, rng):
-    """A request whose deadline expires while it still waits for a
-    replica slot reports missed-in-queue; one admitted in time that
-    finishes late reports missed-in-service only."""
+    """Missed-in-queue means the deadline passed before ``admit_t``.
+    Per-request continuous batching admits every request onto its
+    replica at arrival, so a request whose deadline expires while it
+    waits for a wave (here: behind the pool pages its predecessors'
+    waves hold) reports missed-in-service, its wait showing as a
+    ``pressure_stall`` span; one that starts in time and finishes late
+    reports missed-in-service too."""
     q = unit_queries(small_store, rng, 3)
     traces = make_traces("hyde", 3, seed=53)
     solo = [_solo_latency(small_index, q[i], traces[i]) for i in range(3)]
     srv = TeleRAGServer(small_index, _cfg(), 1, get_arch("llama3-8b"),
-                        micro_batch=1, dispatch=FifoDispatch())
+                        micro_batch=1)
+    deadline_1 = solo[0] + 0.5 * solo[1]
+    deadline_2 = 0.5 * solo[0]
     resp = srv.serve([
         RagRequest(q=q[0], trace=traces[0]),                 # head of line
-        # admitted in time (queue ~ solo[0]) but expires mid-service
-        RagRequest(q=q[1], trace=traces[1],
-                   deadline_s=solo[0] + 0.5 * solo[1]),
-        # expires while still queued behind requests 0 and 1
-        RagRequest(q=q[2], trace=traces[2], deadline_s=0.5 * solo[0])])
+        # starts in time (after request 0) but expires mid-service
+        RagRequest(q=q[1], trace=traces[1], priority=1,
+                   deadline_s=deadline_1),
+        # expires while still waiting behind requests 0 and 1
+        RagRequest(q=q[2], trace=traces[2], priority=2,
+                   deadline_s=deadline_2)])
+    assert _served_order(resp) == [t.request_id for t in traces]
+    assert all(r.admit_t == r.arrival_t for r in resp)
+    assert resp[1].rounds[0].round_start_t < deadline_1
     assert resp[1].deadline_missed and not resp[1].deadline_missed_in_queue
-    assert resp[2].deadline_missed and resp[2].deadline_missed_in_queue
+    assert resp[2].rounds[0].round_start_t > deadline_2
+    assert resp[2].stall_s == pytest.approx(
+        resp[2].rounds[0].round_start_t)
+    assert resp[2].deadline_missed and not resp[2].deadline_missed_in_queue
     t = srv.telemetry().tenant("shared")
     assert t.deadline_missed == 2
-    assert t.missed_in_queue == 1
-    assert t.missed_in_service == 1
+    assert t.missed_in_queue == 0
+    assert t.missed_in_service == 2
 
 
 # ---------------------------------------------------------------------------

@@ -16,9 +16,10 @@ import pytest
 
 import repro.core as core
 from repro.configs import get_arch
+from repro.core.schedulers import TeleRAGScheduler
 from repro.models import transformer as tf
-from repro.serving import (EngineConfig, MultiReplicaOrchestrator,
-                           PipelineExecutor, TeleRAGEngine, make_traces)
+from repro.serving import (EngineConfig, RagRequest, TeleRAGEngine,
+                           TeleRAGServer, make_traces)
 from tests.conftest import unit_queries
 
 
@@ -88,19 +89,22 @@ def test_multi_replica_cache_hit_rate_improves(small_store, small_index, rng):
     cfg = EngineConfig(nprobe=16, top_k=3, buffer_pages=200,
                        lookahead_rank=32, kernel_mode="ref",
                        cache_enabled=True)
-    orch = MultiReplicaOrchestrator(small_index, cfg, 2,
-                                    get_arch("llama3-8b"))
+    srv = TeleRAGServer(small_index, cfg, 2, get_arch("llama3-8b"),
+                        scheduler=TeleRAGScheduler(), micro_batch=4)
+
+    def overlap(q, traces):
+        """Summed routing overlap of one drained arrival wave."""
+        srv.serve([RagRequest(q=q[i], trace=t)
+                   for i, t in enumerate(traces)])
+        return sum(a[2] for a in srv.wave_log[-1].assignments)
+
     qs = unit_queries(small_store, rng, 8)
-    r1 = orch.run_global_batch(qs, make_traces("hyde", 8, seed=1),
-                               micro_batch=4)
+    first = overlap(qs, make_traces("hyde", 8, seed=1))
     # second wave of similar queries: cache-aware router should place them
     # on replicas already holding their clusters
     q2 = qs + 0.02 * rng.standard_normal(qs.shape).astype(np.float32)
     q2 /= np.linalg.norm(q2, axis=-1, keepdims=True)
-    r2 = orch.run_global_batch(q2, make_traces("hyde", 8, seed=2),
-                               micro_batch=4)
-    assert sum(a[2] for a in r2.assignments) > sum(a[2] for a in
-                                                   r1.assignments)
+    assert overlap(q2, make_traces("hyde", 8, seed=2)) > first
 
 
 def test_hit_rate_grows_with_budget(small_store, small_index, rng):
